@@ -66,6 +66,7 @@ from repro.configs.base import ShapeCell
 from repro.configs.reduced import reduced
 from repro.launch import specs as SP
 from repro.launch import train as TR
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.serving import EngineConfig, Request, ServingEngine
 
@@ -107,6 +108,7 @@ def main():
                          "slot raises typed PagePoolExhausted instead "
                          "of evicting + re-queueing the youngest slot")
     args = ap.parse_args()
+    enable_compile_cache()
 
     dp, tp = (int(x) for x in args.mesh.split("x"))
     mesh = make_mesh((dp, tp), ("data", "model"))
@@ -151,10 +153,12 @@ def main():
     stats, per_tok = engine.decode_wire_stats()
     ps = engine.pool_stats()
     peak_kb = ps["peak_pages_in_use"] * engine.cache.kv_page_bytes() / 1e3
+    dev = jax.devices()[0]
     print(f"{cfg.name} ({cfg.hnn_mode}/{cfg.codec}) mesh={args.mesh} "
           f"slots={args.slots}: served {len(results)} requests, "
           f"{toks} tokens in {dt*1e3:.0f}ms "
-          f"({toks/max(dt, 1e-9):.1f} tok/s on CPU)")
+          f"({toks/max(dt, 1e-9):.1f} tok/s on {dev.platform} "
+          f"{dev.device_kind} x{len(jax.devices())})")
     print(f"decode steps={engine.decode_steps}  "
           f"async depth={engine.async_depth}  "
           f"wire {per_tok/1e3:.1f}KB/token "

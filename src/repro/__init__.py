@@ -1,3 +1,2 @@
 """Reproduction of spike-coded die-to-die communication, grown toward a
 production-scale serving system (see ROADMAP.md)."""
-from . import compat  # noqa: F401  (backfills newer jax APIs on old installs)

@@ -1,8 +1,8 @@
 """Pallas hot-spot kernels, each mapped to the paper stage it serves.
 
 Every kernel ships with a pure-jnp oracle in ``ref.py`` and a jit'd
-public wrapper in ``ops.py`` that runs compiled on TPU and in interpret
-mode everywhere else (the CI posture on both jax pins).
+public wrapper in ``ops.py`` that always runs compiled on a TPU backend
+and in interpret mode (or, for ``paged_decode``, its oracle) elsewhere.
 
 =================  ======================================================
 ``lif_encode``     Paper Sec. "spike-based encoding": the fused T-tick
@@ -24,6 +24,6 @@ mode everywhere else (the CI posture on both jax pins).
                    wire the coded cross-shard combine consumes — the
                    attention analog of encode-at-the-boundary, with no
                    dense ``[B, pages * page_size, Hkv, dh]`` gather in
-                   HBM.
+                   HBM: one page of K and V is DMA'd per grid step.
 =================  ======================================================
 """

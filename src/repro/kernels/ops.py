@@ -1,8 +1,12 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On TPU the kernels run compiled; everywhere else (this CPU container)
-they run in interpret mode, which executes the kernel body in Python and
-is used by the test suite to validate against the ``ref.py`` oracles.
+Which path runs is decided by the backend alone.  On a TPU backend every
+wrapper compiles its Pallas kernel: there is no interpret mode and no
+oracle fallback there.  Off-TPU (the CPU test suite), the kernels run in
+interpret mode, which executes the kernel body through the jax
+interpreter and is what the tests validate against the ``ref.py``
+oracles; ``paged_flash_decode`` runs its ``ref.py`` oracle instead
+unless asked for the interpreted kernel (see its docstring).
 
 Ragged shapes are padded up to block multiples here so the kernels can
 assume aligned tiles.
@@ -34,12 +38,10 @@ def _pad_to(x: jax.Array, mult0: int, mult1: int):
     return x, (m, c)
 
 
-@partial(jax.jit, static_argnames=("T", "interpret"))
+@partial(jax.jit, static_argnames=("T",))
 def lif_encode(x: jax.Array, theta: jax.Array, scale: jax.Array, *,
-               T: int = 15,
-               interpret: bool | None = None) -> jax.Array:
+               T: int = 15) -> jax.Array:
     """Fused T-tick IF rate encoder. x [M,C] -> int8 counts [M,C]."""
-    interp = (not _on_tpu()) if interpret is None else interpret
     (M, C) = x.shape
     bm = 8 if M < 256 else 256
     bc = 128 if C < 512 else 512
@@ -47,16 +49,14 @@ def lif_encode(x: jax.Array, theta: jax.Array, scale: jax.Array, *,
     tp = jnp.pad(theta, (0, xp.shape[1] - C), constant_values=1e9)
     sp = jnp.pad(scale, (0, xp.shape[1] - C), constant_values=1.0)
     out = lif_encode_pallas(xp, tp, sp, T=T, block_m=bm,
-                            block_c=bc, interpret=interp)
+                            block_c=bc, interpret=not _on_tpu())
     return out[:m0, :c0]
 
 
-@partial(jax.jit, static_argnames=("T", "out_dtype", "interpret"))
+@partial(jax.jit, static_argnames=("T", "out_dtype"))
 def count_matmul(counts: jax.Array, w: jax.Array, scale: jax.Array, *,
-                 T: int = 15, out_dtype=jnp.bfloat16,
-                 interpret: bool | None = None) -> jax.Array:
+                 T: int = 15, out_dtype=jnp.bfloat16) -> jax.Array:
     """int8 counts [M,K] x w [K,N] with fused rate decode."""
-    interp = (not _on_tpu()) if interpret is None else interpret
     M, K = counts.shape
     _, N = w.shape
     bm = 8 if M < 256 else 256
@@ -67,72 +67,65 @@ def count_matmul(counts: jax.Array, w: jax.Array, scale: jax.Array, *,
     sp = jnp.pad(scale, (0, cp.shape[1] - K))
     out = count_matmul_pallas(cp, wp, sp, T=T, block_m=bm, block_n=bn,
                               block_k=bk, out_dtype=out_dtype,
-                              interpret=interp)
+                              interpret=not _on_tpu())
     return out[:m0, :N]
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def pack4(wire: jax.Array, *, interpret: bool | None = None) -> jax.Array:
-    interp = (not _on_tpu()) if interpret is None else interpret
+@jax.jit
+def pack4(wire: jax.Array) -> jax.Array:
     M, C = wire.shape
     bm = 8 if M < 256 else 256
     bc = 256 if C < 1024 else 1024
     xp, (m0, _) = _pad_to(wire, bm, bc)
-    out = pack4_pallas(xp, block_m=bm, block_c=bc, interpret=interp)
+    out = pack4_pallas(xp, block_m=bm, block_c=bc, interpret=not _on_tpu())
     return out[:m0, : C // 2]
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def unpack4(packed: jax.Array, *, interpret: bool | None = None) -> jax.Array:
-    interp = (not _on_tpu()) if interpret is None else interpret
+@jax.jit
+def unpack4(packed: jax.Array) -> jax.Array:
     M, C2 = packed.shape
     bm = 8 if M < 256 else 256
     bc = 128 if C2 < 512 else 512
     xp, (m0, _) = _pad_to(packed, bm, bc)
-    out = unpack4_pallas(xp, block_m=bm, block_c=bc, interpret=interp)
+    out = unpack4_pallas(xp, block_m=bm, block_c=bc, interpret=not _on_tpu())
     return out[:m0, : C2 * 2]
 
 
 @partial(jax.jit,
          static_argnames=("window", "cap", "encode_wire", "interpret"))
-def paged_flash_decode(q, k_pool, v_pool, cl_page, cl_pos, qpos, *,
+def paged_flash_decode(q, k_pool, v_pool, cl_page, cl_pos, qpos, layer, *,
                        window: int = 0, cap: float = 0.0,
-                       encode_wire: bool = False,
-                       interpret: bool | None = None):
+                       encode_wire: bool = False, interpret: bool = False):
     """Fused page-gather -> flash decode -> LSE partial over one shard.
 
-    q [B,K1,Hq,dh] x this shard's pool slice [P_loc,psz,Hkv,dh], walking
-    the slot's compacted page list (cl_page local rows / cl_pos absolute
-    start positions, [B,ppc], -1 = none).  Returns ``(o, lse)`` or, with
-    ``encode_wire``, the epilogue-quantized ``(wire, scale, lse)`` for
-    the coded cross-shard combine.  Grid is (B,) — no padding needed.
+    q [B,K1,Hq,dh] x unit ``layer`` of this shard's stacked lane-flat
+    pool slice [U,P_loc,psz,Hkv*dh], walking the slot's compacted page
+    list (cl_page local rows / cl_pos absolute start positions, [B,ppc],
+    -1 = none).  Returns ``(o, lse)`` or, with ``encode_wire``, the
+    epilogue-quantized ``(wire, scale, lse)`` for the coded cross-shard
+    combine.
 
-    Dispatch: on TPU the Pallas kernel runs compiled.  Off-TPU the
-    default (``interpret=None``) runs the SAME compacted algorithm
-    through XLA via the ``ref.py`` oracle — the page-list compaction
-    (each shard visits ``ceil(pages/shards)`` pages, never the full
-    block-table width) is a backend-independent win, while the
-    in-kernel fusion (no gathered K/V intermediate in HBM, epilogue
-    quantize) only pays on a real accelerator and interpret-mode
-    Pallas would bury it in per-program overhead.  ``interpret=True``
-    forces the interpreted kernel body — the knob the kernel-vs-oracle
-    tests and the CI kernel lane use to validate the Pallas code path
-    on every pinned jax.
+    Dispatch is by backend.  On TPU the Pallas kernel always runs
+    compiled, whatever ``interpret`` says.  Off-TPU the same compacted
+    algorithm runs through XLA via the ``ref.py`` oracle (interpret-mode
+    Pallas would bury the engine tests in per-program overhead), unless
+    ``interpret=True`` asks for the interpreted kernel body: the knob
+    the kernel-vs-oracle tests use to validate the Pallas code path.
     """
-    if interpret is None and not _on_tpu():
-        o, lse = ref.paged_decode_ref(q, k_pool, v_pool, cl_page, cl_pos,
-                                      qpos, window=window, cap=cap)
-        if not encode_wire:
-            return o, lse
-        # same per-token absmax int8 contract as the kernel epilogue
-        # and core.boundary.quantize_partial
-        s = jnp.maximum(jnp.max(jnp.abs(o), axis=-1, keepdims=True),
-                        1e-6) / 127.0
-        return jnp.round(o / s).astype(jnp.int8), s, lse
-    interp = (not _on_tpu()) if interpret is None else interpret
-    return paged_decode_pallas(q, k_pool, v_pool, cl_page, cl_pos, qpos,
-                               window=window, cap=cap,
-                               encode_wire=encode_wire, interpret=interp)
+    if _on_tpu() or interpret:
+        return paged_decode_pallas(q, k_pool, v_pool, cl_page, cl_pos,
+                                   qpos, layer, window=window, cap=cap,
+                                   encode_wire=encode_wire,
+                                   interpret=not _on_tpu())
+    o, lse = ref.paged_decode_ref(q, k_pool, v_pool, cl_page, cl_pos, qpos,
+                                  layer, window=window, cap=cap)
+    if not encode_wire:
+        return o, lse
+    # same per-token absmax int8 contract as the kernel epilogue and
+    # core.boundary.quantize_partial
+    s = jnp.maximum(jnp.max(jnp.abs(o), axis=-1, keepdims=True),
+                    1e-6) / 127.0
+    return jnp.round(o / s).astype(jnp.int8), s, lse
 
 
 __all__ = ["lif_encode", "count_matmul", "pack4", "unpack4",

@@ -43,23 +43,26 @@ def count_matmul_ref(counts: jax.Array, w: jax.Array, scale: jax.Array,
 
 def paged_decode_ref(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                      cl_page: jax.Array, cl_pos: jax.Array, qpos: jax.Array,
-                     *, window: int = 0, cap: float = 0.0):
+                     layer, *, window: int = 0, cap: float = 0.0):
     """Dense single-softmax oracle for the fused paged-decode kernel.
 
     Same inputs/outputs as ``paged_decode.paged_decode_pallas`` (without
-    the wire epilogue); gathers every compacted-list page densely and
-    runs the exact masking/softmax math of
-    ``models.common.verify_attention_partial`` — one global max, not the
-    kernel's online per-page reduction, so agreement is fp-epsilon.
+    the wire epilogue; pools stacked and lane-flat ``[U, P_loc, psz,
+    Hkv*dh]``, read at unit ``layer``);
+    gathers every compacted-list page densely and runs the exact
+    masking/softmax math of ``models.common.verify_attention_partial`` —
+    one global max, not the kernel's online per-page reduction, so
+    agreement is fp-epsilon.
     """
     import math
     B, K1, Hq, dh = q.shape
-    P_loc, psz, Hkv, _ = k_pool.shape
+    _, P_loc, psz, L = k_pool.shape
+    Hkv = L // dh
     ppc = cl_page.shape[1]
     valid = cl_page >= 0                                     # [B, ppc]
     safe = jnp.where(valid, cl_page, 0)
-    k_s = k_pool[safe].astype(jnp.float32)       # [B, ppc, psz, Hkv, dh]
-    v_s = v_pool[safe].astype(jnp.float32)
+    k_s = k_pool[layer, safe].astype(jnp.float32)  # [B,ppc,psz,Hkv*dh]
+    v_s = v_pool[layer, safe].astype(jnp.float32)
     k_s = k_s.reshape(B, ppc * psz, Hkv, dh)
     v_s = v_s.reshape(B, ppc * psz, Hkv, dh)
     if Hkv != Hq:

@@ -41,8 +41,10 @@ _DTYPE_BYTES = {
 #: these moves boundary packets, not fp activations
 _CODED_DTYPES = frozenset({"s8", "u8", "s4", "u4", "pred"})
 
+#: result type = everything between "=" and the op name; TPU modules
+#: print tiled layouts (``s8[8,64]{1,0:T(8,128)(4,1)}``) inside it
 _COLL_RE = re.compile(
-    r"=\s*(\([^)]*\)|[a-z0-9\[\],{}<=]+)\s+"
+    r"=\s*(.+?)\s+"
     r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
     r"(-start)?\(", re.IGNORECASE)
 _SHAPE_RE = re.compile(r"(f64|f32|bf16|f16|f8e4m3fn|f8e5m2|s64|u64|s32|u32|"

@@ -205,8 +205,12 @@ def paged_cache_specs(plan: CellPlan, page_size: int, num_pages: int):
     """(ShapeDtypeStruct tree, PartitionSpec tree) for the POOLED cache.
 
     Attention KV leaves become a shared device page pool
-    ``[U, num_pages, page_size, Hkv, dh]`` with the page dim sharded
-    over dp x tp (see ``_pool_axes``); recurrent/SSM state leaves stay
+    ``[U, num_pages, page_size, Hkv*dh]`` with the page dim sharded
+    over dp x tp (see ``_pool_axes``).  The heads are folded into the
+    minor (lane) dim so the pool's default TPU layout is row-major, the
+    layout the fused decode kernel reads; with a minor dim of ``dh``
+    (64 for many models) XLA lays the pool out page-minor and copies the
+    whole pool around every kernel call.  Recurrent/SSM state leaves stay
     slot-major — only attention KV pages (state cannot be paged: it is
     O(1) per slot and every block reads all of it every step).
     """
@@ -216,8 +220,8 @@ def paged_cache_specs(plan: CellPlan, page_size: int, num_pages: int):
             "paged KV for encoder-decoder (cross_kv) serving: follow-on")
     structs, specs = cache_specs(plan)
     d_at = blocks_attn.attn_dims(cfg, plan.tp_size)
-    shape = (cfg.n_units, num_pages, page_size, d_at["Hkv"], d_at["dh"])
-    sp = P(None, _pool_axes(plan), None, None, None)
+    shape = (cfg.n_units, num_pages, page_size, d_at["Hkv"] * d_at["dh"])
+    sp = P(None, _pool_axes(plan), None, None)
     for i, kind in enumerate(cfg.pattern):
         if kind in ("attn", "global", "local", "attn_moe"):
             structs[f"pos{i}"]["kv"] = {
@@ -300,10 +304,13 @@ def migrate_stage_shape(plan: CellPlan, page_size: int,
     mirrored destination block row.  Static width = the full block-row
     span: the wire cost of a migration is therefore shape-constant per
     (src, dst) pair — which is what lets the host price it without
-    reading device state (``boundary.kv_wire_bytes``).
+    reading device state (``boundary.kv_wire_bytes``).  The slab is in
+    head space (the pool leaf's lane-flat ``Hkv*dh`` split back into
+    heads), so the coded wire keeps one scale per (position, head).
     """
-    U, _, psz, Hkv, dh = kv_leaf_shape
-    return (U, pages_per_slot(plan.cell.seq_len, page_size), psz, Hkv, dh)
+    d_at = blocks_attn.attn_dims(plan.cfg, plan.tp_size)
+    return (kv_leaf_shape[0], pages_per_slot(plan.cell.seq_len, page_size),
+            page_size, d_at["Hkv"], d_at["dh"])
 
 
 def decode_input_specs(plan: CellPlan):

@@ -238,24 +238,28 @@ def make_draft_head_train_step(cfg, plan: CellPlan, mesh, num_heads: int,
     return jax.jit(fn, donate_argnums=(0, 1)), pspecs_full, opt_specs, bspecs
 
 
+def _init_on_mesh(defs, specs, mesh, key, dtype):
+    """Materialize ``defs`` with every shard built on its own device (one
+    jitted init with ``out_shardings``): no device ever holds the whole
+    tree.  Values are those of ``PR.init_params(defs, key, dtype)``."""
+    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), specs)
+    return jax.jit(lambda k: PR.init_params(defs, k, dtype),
+                   out_shardings=shardings)(key)
+
+
 def init_draft_head_params(cfg, plan: CellPlan, mesh, key, num_heads: int,
                            d_hidden: int = 0, dtype=None):
     """Materialize a fresh (identity-init) draft-heads subtree, sharded
     (i.e. replicated — the defs carry no tp/fsdp dims) on the mesh."""
     hdefs = DH.draft_head_defs(cfg, num_heads, d_hidden)
     hspecs = PR.specs_tree(hdefs, plan.dp, plan.tp)
-    host = PR.init_params(hdefs, key, dtype or cfg.dtype)
-    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), hspecs)
-    return jax.device_put(host, shardings)
+    return _init_on_mesh(hdefs, hspecs, mesh, key, dtype or cfg.dtype)
 
 
 def init_sharded_params(cfg, plan: CellPlan, mesh, key, dtype=None):
     """Materialize params sharded on the mesh (for real runs, not dryrun)."""
     defs, pspecs, _ = shard_params_specs(cfg, plan)
-    dtype = dtype or cfg.dtype
-    host = PR.init_params(defs, key, dtype)
-    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs)
-    return jax.device_put(host, shardings)
+    return _init_on_mesh(defs, pspecs, mesh, key, dtype or cfg.dtype)
 
 
 def abstract_sharded_params(cfg, plan: CellPlan):

@@ -59,6 +59,8 @@ def main(argv=None):
                          "heads-only training (trunk-only manifest)")
     args = ap.parse_args(argv)
 
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     from ..configs import get_config
     from ..configs.base import ShapeCell
     from ..configs.reduced import reduced as reduce_cfg

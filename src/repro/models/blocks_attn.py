@@ -271,13 +271,16 @@ def mlp_fwd(p, x, ctx: Context, aux):
 # ---------------------------------------------------------------------------
 
 
-def _paged_kv_write(cache, bt, qpos, k_new, v_new, ctx: Context):
+def _paged_kv_write(cache, bt, qpos, k_new, v_new, ctx: Context, layer):
     """Scatter new KV rows through the block table into the local pool
-    shard: ``pool[page, offset]`` with ``page = bt[slot, pos//psz]``.
+    shard: ``pool[layer, page, offset]`` with ``page = bt[slot,
+    pos//psz]`` — in place on the whole stacked pool, so no per-layer
+    slice of it is ever copied.
 
-    cache {k,v} [P_loc, psz, Hkv, dh] (this shard's pages of the pool);
-    bt [B, PPS] int32 global page ids (-1 unmapped); qpos [B, K1]
-    absolute write positions; k_new/v_new [B, K1, Hkv, dh].
+    cache {k,v} [U, P_loc, psz, Hkv*dh] (this shard's pages of the
+    lane-flat pool, every unit); bt [B, PPS] int32 global page ids (-1
+    unmapped); qpos [B, K1] absolute write positions; k_new/v_new
+    [B, K1, Hkv, dh].
 
     Writes whose page is unmapped, resident on another shard, or whose
     position falls past the block table (>= PPS * psz) are DROPPED via
@@ -290,7 +293,7 @@ def _paged_kv_write(cache, bt, qpos, k_new, v_new, ctx: Context):
     scatter needs no duplicate-resolution order.
     """
     ck, cv = cache["k"], cache["v"]
-    P_loc, psz = ck.shape[0], ck.shape[1]
+    P_loc, psz = ck.shape[1], ck.shape[2]
     PPS = bt.shape[1]
     pj = qpos // psz                                        # [B, K1]
     oj = qpos % psz
@@ -298,15 +301,17 @@ def _paged_kv_write(cache, bt, qpos, k_new, v_new, ctx: Context):
     loc, _ = pool_local_pages(g, pool_linear_index(ctx), P_loc)
     # a position past the block table (>= PPS * psz) must also drop
     loc = jnp.where(pj < PPS, loc, P_loc)    # OOB index -> mode="drop"
-    ck = ck.at[loc, oj].set(k_new.astype(ck.dtype), mode="drop")
-    cv = cv.at[loc, oj].set(v_new.astype(cv.dtype), mode="drop")
+    flat = lambda x, c: x.reshape(*x.shape[:2], -1).astype(c.dtype)
+    ck = ck.at[layer, loc, oj].set(flat(k_new, ck), mode="drop")
+    cv = cv.at[layer, loc, oj].set(flat(v_new, cv), mode="drop")
     return {"k": ck, "v": cv}
 
 
-def _paged_kv_gather(cache, bt, ctx: Context):
-    """Gather every local slot's resident pages, ordered by position.
+def _paged_kv_gather(cache, bt, ctx: Context, layer):
+    """Gather every local slot's resident pages of unit ``layer``,
+    ordered by position.
 
-    cache {k,v} [P_loc, psz, Hkv, dh]; bt [B, PPS].  Returns
+    cache {k,v} [U, P_loc, psz, Hkv*dh]; bt [B, PPS].  Returns
     (k [B, PPS*psz, Hkv, dh], v likewise, valid [B, PPS*psz] bool) —
     entry ``i`` of the gathered sequence IS absolute position ``i`` of
     the slot, so the attention partial runs with ``shard_offset=0`` and
@@ -333,12 +338,12 @@ def _paged_kv_gather(cache, bt, ctx: Context):
     out-of-range behavior independent of pool size.
     """
     ck, cv = cache["k"], cache["v"]
-    P_loc, psz, Hkv, dh = ck.shape
+    _, P_loc, psz, _ = ck.shape
     B, PPS = bt.shape
     loc, ok = pool_local_pages(bt, pool_linear_index(ctx), P_loc)
     idx = jnp.where(ok, loc, 0)
-    kg = ck[idx].reshape(B, PPS * psz, Hkv, dh)
-    vg = cv[idx].reshape(B, PPS * psz, Hkv, dh)
+    kg = ck[layer, idx].reshape(B, PPS * psz, -1, ctx.cfg.d_head)
+    vg = cv[layer, idx].reshape(B, PPS * psz, -1, ctx.cfg.d_head)
     return kg, vg, jnp.repeat(ok, psz, axis=1)
 
 
@@ -357,13 +362,14 @@ def _combine_partials(o, lse, ctx: Context):
 
 
 def _paged_attn_combined(q, cache, bt, page_list, qpos, ctx: Context,
-                         window, cap):
+                         window, cap, layer):
     """Paged attention partial + cross-shard combine, both cache walks.
 
     q [B, K1, Hq, dh] (full heads, post-gather); qpos [B, K1] absolute
-    query positions.  ``page_list`` (the engine's compacted per-shard
-    feed, local [B, 1, ppc] after sharding — None on the reference path)
-    selects the fused Pallas kernel: gather -> flash -> LSE partial in
+    query positions; ``layer`` the unit index into the stacked pool.
+    ``page_list`` (the engine's compacted per-shard feed, local
+    [B, 1, ppc] after sharding — None on the reference path) selects
+    the fused Pallas kernel: gather -> flash -> LSE partial in
     one pass over this shard's resident pages, with the int8 wire
     encode fused at the kernel epilogue when the codec is coded.  The
     reference path gathers the full block table (``_paged_kv_gather``)
@@ -377,15 +383,15 @@ def _paged_attn_combined(q, cache, bt, page_list, qpos, ctx: Context,
         clp, clo = clp[:, 0], clo[:, 0]            # [B_loc, ppc]
         if coded:
             wire, scale, lse = kops.paged_flash_decode(
-                q, cache["k"], cache["v"], clp, clo, qpos,
+                q, cache["k"], cache["v"], clp, clo, qpos, layer,
                 window=window, cap=cap, encode_wire=True)
             return boundary.coded_combine_partials(wire, scale, lse,
                                                    ctx.cp, jnp.float32)
         o, lse = kops.paged_flash_decode(q, cache["k"], cache["v"],
-                                         clp, clo, qpos,
+                                         clp, clo, qpos, layer,
                                          window=window, cap=cap)
         return common.combine_decode_partials(o, lse, ctx.cp)
-    k_s, v_s, kv_valid = _paged_kv_gather(cache, bt, ctx)
+    k_s, v_s, kv_valid = _paged_kv_gather(cache, bt, ctx, layer)
     o, lse = common.verify_attention_partial(
         q, k_s, v_s, pos=qpos, shard_offset=0, window=window, cap=cap,
         kv_valid=kv_valid)
@@ -404,9 +410,10 @@ def attn_decode_fwd(p, x, cache, pos, ctx: Context, aux, kind="attn",
 
       dense (single-request serve path): cache {k,v} [B_loc, Ss, Hkv, dh]
         seq-sharded over ctx.cp, indexed ``cache[slot, pos]``;
-      paged (serving engine): cache {k,v} [P_loc, psz, Hkv, dh] — this
-        shard's pages of the shared pool — indexed ``cache[page, offset]``
-        through the per-slot block table rows in ``aux["block_table"]``.
+      paged (serving engine): cache {k,v} [U, P_loc, psz, Hkv*dh] —
+        this shard's pages of the shared pool, every unit — indexed
+        ``cache[aux["layer"], page, offset]`` through the per-slot block
+        table rows in ``aux["block_table"]``.
 
     Returns (x', cache')."""
     cfg = ctx.cfg
@@ -460,7 +467,7 @@ def attn_decode_fwd(p, x, cache, pos, ctx: Context, aux, kind="attn",
         if bt is not None:
             # paged: route the write through the slot's block-table row
             cache = _paged_kv_write(cache, bt, pos[:, None], k_new, v_new,
-                                    ctx)
+                                    ctx, aux["layer"])
         else:
             # dense per-slot cache write: each slot lands at its own
             # position, only on the cp shard that owns it
@@ -488,7 +495,8 @@ def attn_decode_fwd(p, x, cache, pos, ctx: Context, aux, kind="attn",
         # engine feeds them, else the reference full-table gather
         plist = aux.get("page_list")
         o = _paged_attn_combined(q, cache, bt, plist, pos[:, None], ctx,
-                                 window, cfg.attn_softcap)[:, 0]
+                                 window, cfg.attn_softcap,
+                                 aux["layer"])[:, 0]
     else:
         k_s, v_s, kv_valid = cache["k"], cache["v"], None
         off = cp_linear_index(ctx) * cache["k"].shape[1]
@@ -520,7 +528,8 @@ def attn_verify_fwd(p, x, cache, pos, ctx: Context, aux, kind="attn"):
     committed token followed by spec_k draft tokens per slot; pos [B]
     per-slot *base* positions (query j sits at pos+j).  Cache layout is
     dense ([B, Ss, Hkv, dh] seq-sharded over ctx.cp) or the shared page
-    pool ([P_loc, psz, Hkv, dh] + ``aux["block_table"]``), exactly as in
+    pool ([U, P_loc, psz, Hkv*dh] + ``aux["block_table"]`` +
+    ``aux["layer"]``), exactly as in
     ``attn_decode_fwd``.
 
     Every per-token op is shared with ``attn_decode_fwd`` (same norms,
@@ -577,9 +586,11 @@ def attn_verify_fwd(p, x, cache, pos, ctx: Context, aux, kind="attn"):
         # (page, offset) targets are distinct by construction), then
         # attend over the slot's resident pages — fused kernel when the
         # engine feeds the compacted lists, reference gather otherwise
-        cache = _paged_kv_write(cache, bt, qpos, k_new, v_new, ctx)
+        cache = _paged_kv_write(cache, bt, qpos, k_new, v_new, ctx,
+                                aux["layer"])
         o = _paged_attn_combined(q, cache, bt, aux.get("page_list"),
-                                 qpos, ctx, window, cfg.attn_softcap)
+                                 qpos, ctx, window, cfg.attn_softcap,
+                                 aux["layer"])
     else:
         # dense: scatter the K1 new KV rows one position at a time (K1
         # is static and small) — sequential writes keep the update

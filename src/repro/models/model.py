@@ -521,6 +521,49 @@ def forward_prefill(params, batch, ctx: Context, last_pos=None,
     return logits, caches
 
 
+def _split_pool(cache, paged: bool):
+    """(KV page-pool leaves, every other leaf) of a decode cache tree.
+
+    On the paged layout the layer scan CARRIES the pool whole and each
+    unit reads and writes it at its own index (``aux["layer"]``): as a
+    scanned input/output pair XLA would copy the entire pool between
+    the two buffers on every step.  State leaves, and every leaf of the
+    dense layout, stay scanned per unit."""
+    if not paged:
+        return {}, cache
+    pool = {k: c["kv"] for k, c in cache.items() if "kv" in c}
+    rest = {k: {n: v for n, v in c.items() if n != "kv"}
+            for k, c in cache.items()}
+    return pool, rest
+
+
+def _join_pool(pool, rest):
+    return {k: dict(c, kv=pool[k]) if k in pool else c
+            for k, c in rest.items()}
+
+
+def _scan_units(unit_fn, params, x, cache, aux):
+    """Run ``unit_fn(unit_p, cross_p, x, cache_u, aux) -> (x, cache_u)``
+    over the stacked units (``aux["layer"]`` = the unit index); returns
+    (x, new cache)."""
+    paged = "block_table" in aux
+    pool, rest = _split_pool(cache, paged)
+
+    def body(carry, slc):
+        x, pool = carry
+        unit_p, cross_p, rest_u, i = slc
+        x, nc = unit_fn(unit_p, cross_p, x, _join_pool(pool, rest_u),
+                        dict(aux, layer=i))
+        pool, rest_u = _split_pool(nc, paged)
+        return (x, pool), rest_u
+
+    n = jax.tree.leaves(params["units"])[0].shape[0]
+    (x, pool), rest = lax.scan(body, (x, pool),
+                               (params["units"], params.get("cross_units"),
+                                rest, jnp.arange(n, dtype=jnp.int32)))
+    return x, _join_pool(pool, rest)
+
+
 def forward_decode(params, cache, token, pos, ctx: Context, aux_extra=None):
     """One decode step.  token [B_loc] int32; pos scalar int32 or
     [B_loc] per-slot positions (batched serving).
@@ -556,19 +599,9 @@ def forward_decode(params, cache, token, pos, ctx: Context, aux_extra=None):
                                 ctx.tp)[:, None, :]
     x = x.astype(cfg.dtype)
 
-    def body(carry, slc):
-        x = carry
-        unit_p, cross_p, cache_u = slc
-        x, nc = _unit_decode(unit_p, cross_p, x, cache_u, pos, ctx, aux)
-        return x, nc
-
-    cross = params.get("cross_units")
-    if cross is None:
-        x, new_cache = lax.scan(
-            lambda c, s: body(c, (s[0], None, s[1])), x,
-            (params["units"], cache))
-    else:
-        x, new_cache = lax.scan(body, x, (params["units"], cross, cache))
+    x, new_cache = _scan_units(
+        lambda p, xp, x, c, a: _unit_decode(p, xp, x, c, pos, ctx, a),
+        params, x, cache, aux)
 
     h = common.norm(x, params["final_ln"], cfg.norm)
     if ctx.tp_size > 1:
@@ -658,13 +691,9 @@ def forward_verify(params, cache, tokens, pos, ctx: Context, aux_extra=None,
     if params.get("cross_units") is not None:
         raise NotImplementedError("verify step: encoder-decoder unsupported")
 
-    def body(carry, slc):
-        x = carry
-        unit_p, cache_u = slc
-        x, nc = _unit_verify(unit_p, x, cache_u, pos, ctx, aux)
-        return x, nc
-
-    x, new_cache = lax.scan(body, x, (params["units"], cache))
+    x, new_cache = _scan_units(
+        lambda p, _, x, c, a: _unit_verify(p, x, c, pos, ctx, a),
+        params, x, cache, aux)
 
     h = common.norm(x, params["final_ln"], cfg.norm)
     if ctx.tp_size > 1:

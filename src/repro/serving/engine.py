@@ -216,8 +216,8 @@ class EngineConfig:
     attn_kernel: str = "fused"     # paged decode attention path:
     #                                "fused" walks the compacted per-shard
     #                                page lists in one Pallas kernel
-    #                                (kernels/paged_decode.py, interpret
-    #                                mode off-TPU); "reference" scores the
+    #                                (kernels/paged_decode.py; its XLA
+    #                                oracle off-TPU); "reference" scores the
     #                                full block table per shard — the
     #                                oracle the fused path is fuzz-checked
     #                                against
@@ -1241,9 +1241,10 @@ class ServingEngine:
         """Fresh device copy of a host feed array with the step's own
         input sharding (the double buffer: the in-flight step keeps the
         previous copy, the host is free to mutate ``arr`` for the next
-        tick)."""
-        return jax.device_put(np.ascontiguousarray(arr),
-                              NamedSharding(self.mesh, spec))
+        tick).  The host copy is made here, synchronously: a transfer
+        may still read its source after ``device_put`` returns, and on
+        the CPU backend an array can even alias host memory."""
+        return jax.device_put(np.array(arr), NamedSharding(self.mesh, spec))
 
     def _token_feed(self):
         """Device token feed for the next decode dispatch.
@@ -1650,22 +1651,34 @@ class ServingEngine:
 
     # -- introspection -----------------------------------------------------
 
-    def _wire_stats(self, program, ins, tokens_per_step: float,
-                    params=None):
-        """lower+compile ``program`` on its input specs and parse the ICI
-        collectives; (CollectiveStats, total wire bytes per token across
-        the mesh at ``tokens_per_step`` tokens committed per step).
-        ``params`` defaults to the trunk-only tree (what every program
-        except the heads verify step compiles against)."""
-        from ..launch import roofline as RL
-        lowered = program.lower(
+    def _compile(self, program, ins, params=None):
+        """lower+compile ``program`` on its input specs.  ``params``
+        defaults to the trunk-only tree (what every program except the
+        heads verify step compiles against)."""
+        return program.lower(
             self._trunk if params is None else params,
             self.cache.buffers, ins["token"], ins["pos"],
-            ins["bt"], ins["clp"], ins["clo"], ins["temp"], ins["key"])
-        stats = RL.parse_collectives(lowered.compile().as_text())
+            ins["bt"], ins["clp"], ins["clo"], ins["temp"],
+            ins["key"]).compile()
+
+    def _wire_stats(self, compiled, tokens_per_step: float):
+        """Parse the ICI collectives of a compiled step; (CollectiveStats,
+        total wire bytes per token across the mesh at ``tokens_per_step``
+        tokens committed per step)."""
+        from ..launch import roofline as RL
+        stats = RL.parse_collectives(compiled.as_text())
         ndev = self.plan.dp_size * self.plan.tp_size
         per_tok = stats.wire_bytes * ndev / max(tokens_per_step, 1e-9)
         return stats, per_tok
+
+    def compiled_decode_step(self):
+        """The batched decode step, compiled on its input specs: the
+        ``jax.stages.Compiled`` whose HLO text and memory analysis show
+        what the device runs (collectives, whether the fused kernel is
+        there as a ``tpu_custom_call``)."""
+        ins, _ = serve_decode_input_specs(self.plan, self.ecfg.page_size,
+                                          self.num_pages)
+        return self._compile(self._decode, ins)
 
     def decode_wire_stats(self):
         """Parse the compiled batched decode step's collectives.
@@ -1674,9 +1687,8 @@ class ServingEngine:
         bytes of ONE decode step, scaled to total bytes per generated
         token across the mesh.
         """
-        ins, _ = serve_decode_input_specs(self.plan, self.ecfg.page_size,
-                                          self.num_pages)
-        return self._wire_stats(self._decode, ins, self.ecfg.num_slots)
+        return self._wire_stats(self.compiled_decode_step(),
+                                self.ecfg.num_slots)
 
     def verify_wire_stats(self, accepted_len: float = 1.0):
         """Parse the compiled k-token verify step's collectives.
@@ -1694,10 +1706,10 @@ class ServingEngine:
         ins, _ = serve_verify_input_specs(self.plan_ver, self.spec_k,
                                           self.ecfg.page_size,
                                           self.num_pages)
-        return self._wire_stats(
-            self._verify, ins, self.ecfg.num_slots * accepted_len,
-            params=(self.params if self.drafter_kind == "heads"
-                    else None))
+        compiled = self._compile(
+            self._verify, ins,
+            params=self.params if self.drafter_kind == "heads" else None)
+        return self._wire_stats(compiled, self.ecfg.num_slots * accepted_len)
 
     def wire_stream_profile(self):
         """Per-collective wire streams of each compiled step kind.

@@ -1,7 +1,7 @@
 """Pooled KV page cache + slot-major state cache for the serving engine.
 
 Device layout is a true block-table design: attention KV lives in ONE
-shared page pool ``[U, num_pages, page_size, Hkv, dh]`` whose page dim
+shared page pool ``[U, num_pages, page_size, Hkv*dh]`` whose page dim
 is sharded over all mesh axes (dp x tp), and each request slot maps an
 ordered list of pages through a per-slot block-table row
 ``[pages_per_slot]`` of global page ids (-1 = unmapped).  Decode/verify
@@ -669,18 +669,18 @@ def make_insert_fn(plan: CellPlan, plan_pre: CellPlan, mesh,
         def merge(path, c, p):
             p0 = p[:, 0]                              # drop the B=1 dim
             if _is_kv_path(path):
-                # c: pool shard [U, P_loc, psz, Hkv, dh]; gather the one
-                # request's full prefill KV, re-slice it into page
-                # blocks, scatter through the slot's fresh table row
+                # c: pool shard [U, P_loc, psz, Hkv*dh]; gather the one
+                # request's full prefill KV, re-slice it into lane-flat
+                # page blocks, scatter through the slot's fresh table row
                 P_loc = c.shape[1]
                 full = lax.all_gather(p0, tp, axis=1, tiled=True)
                 pps = pages.shape[0]
                 gpos = jnp.arange(pps * psz)
                 src = jnp.take(full, jnp.minimum(gpos, S_pre - 1), axis=1)
-                src = src.reshape(c.shape[0], pps, psz, *c.shape[3:])
                 src = src.astype(c.dtype)
                 if kv_wire == "coded":
-                    src = kv_wire_roundtrip(src)
+                    src = kv_wire_roundtrip(src)     # per (position, head)
+                src = src.reshape(c.shape[0], pps, psz, -1)
                 loc, _ = pool_local_pages(pages, pidx, P_loc)
                 return c.at[:, loc].set(src, mode="drop")
             cur = lax.dynamic_index_in_dim(c, ls, axis=1, keepdims=False)
@@ -702,7 +702,7 @@ def make_migrate_fn(plan: CellPlan, mesh, page_size: int, num_pages: int,
     Compiled once per (src_group, dst_group) pair — the ppermute perm is
     static.  Per KV leaf, each tp shard of the source group gathers its
     resident pages of the source block row into a static
-    ``[U, pages_per_slot, page_size, Hkv, dh]`` staging slab
+    ``[U, pages_per_slot, page_size, Hkv, dh]`` head-space staging slab
     (non-resident rows zeroed), sends it through ONE
     ``boundary.coded_kv_migrate`` over the dp axis (pow2-absmax int8
     wire + f32 scales when ``coded``, plain fp otherwise), and the
@@ -739,12 +739,16 @@ def make_migrate_fn(plan: CellPlan, mesh, page_size: int, num_pages: int,
                 loc_s, ok_s = pool_local_pages(src_bt, pidx, P_loc)
                 stage = jnp.take(c, jnp.minimum(loc_s, P_loc - 1), axis=1)
                 stage = jnp.where(
-                    ok_s.reshape(1, -1, 1, 1, 1), stage,
+                    ok_s.reshape(1, -1, 1, 1), stage,
                     jnp.zeros((), c.dtype))
-                stage = coded_kv_migrate(stage, codec, dp_axis, perm)
+                stage = coded_kv_migrate(
+                    stage.reshape(migrate_stage_shape(plan, page_size,
+                                                      c.shape)),
+                    codec, dp_axis, perm)
                 loc_d, _ = pool_local_pages(dst_bt, pidx, P_loc)
-                return c.at[:, loc_d].set(stage.astype(c.dtype),
-                                          mode="drop")
+                return c.at[:, loc_d].set(
+                    stage.reshape(c.shape[0], -1, *c.shape[2:]).astype(
+                        c.dtype), mode="drop")
             row = lax.dynamic_index_in_dim(c, ls_src, axis=1,
                                            keepdims=False)
             row = lax.ppermute(row, dp_axis, perm)
@@ -837,9 +841,12 @@ class PagedKVCache:
         self._note_peak()
         ins = (self._insert if plan_pre is None
                else self.insert_fn_for(plan_pre))
+        # a snapshot of the row, not a view: the array handed to the
+        # asynchronously dispatched insert may alias host memory, and the
+        # allocator rewrites this row as the slot grows or is recycled
         self.buffers = ins(
             self.buffers, pre_cache, jnp.asarray(slot, jnp.int32),
-            jnp.asarray(self.allocator.block_table[slot], jnp.int32))
+            np.array(self.allocator.block_table[slot], np.int32))
         return slot
 
     def migrate(self, src_slot: int, dst_group: int) -> int:
@@ -862,7 +869,7 @@ class PagedKVCache:
                 src_group, dst_group, coded=self.kv_wire == "coded")
         self.buffers = self._migrate_fns[key](
             self.buffers, jnp.asarray(src_bt),
-            jnp.asarray(alloc.block_table[dst_slot], jnp.int32),
+            np.array(alloc.block_table[dst_slot], np.int32),
             jnp.asarray(src_slot, jnp.int32),
             jnp.asarray(dst_slot, jnp.int32))
         return dst_slot

@@ -40,8 +40,10 @@ def _rand_case(seed, B, K1, Hq, Hkv, dh, P_loc, psz, ppc, n_live=None,
     key = jax.random.PRNGKey(seed)
     kq, kk, kv = jax.random.split(key, 3)
     q = jax.random.normal(kq, (B, K1, Hq, dh), jnp.float32)
-    k_pool = jax.random.normal(kk, (P_loc, psz, Hkv, dh), jnp.float32)
-    v_pool = jax.random.normal(kv, (P_loc, psz, Hkv, dh), jnp.float32)
+    # two units of lane-flat pool pages [2, P_loc, psz, Hkv*dh], the
+    # engine's layout
+    k_pool = jax.random.normal(kk, (2, P_loc, psz, Hkv * dh), jnp.float32)
+    v_pool = jax.random.normal(kv, (2, P_loc, psz, Hkv * dh), jnp.float32)
     clp = np.full((B, ppc), -1, np.int32)
     clo = np.full((B, ppc), -1, np.int32)
     qpos = np.zeros((B, K1), np.int32)
@@ -61,12 +63,12 @@ def _assert_matches_oracle(case, window=0, cap=0.0):
     import jax.numpy as jnp
     from repro.kernels import ops, ref
     q, kp, vp, clp, clo, qpos = case
-    # interpret=True forces the Pallas kernel body (the default off-TPU
+    # interpret=True runs the Pallas kernel body (the default off-TPU
     # dispatch runs the oracle itself — see ops.paged_flash_decode)
-    o, lse = ops.paged_flash_decode(q, kp, vp, clp, clo, qpos,
+    o, lse = ops.paged_flash_decode(q, kp, vp, clp, clo, qpos, 1,
                                     window=window, cap=cap,
                                     interpret=True)
-    oe, le = ref.paged_decode_ref(q, kp, vp, clp, clo, qpos,
+    oe, le = ref.paged_decode_ref(q, kp, vp, clp, clo, qpos, 1,
                                   window=window, cap=cap)
     np.testing.assert_allclose(np.array(o), np.array(oe), atol=2e-5,
                                rtol=2e-5)
@@ -101,9 +103,9 @@ def test_evicted_slot_all_invalid():
                                            dh=16, P_loc=8, psz=8, ppc=3)
     clp = clp.at[1].set(-1)
     clo = clo.at[1].set(-1)
-    o, lse = ops.paged_flash_decode(q, kp, vp, clp, clo, qpos,
+    o, lse = ops.paged_flash_decode(q, kp, vp, clp, clo, qpos, 0,
                                     interpret=True)
-    oe, le = ref.paged_decode_ref(q, kp, vp, clp, clo, qpos)
+    oe, le = ref.paged_decode_ref(q, kp, vp, clp, clo, qpos, 0)
     assert np.isfinite(np.array(o)).all()
     np.testing.assert_allclose(np.array(lse[1]), -1e30)
     np.testing.assert_allclose(np.array(le[1]), -1e30)
@@ -138,14 +140,14 @@ def test_wire_epilogue_matches_quantize_partial():
     from repro.kernels import ops
     q, kp, vp, clp, clo, qpos = _rand_case(5, B=4, K1=2, Hq=4, Hkv=4,
                                            dh=16, P_loc=10, psz=8, ppc=3)
-    o, lse = ops.paged_flash_decode(q, kp, vp, clp, clo, qpos,
+    o, lse = ops.paged_flash_decode(q, kp, vp, clp, clo, qpos, 0,
                                     interpret=True)
     we, se = boundary.quantize_partial(o)
     # both the Pallas epilogue and the off-TPU XLA dispatch must honor
     # the contract
-    for interp in (True, None):
+    for interp in (True, False):
         wire, scale, lse_w = ops.paged_flash_decode(
-            q, kp, vp, clp, clo, qpos, encode_wire=True,
+            q, kp, vp, clp, clo, qpos, 0, encode_wire=True,
             interpret=interp)
         assert wire.dtype == np.int8 and we.dtype == np.int8
         assert scale.shape == se.shape == (4, 2, 4, 1)
@@ -314,8 +316,9 @@ def test_fuzz_allocator_invariants(seed, shards, steps):
                 live[a.alloc(n)] = n
             elif op == 1 and live:
                 s = rng.choice(sorted(live))
-                live[s] = min(64, live[s] + int(rng.randint(1, 17)))
-                a.ensure(s, live[s])
+                n = min(64, live[s] + int(rng.randint(1, 17)))
+                a.ensure(s, n)        # may raise: occupancy stays put
+                live[s] = n
             elif op == 2 and live:
                 s = rng.choice(sorted(live))
                 live[s] = int(rng.randint(1, live[s] + 1))
