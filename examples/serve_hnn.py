@@ -37,8 +37,7 @@ host launches decode step t+1 (feeding step t's sampled tokens straight
 from the device array, no host round-trip) before it syncs step t, so
 scheduling, admission prefill, and page bookkeeping overlap the device
 step.  Greedy token streams are bit-identical to ``--async-depth 0``;
-see ``benchmarks/serve_bench.py`` for the measured per-step latency
-histogram.
+``bench/run.py`` measures the served path on the chip.
 
 Pool pressure + graceful degradation
 ------------------------------------
@@ -48,8 +47,7 @@ cannot map its next page, the engine (by default) evicts + re-queues
 the youngest slot of the starving group and restarts it on re-admit:
 greedy streams stay bit-identical, only latency pays, and the report
 prints the preemption count.  ``--no-preempt`` restores the raw typed
-``PagePoolExhausted``.  For SLO percentiles under trace-driven load and
-injected faults, see ``benchmarks/slo_bench.py``.
+``PagePoolExhausted``.
 
     PYTHONPATH=src python examples/serve_hnn.py --mesh 1x2 --slots 4 \
         --page-size 8 --num-pages 10

@@ -26,9 +26,15 @@ through rounding from ``repro.core.spike``).
 All functions must be called inside ``shard_map`` with the named axes
 bound.  The channel axis is the last axis; ``axis`` selects the token
 axis being gathered/scattered.
+
+The codec's local ops run under the named scope ``spike_codec`` (with
+``encode`` / ``decode`` sub-scopes where the two stay apart), so their
+HLO ``op_name`` metadata — and the device trace — can tell the codec's
+time from the rest of a step.  The collectives stay outside it.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Sequence
 
@@ -70,6 +76,18 @@ HNN_FUSED = BoundaryCodec(mode="spike_fused", cfg=SpikeConfig(T=15))
 HNN_PACK4 = BoundaryCodec(mode="spike_pack4", cfg=SpikeConfig(T=7))
 
 
+#: named scope of the codec's local ops; it holds none of the stream
+#: hints of ``launch.roofline``, so no collective's stream changes
+CODEC_SCOPE = "spike_codec"
+
+
+@contextlib.contextmanager
+def _codec_scope(part: str):
+    """``spike_codec/<part>`` (``encode`` or ``decode``) around codec ops."""
+    with jax.named_scope(CODEC_SCOPE), jax.named_scope(part):
+        yield
+
+
 def _axis_size(axis_name: Axis) -> int:
     if isinstance(axis_name, (tuple, list)):
         n = 1
@@ -86,48 +104,55 @@ def _axis_size(axis_name: Axis) -> int:
 
 def _encode_local(x, params, codec: BoundaryCodec):
     """x float [..., C] -> (wire int tensor, decode closure, counts float)."""
-    cfg = codec.cfg
-    if codec.mode == "int8":
-        amax = jnp.max(jnp.abs(x), axis=tuple(range(x.ndim - 1)), keepdims=True)
-        s = jnp.maximum(amax, 1e-6) / 127.0
-        wire = jnp.round(x / s).astype(jnp.int8)
-        return wire, s, None
-    counts = spike.encode(x, params, cfg)           # float in {-T..T}
-    if codec.mode == "spike_pack4":
-        wire = (counts + cfg.T).astype(jnp.uint8)   # {0..14} fits 4 bits
-        shp = wire.shape
-        wire = spike.pack4(wire.reshape(-1, shp[-1])).reshape(
-            *shp[:-1], shp[-1] // 2)
+    with _codec_scope("encode"):
+        cfg = codec.cfg
+        if codec.mode == "int8":
+            amax = jnp.max(jnp.abs(x), axis=tuple(range(x.ndim - 1)),
+                           keepdims=True)
+            s = jnp.maximum(amax, 1e-6) / 127.0
+            wire = jnp.round(x / s).astype(jnp.int8)
+            return wire, s, None
+        counts = spike.encode(x, params, cfg)           # float in {-T..T}
+        if codec.mode == "spike_pack4":
+            wire = (counts + cfg.T).astype(jnp.uint8)   # {0..14} fits 4 bits
+            shp = wire.shape
+            wire = spike.pack4(wire.reshape(-1, shp[-1])).reshape(
+                *shp[:-1], shp[-1] // 2)
+            return wire, None, counts
+        wire = counts.astype(jnp.int8)
         return wire, None, counts
-    wire = counts.astype(jnp.int8)
-    return wire, None, counts
 
 
 def _decode_local(wire, params, codec: BoundaryCodec, scale_i8, dtype):
     # decode directly in the compute dtype: counts are small integers,
     # exactly representable in bf16, and the f32 intermediate would be the
     # largest transient buffer at the boundary
-    cfg = codec.cfg
-    if codec.mode == "int8":
-        return (wire.astype(jnp.float32) * scale_i8).astype(dtype)
-    if codec.mode == "spike_pack4":
-        shp = wire.shape
-        u = spike.unpack4(wire.reshape(-1, shp[-1])).reshape(
-            *shp[:-1], shp[-1] * 2)
-        counts = u.astype(dtype) - jnp.asarray(cfg.T, dtype)
-    else:
-        counts = wire.astype(dtype)
-    return spike.decode(counts, params, cfg, dtype)
+    with _codec_scope("decode"):
+        cfg = codec.cfg
+        if codec.mode == "int8":
+            return (wire.astype(jnp.float32) * scale_i8).astype(dtype)
+        if codec.mode == "spike_pack4":
+            shp = wire.shape
+            u = spike.unpack4(wire.reshape(-1, shp[-1])).reshape(
+                *shp[:-1], shp[-1] * 2)
+            counts = u.astype(dtype) - jnp.asarray(cfg.T, dtype)
+        else:
+            counts = wire.astype(dtype)
+        return spike.decode(counts, params, cfg, dtype)
 
 
 def _local_roundtrip(x, params, codec: BoundaryCodec):
     """Differentiable local view of encode->wire->decode (for the VJP)."""
     if codec.mode == "int8":
-        amax = jnp.max(jnp.abs(x), axis=tuple(range(x.ndim - 1)), keepdims=True)
-        s = jnp.maximum(amax, 1e-6) / 127.0
-        return spike.round_ste(x / s) * s
-    counts = spike.encode(x, params, codec.cfg)
-    return spike.decode(counts, params, codec.cfg, x.dtype)
+        with jax.named_scope(CODEC_SCOPE):
+            amax = jnp.max(jnp.abs(x), axis=tuple(range(x.ndim - 1)),
+                           keepdims=True)
+            s = jnp.maximum(amax, 1e-6) / 127.0
+            return spike.round_ste(x / s) * s
+    with _codec_scope("encode"):
+        counts = spike.encode(x, params, codec.cfg)
+    with _codec_scope("decode"):
+        return spike.decode(counts, params, codec.cfg, x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -180,14 +205,15 @@ def coded_all_gather(x, params, codec: BoundaryCodec, axis_name: Axis,
             # per-source-chip scales: decode segment-wise
             n = _axis_size(axis_name)
             s8_g = lax.all_gather(s8, axis_name, axis=0, tiled=False)  # [n,1..,C]
-            seg = jnp.moveaxis(
-                wire_g.reshape(wire_g.shape[:axis]
-                               + (n, wire_g.shape[axis] // n)
-                               + wire_g.shape[axis + 1:]), axis, 0)
-            dec = seg.astype(jnp.float32) * s8_g.reshape(
-                (n,) + (1,) * (seg.ndim - 2) + (s8.shape[-1],))
-            dec = jnp.moveaxis(dec, 0, axis)
-            return dec.reshape(wire_g.shape).astype(x.dtype)
+            with _codec_scope("decode"):
+                seg = jnp.moveaxis(
+                    wire_g.reshape(wire_g.shape[:axis]
+                                   + (n, wire_g.shape[axis] // n)
+                                   + wire_g.shape[axis + 1:]), axis, 0)
+                dec = seg.astype(jnp.float32) * s8_g.reshape(
+                    (n,) + (1,) * (seg.ndim - 2) + (s8.shape[-1],))
+                dec = jnp.moveaxis(dec, 0, axis)
+                return dec.reshape(wire_g.shape).astype(x.dtype)
         return _decode_local(wire_g, p, codec, None, x.dtype)
 
     def _fwd(x, theta, log_scale):
@@ -317,17 +343,20 @@ def wire_roundtrip(x, params, codec: BoundaryCodec):
     if codec.mode == "int8":
         # per-token scale (NOT per-channel-over-batch): decode slots must
         # not see each other's magnitudes
-        s = jnp.maximum(jnp.max(jnp.abs(x), axis=-1, keepdims=True),
-                        1e-6) / 127.0
-        return (spike.round_ste(x / s) * s).astype(x.dtype)
+        with jax.named_scope(CODEC_SCOPE):
+            s = jnp.maximum(jnp.max(jnp.abs(x), axis=-1, keepdims=True),
+                            1e-6) / 127.0
+            return (spike.round_ste(x / s) * s).astype(x.dtype)
     if codec.mode == "sparse_topk":
         C = x.shape[-1]
         k = min(max(8, int(C * codec.capacity)), C)
-        c = spike.encode(x, params, codec.cfg)
-        mag = lax.stop_gradient(jnp.abs(c))
-        thresh = jnp.sort(mag, axis=-1)[..., C - k][..., None]
-        mask = (mag >= thresh).astype(c.dtype)
-        return spike.decode(c * mask, params, codec.cfg, x.dtype)
+        with _codec_scope("encode"):
+            c = spike.encode(x, params, codec.cfg)
+            mag = lax.stop_gradient(jnp.abs(c))
+            thresh = jnp.sort(mag, axis=-1)[..., C - k][..., None]
+            mask = (mag >= thresh).astype(c.dtype)
+        with _codec_scope("decode"):
+            return spike.decode(c * mask, params, codec.cfg, x.dtype)
     return _local_roundtrip(x, params, codec)
 
 
@@ -348,19 +377,22 @@ def coded_psum(x, params, codec: BoundaryCodec, axis_name: Axis):
     def _pr(x, theta, log_scale):
         p = {"theta": theta, "log_scale": log_scale}
         if codec.mode == "int8":
-            s = jnp.maximum(jnp.max(jnp.abs(x), axis=-1, keepdims=True),
-                            1e-6) / 127.0
-            wire = jnp.round(x / s).astype(jnp.int8)
+            with _codec_scope("encode"):
+                s = jnp.maximum(jnp.max(jnp.abs(x), axis=-1, keepdims=True),
+                                1e-6) / 127.0
+                wire = jnp.round(x / s).astype(jnp.int8)
             wire_g = lax.all_gather(wire, axis_name, axis=0, tiled=False)
             s_g = lax.all_gather(s, axis_name, axis=0, tiled=False)
-            dec = wire_g.astype(jnp.float32) * s_g.astype(jnp.float32)
+            with _codec_scope("decode"):
+                dec = wire_g.astype(jnp.float32) * s_g.astype(jnp.float32)
             return jnp.sum(dec, axis=0).astype(x.dtype)
         if codec.mode == "sparse_topk":
-            counts = spike.encode(x, p, codec.cfg)
-            wire = counts.astype(jnp.int8)
+            with _codec_scope("encode"):
+                wire = spike.encode(x, p, codec.cfg).astype(jnp.int8)
             wire_g = lax.all_gather(wire, axis_name, axis=0, tiled=False)
-            dec = spike.decode(wire_g.astype(x.dtype), p, codec.cfg,
-                               x.dtype)
+            with _codec_scope("decode"):
+                dec = spike.decode(wire_g.astype(x.dtype), p, codec.cfg,
+                                   x.dtype)
             return jnp.sum(dec, axis=0)
         wire, _, _ = _encode_local(x, p, codec)
         wire_g = lax.all_gather(wire, axis_name, axis=0, tiled=False)

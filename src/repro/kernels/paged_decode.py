@@ -188,6 +188,9 @@ def paged_decode_pallas(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        # the custom call's name in HLO and in the device trace, whatever
+        # jitted function encloses the call
+        name="paged_flash_decode",
     )(jnp.asarray(layer, jnp.int32).reshape(1),
       cl_page.reshape(-1).astype(jnp.int32),
       cl_pos.reshape(-1).astype(jnp.int32),

@@ -1,8 +1,7 @@
 """Batched serving: continuous batching, block-table paged KV (shared
 device page pool), on-device sampling, self-drafting speculative
 decoding, async dispatch/commit decode streams over the spike-coded
-wire, and an SLO harness (trace-driven workloads, fault injection,
-BENCH_serve.json perf trajectory).
+wire, and an SLO harness (trace-driven workloads, fault injection).
 
 ``EngineConfig`` knobs (the ones that shape the serving regime):
 
@@ -117,15 +116,6 @@ per_step``          collective {stream -> bytes} of one compiled step,
                     ``wire_bytes``; unknown step kinds warn instead of
                     silently pricing at 0, and migration bytes pending
                     at drain flush into a terminal ``drain`` event.
-``--cosim``         ``serve_bench`` / ``slo_bench`` flag: feed each
-                    run's step trace through the cycle-level NoC
-                    simulator (``repro.sim.noc.NocSim.simulate_trace``)
-                    — per-codec ``cosim`` block (simulated joules/token,
-                    NoC cycles/us per token, PE/MEM/Router/EMIO energy,
-                    per-stream wire KB) in BENCH_serve.json, plus a
-                    codec ranking by simulated joules per served token.
-                    Schema-gated by ``validate_bench``, which also
-                    enforces cycle-level >= closed-form eq (8) EMIO.
 ==================  =====================================================
 """
 from .draft import NGramDrafter
@@ -136,20 +126,16 @@ from .errors import (CacheOverflowError, EngineConfigError,
                      PagePoolExhausted, SchedulerStall, SlotsExhausted)
 from .kv_cache import PagedKVCache, SlotAllocator
 from .sampling import SamplingConfig, sample, sample_verify
-from .slo import (BENCH_SCHEMA, FaultInjector, FaultPlan, SLOMonitor,
-                  SLOTargets, load_bench, make_bench_payload,
-                  validate_bench, write_bench)
+from .slo import FaultInjector, FaultPlan, SLOMonitor, SLOTargets
 from .workload import (PRESETS, RequestClass, Trace, TracedRequest,
                        make_trace, preset_trace, replay, zoo_mix)
 
-__all__ = ["BENCH_SCHEMA", "CacheOverflowError", "EngineConfig",
-           "EngineConfigError", "FaultInjector", "FaultPlan",
-           "NGramDrafter", "PRESETS", "PagePoolExhausted", "PagedKVCache",
-           "Request", "RequestClass", "SLOMonitor", "SLOTargets",
-           "SamplingConfig", "SchedulerStall", "ServingEngine",
-           "SlotAllocator", "SlotsExhausted", "Trace", "TracedRequest",
-           "WARMUP_RID", "load_bench", "make_bench_payload", "make_trace",
-           "preset_trace", "replay", "sample", "sample_verify",
-           "validate_bench", "write_bench", "zoo_mix",
+__all__ = ["CacheOverflowError", "EngineConfig", "EngineConfigError",
+           "FaultInjector", "FaultPlan", "NGramDrafter", "PRESETS",
+           "PagePoolExhausted", "PagedKVCache", "Request", "RequestClass",
+           "SLOMonitor", "SLOTargets", "SamplingConfig", "SchedulerStall",
+           "ServingEngine", "SlotAllocator", "SlotsExhausted", "Trace",
+           "TracedRequest", "WARMUP_RID", "make_trace", "preset_trace",
+           "replay", "sample", "sample_verify", "zoo_mix",
            "make_engine_decode_step", "make_engine_heads_verify_step",
            "make_engine_prefill_step", "make_engine_verify_step"]

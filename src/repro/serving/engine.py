@@ -127,16 +127,31 @@ per-slot position and overwritten as decode advances).  Families with
 recurrent state (ssm/rnn/hybrid) fold pad tokens into the prefill-final
 state, so their prompts must arrive at exactly ``prefill_len`` tokens;
 the engine enforces this.
+
+Tracing: the scheduler's layers are host spans on the profiler's clock
+(``jax.profiler.TraceAnnotation``, a no-op unless a profiler session is
+active), named ``engine.*``: ``engine.step`` (``tick``) holds
+``engine.dispatch`` — ``engine.admit`` (``rid``, ``prompt_len``; under it
+``engine.prefill`` with its host input ``bytes`` and ``engine.insert``),
+``engine.ensure_pages``, ``engine.stage`` (``bytes``) and
+``engine.launch`` — and ``engine.commit`` — ``engine.commit.wait`` (the
+host blocked on the device) and ``engine.commit.apply`` (bookkeeping;
+``engine.retire`` with the ``rid`` of each request that finishes).
+Counters beside ``tokens_generated``: ``staged_bytes`` (host bytes
+handed to the device: every staged feed and each prefill's host inputs)
+and ``queue_wait_s`` (summed submit -> admit host time).
 """
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import deque
 from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..configs.base import ShapeCell
@@ -684,8 +699,13 @@ class ServingEngine:
         self._admit_seq = 0
         self._key = jax.random.PRNGKey(ecfg.seed)
         self._tick = 0
+        self._ticks = 0                # step() calls: the engine.step tag
         self.tokens_generated = 0
         self.decode_steps = 0
+        self.staged_bytes = 0      # host bytes handed to the device
+        self.queue_wait_s = 0.0    # summed submit -> admit host time
+        #: id(request) -> host time of its submit, until its first admit
+        self._submitted: dict = {}
         self.spec_commits = 0      # tokens committed by verify steps
         self.spec_verifies = 0     # (slot, verify-step) participations
         self.pipelined_dispatches = 0  # verify dispatches launched while
@@ -736,6 +756,7 @@ class ServingEngine:
                 f"(num_pages={self.num_pages}): the request could never "
                 "be admitted")
         self._queue.append(req)
+        self._submitted[id(req)] = time.perf_counter()
         self._emit("on_submit", req.rid, P_len)
 
     def _emit(self, event: str, *args):
@@ -798,45 +819,54 @@ class ServingEngine:
         """
         req, prior, prompt = self._entry_parts(entry)
         P_len = len(prompt)
-        S_pre, prefill_fn, plan_pre = self._prefill_for(P_len)
-        toks = np.zeros((1, S_pre), np.int32)
-        toks[0, :P_len] = np.asarray(prompt, np.int32)
-        first, pre_cache = prefill_fn(
-            self._trunk, toks, np.array([P_len - 1], np.int32),
-            np.array([req.temperature], np.float32), self._next_key())
-        # admit maps ceil(P_len/page_size) pages — O(prompt), not
-        # O(max_seq); each decode step maps the next page on demand
-        slot = self.cache.admit(pre_cache, P_len, plan_pre=plan_pre,
-                                groups=self._prefill_group_ids)
-        if self.ecfg.disagg:
-            # prefill-role group done: hand the paged KV (+ state rows)
-            # to a decode-role group through the coded one-ppermute
-            # migration.  The dispatch-side pre-check (_can_admit_next)
-            # already proved a mirror-capable target exists, so routing
-            # here cannot fail.
-            dst = self._route_migration(slot)
-            src_g = self.cache.allocator.group_of(slot)
-            wire = self.cache.migrate_wire_bytes()
-            slot = self.cache.migrate(slot, dst)
-            self.migrations += 1
-            self.migrated_wire_bytes += wire
-            self._emit("on_migrate", req.rid, src_g, dst, wire)
-        st = _Slot(req, list(prior), None, seq=self._admit_seq,
-                   pending_first=first)
-        self._admit_seq += 1
-        self._slots[slot] = st
-        self._pos[slot] = P_len
-        self._temp[slot] = req.temperature
-        self._tok_dirty.discard(slot)
-        self._tok_pending[slot] = first
-        self.tokens_generated += 1
-        self._emit("on_admit", req.rid, slot)
-        # retirement the host can predict WITHOUT the token value (count
-        # and context limits) applies now so the slot is never scheduled;
-        # the deferred value still folds later for the output/EOS
-        if (self._n_committed(st) >= st.req.max_new_tokens
-                or self._committed_pos(st) >= self.ecfg.max_seq):
-            st.live = False
+        with TraceAnnotation("engine.admit", rid=req.rid, prompt_len=P_len):
+            t_sub = self._submitted.pop(id(req), None)
+            if t_sub is not None:
+                self.queue_wait_s += time.perf_counter() - t_sub
+            S_pre, prefill_fn, plan_pre = self._prefill_for(P_len)
+            toks = np.zeros((1, S_pre), np.int32)
+            toks[0, :P_len] = np.asarray(prompt, np.int32)
+            last = np.array([P_len - 1], np.int32)
+            temp = np.array([req.temperature], np.float32)
+            nbytes = toks.nbytes + last.nbytes + temp.nbytes
+            self.staged_bytes += nbytes
+            with TraceAnnotation("engine.prefill", bytes=nbytes):
+                first, pre_cache = prefill_fn(self._trunk, toks, last, temp,
+                                              self._next_key())
+            # admit maps ceil(P_len/page_size) pages — O(prompt), not
+            # O(max_seq); each decode step maps the next page on demand
+            with TraceAnnotation("engine.insert"):
+                slot = self.cache.admit(pre_cache, P_len, plan_pre=plan_pre,
+                                        groups=self._prefill_group_ids)
+            if self.ecfg.disagg:
+                # prefill-role group done: hand the paged KV (+ state rows)
+                # to a decode-role group through the coded one-ppermute
+                # migration.  The dispatch-side pre-check (_can_admit_next)
+                # already proved a mirror-capable target exists, so routing
+                # here cannot fail.
+                dst = self._route_migration(slot)
+                src_g = self.cache.allocator.group_of(slot)
+                wire = self.cache.migrate_wire_bytes()
+                slot = self.cache.migrate(slot, dst)
+                self.migrations += 1
+                self.migrated_wire_bytes += wire
+                self._emit("on_migrate", req.rid, src_g, dst, wire)
+            st = _Slot(req, list(prior), None, seq=self._admit_seq,
+                       pending_first=first)
+            self._admit_seq += 1
+            self._slots[slot] = st
+            self._pos[slot] = P_len
+            self._temp[slot] = req.temperature
+            self._tok_dirty.discard(slot)
+            self._tok_pending[slot] = first
+            self.tokens_generated += 1
+            self._emit("on_admit", req.rid, slot)
+            # retirement the host can predict WITHOUT the token value (count
+            # and context limits) applies now so the slot is never scheduled;
+            # the deferred value still folds later for the output/EOS
+            if (self._n_committed(st) >= st.req.max_new_tokens
+                    or self._committed_pos(st) >= self.ecfg.max_seq):
+                st.live = False
 
     def _n_committed(self, st: _Slot) -> int:
         """Tokens the request has generated as far as the host is
@@ -905,11 +935,12 @@ class ServingEngine:
             # page can never be corrupted by its previous owner.  Under
             # overlap the freed pages park in the cache's deferred-free
             # limbo until every dispatched snapshot has committed.
-            st.live = False
-            self.cache.evict(slot)
-            self._slots[slot] = None
-            self._retired.append((st.req, st.out))
-            self._emit("on_finish", st.req.rid, len(st.out))
+            with TraceAnnotation("engine.retire", rid=st.req.rid):
+                st.live = False
+                self.cache.evict(slot)
+                self._slots[slot] = None
+                self._retired.append((st.req, st.out))
+                self._emit("on_finish", st.req.rid, len(st.out))
 
     # -- scheduling --------------------------------------------------------
 
@@ -1145,17 +1176,23 @@ class ServingEngine:
         and the operator sized ``num_pages`` below even one request's
         demand.
         """
-        dispatched = self.dispatch()
-        target = self.async_depth if dispatched else 0
-        while len(self._inflight) > target:
-            self.commit()
-        return self._drain_retired()
+        self._ticks += 1
+        with TraceAnnotation("engine.step", tick=self._ticks):
+            dispatched = self.dispatch()
+            target = self.async_depth if dispatched else 0
+            while len(self._inflight) > target:
+                self.commit()
+            return self._drain_retired()
 
     def dispatch(self) -> bool:
         """Admit what fits, then LAUNCH one batched decode (or k-token
         verify) step without waiting for its tokens.  Returns True iff a
         device step was dispatched (its results surface at a later
         ``commit()``)."""
+        with TraceAnnotation("engine.dispatch"):
+            return self._dispatch()
+
+    def _dispatch(self) -> bool:
         while self._queue and self._can_admit_next():
             self._admit(self._queue.popleft())
         if self.spec_k > 0 and self.drafter_kind == "heads":
@@ -1206,17 +1243,20 @@ class ServingEngine:
         release deferred page frees."""
         if not self._inflight:
             raise ValueError("commit: no dispatched step in flight")
-        rec = self._inflight.popleft()
-        out = np.asarray(rec.out)        # host sync: the step has fully
-        #                                  executed once this returns
-        self.cache.note_commit()
-        self.decode_steps += 1
-        if rec.kind == "verify_heads":
-            self._commit_verify_heads(rec, out)
-        elif rec.kind == "verify":
-            self._commit_verify(rec, out)
-        else:
-            self._commit_decode(rec, out)
+        with TraceAnnotation("engine.commit"):
+            rec = self._inflight.popleft()
+            with TraceAnnotation("engine.commit.wait"):
+                out = np.asarray(rec.out)   # host sync: the step has fully
+                #                             executed once this returns
+            with TraceAnnotation("engine.commit.apply"):
+                self.cache.note_commit()
+                self.decode_steps += 1
+                if rec.kind == "verify_heads":
+                    self._commit_verify_heads(rec, out)
+                elif rec.kind == "verify":
+                    self._commit_verify(rec, out)
+                else:
+                    self._commit_decode(rec, out)
 
     def flush(self):
         """Commit every in-flight dispatched step (drain the pipeline)."""
@@ -1243,8 +1283,20 @@ class ServingEngine:
         previous copy, the host is free to mutate ``arr`` for the next
         tick).  The host copy is made here, synchronously: a transfer
         may still read its source after ``device_put`` returns, and on
-        the CPU backend an array can even alias host memory."""
-        return jax.device_put(np.array(arr), NamedSharding(self.mesh, spec))
+        the CPU backend an array can even alias host memory.  Its bytes
+        count in ``staged_bytes``."""
+        arr = np.array(arr)
+        self.staged_bytes += arr.nbytes
+        return jax.device_put(arr, NamedSharding(self.mesh, spec))
+
+    def _stage_step_feeds(self):
+        """Staged (block table, page-list rows, page-list positions,
+        temperatures): the feeds every step kind takes from the host."""
+        f = self._feed_specs
+        return (self._stage(self.cache.block_table, f["bt"]),
+                self._stage(self.cache.page_list_loc, f["clp"]),
+                self._stage(self.cache.page_list_pos, f["clo"]),
+                self._stage(self._temp, f["temp"]))
 
     def _token_feed(self):
         """Device token feed for the next decode dispatch.
@@ -1331,19 +1383,22 @@ class ServingEngine:
         # overlap a slot here may already be finished at a
         # still-uncommitted step (late EOS) — its page comes back
         # through the deferred-free epoch at that step's commit.
-        live = self._ensure_for_step(live, lambda i: int(self._pos[i]) + 1)
+        with TraceAnnotation("engine.ensure_pages"):
+            live = self._ensure_for_step(live,
+                                         lambda i: int(self._pos[i]) + 1)
         if not live:
             return
-        tok = self._token_feed()
-        pos = self._stage(self._pos, self._feed_specs["pos"])
-        bt = self._stage(self.cache.block_table, self._feed_specs["bt"])
-        clp = self._stage(self.cache.page_list_loc, self._feed_specs["clp"])
-        clo = self._stage(self.cache.page_list_pos, self._feed_specs["clo"])
-        temp = self._stage(self._temp, self._feed_specs["temp"])
-        out, self.cache.buffers = self._decode(
-            self._trunk, self.cache.buffers, tok, pos, bt, clp, clo, temp,
-            self._next_key())
-        self.cache.note_dispatch()
+        b0 = self.staged_bytes
+        with TraceAnnotation("engine.stage") as span:
+            tok = self._token_feed()
+            pos = self._stage(self._pos, self._feed_specs["pos"])
+            bt, clp, clo, temp = self._stage_step_feeds()
+            span.set_metadata(bytes=self.staged_bytes - b0)
+        with TraceAnnotation("engine.launch"):
+            out, self.cache.buffers = self._decode(
+                self._trunk, self.cache.buffers, tok, pos, bt, clp, clo,
+                temp, self._next_key())
+            self.cache.note_dispatch()
         self._tok_dev = out
         self._inflight.append(
             _InFlight("decode", [(i, self._slots[i]) for i in live], out))
@@ -1374,29 +1429,31 @@ class ServingEngine:
         # the verify step writes KV at pos..pos+k (clipped at the
         # context end): map those pages before launching; the rejected
         # tail's pages roll back once acceptance is known
-        live = self._ensure_for_step(
-            live, lambda i: min(int(self._pos[i]) + k + 1,
-                                self.ecfg.max_seq))
+        with TraceAnnotation("engine.ensure_pages"):
+            live = self._ensure_for_step(
+                live, lambda i: min(int(self._pos[i]) + k + 1,
+                                    self.ecfg.max_seq))
         if not live:
             return
         drafts = np.zeros((n, k), np.int32)
         for i in live:
             drafts[i] = self._slots[i].drafter.propose(k)
-        tok_in = self._stage(
-            np.concatenate([self._tokens[:, None], drafts], axis=1),
-            self._feed_specs["vtoken"])
-        # this feed just consumed the host token shadow for EVERY slot:
-        # nothing stays dirty for a future feed
-        self._tok_dirty.clear()
-        pos = self._stage(self._pos, self._feed_specs["pos"])
-        bt = self._stage(self.cache.block_table, self._feed_specs["bt"])
-        clp = self._stage(self.cache.page_list_loc, self._feed_specs["clp"])
-        clo = self._stage(self.cache.page_list_pos, self._feed_specs["clo"])
-        temp = self._stage(self._temp, self._feed_specs["temp"])
-        out, self.cache.buffers = self._verify(
-            self._trunk, self.cache.buffers, tok_in, pos, bt, clp, clo,
-            temp, self._next_key())
-        self.cache.note_dispatch()
+        b0 = self.staged_bytes
+        with TraceAnnotation("engine.stage") as span:
+            tok_in = self._stage(
+                np.concatenate([self._tokens[:, None], drafts], axis=1),
+                self._feed_specs["vtoken"])
+            # this feed just consumed the host token shadow for EVERY
+            # slot: nothing stays dirty for a future feed
+            self._tok_dirty.clear()
+            pos = self._stage(self._pos, self._feed_specs["pos"])
+            bt, clp, clo, temp = self._stage_step_feeds()
+            span.set_metadata(bytes=self.staged_bytes - b0)
+        with TraceAnnotation("engine.launch"):
+            out, self.cache.buffers = self._verify(
+                self._trunk, self.cache.buffers, tok_in, pos, bt, clp, clo,
+                temp, self._next_key())
+            self.cache.note_dispatch()
         self._inflight.append(
             _InFlight("verify", [(i, self._slots[i]) for i in live], out,
                       drafts=drafts))
@@ -1451,11 +1508,12 @@ class ServingEngine:
         page-exactly by the chain's last commit (``st.inflight == 0``).
         """
         k = self.spec_k
-        live = self._ensure_for_step(
-            live, lambda i: min(
-                int(self._pos[i])
-                + (k + 1) * (self._slots[i].inflight + 1),
-                self.ecfg.max_seq))
+        with TraceAnnotation("engine.ensure_pages"):
+            live = self._ensure_for_step(
+                live, lambda i: min(
+                    int(self._pos[i])
+                    + (k + 1) * (self._slots[i].inflight + 1),
+                    self.ecfg.max_seq))
         if not live:
             return
         if self._inflight:
@@ -1463,15 +1521,16 @@ class ServingEngine:
             # join the ngram drafter forces is provably gone (tests
             # assert this counter stays 0 for drafter="ngram")
             self.pipelined_dispatches += 1
-        feed, pos = self._verify_feed()
-        bt = self._stage(self.cache.block_table, self._feed_specs["bt"])
-        clp = self._stage(self.cache.page_list_loc, self._feed_specs["clp"])
-        clo = self._stage(self.cache.page_list_pos, self._feed_specs["clo"])
-        temp = self._stage(self._temp, self._feed_specs["temp"])
-        out, feed_next, pos_next, self.cache.buffers = self._verify(
-            self.params, self.cache.buffers, feed, pos, bt, clp, clo,
-            temp, self._next_key())
-        self.cache.note_dispatch()
+        b0 = self.staged_bytes
+        with TraceAnnotation("engine.stage") as span:
+            feed, pos = self._verify_feed()
+            bt, clp, clo, temp = self._stage_step_feeds()
+            span.set_metadata(bytes=self.staged_bytes - b0)
+        with TraceAnnotation("engine.launch"):
+            out, feed_next, pos_next, self.cache.buffers = self._verify(
+                self.params, self.cache.buffers, feed, pos, bt, clp, clo,
+                temp, self._next_key())
+            self.cache.note_dispatch()
         self._vfeed_dev, self._vpos_dev = feed_next, pos_next
         self._inflight.append(
             _InFlight("verify_heads",
@@ -1638,6 +1697,8 @@ class ServingEngine:
         self.flush()
         self.tokens_generated = 0
         self.decode_steps = 0
+        self.staged_bytes = 0
+        self.queue_wait_s = 0.0
         self.spec_commits = 0
         self.spec_verifies = 0
         self.pipelined_dispatches = 0

@@ -1,6 +1,6 @@
-"""Serving observability: per-request SLOs, fault injection, BENCH JSON.
+"""Serving observability: per-request SLOs and fault injection.
 
-Three pieces, all host-side and engine-agnostic (they attach to a
+Two pieces, both host-side and engine-agnostic (they attach to a
 ``ServingEngine`` through its observer hooks plus the ``on_step``
 callback — no hot-path device work):
 
@@ -27,14 +27,6 @@ callback — no hot-path device work):
     request, resume).  All three ride the engine's graceful-degradation
     paths, which the fault fuzz (tests/test_faults.py) gates on greedy
     token-identity with an uninterrupted run.
-
-``BENCH_serve.json`` emitter
-    ``make_bench_payload`` / ``write_bench`` / ``load_bench`` define the
-    in-repo perf-trajectory artifact (schema ``bench_serve/v1``): run
-    config + per-codec tokens/s, stepus/TTFT/TPOT percentiles, wire
-    KB/token, SLO attainment, fault counters.  ``validate_bench`` is
-    the schema gate CI's bench-smoke lane fails on, so the trajectory
-    can't silently rot.
 """
 from __future__ import annotations
 
@@ -48,12 +40,8 @@ import numpy as np
 
 from .engine import WARMUP_RID
 
-__all__ = ["BENCH_SCHEMA", "FaultInjector", "FaultPlan", "SLOMonitor",
-           "SLOTargets", "StepEvent", "load_bench", "make_bench_payload",
-           "percentiles", "validate_bench", "write_bench"]
-
-#: Schema tag every BENCH_serve.json carries; bump on breaking changes.
-BENCH_SCHEMA = "bench_serve/v1"
+__all__ = ["FaultInjector", "FaultPlan", "SLOMonitor", "SLOTargets",
+           "StepEvent", "percentiles"]
 
 
 # ---------------------------------------------------------------------------
@@ -319,7 +307,7 @@ class SLOMonitor:
                 if r.t_finish is not None and r.t_first is not None]
 
     def report(self) -> dict:
-        """Structured SLO report (the per-codec payload of BENCH JSON)."""
+        """Structured SLO report."""
         self._flush_pending_mig()
         fin = self._finished()
         t = self.targets
@@ -524,117 +512,3 @@ class FaultInjector:
             if len(active) >= 1 or engine.queue_depth:
                 engine.resume(engine.suspend())
                 self.injected["suspend"] += 1
-
-
-# ---------------------------------------------------------------------------
-# BENCH_serve.json: the in-repo perf-trajectory artifact
-# ---------------------------------------------------------------------------
-
-_PCTL_KEYS = ("p50", "p95", "p99")
-
-
-def make_bench_payload(run: dict, results: Dict[str, dict],
-                       created: Optional[str] = None) -> dict:
-    """Assemble (and validate) a ``bench_serve/v1`` payload.
-
-    ``run`` is the full engine/workload configuration; ``results`` maps
-    codec name -> per-codec result dict — ``tokens_per_s``, ``step_us``
-    / ``ttft_ms`` / ``tpot_ms`` percentile dicts, ``wire_kb_per_tok``,
-    an ``slo`` block with targets + attainment, and a ``faults`` block
-    (an ``SLOMonitor.report()`` plus ``wire_kb_per_tok`` satisfies it).
-    """
-    payload = {"schema": BENCH_SCHEMA, "run": dict(run),
-               "results": results}
-    if created is not None:
-        payload["created"] = created
-    validate_bench(payload)
-    return payload
-
-
-def _need(obj: dict, key: str, where: str, typ=None):
-    if not isinstance(obj, dict) or key not in obj:
-        raise ValueError(f"BENCH schema: missing {where}.{key}")
-    v = obj[key]
-    if typ is not None and not isinstance(v, typ):
-        raise ValueError(
-            f"BENCH schema: {where}.{key} must be {typ}, got {type(v)}")
-    return v
-
-
-def _need_pctl(obj: dict, key: str, where: str):
-    d = _need(obj, key, where, dict)
-    for p in _PCTL_KEYS:
-        _need(d, p, f"{where}.{key}", (int, float))
-    return d
-
-
-def validate_bench(payload: dict):
-    """Raise ``ValueError`` unless ``payload`` is a valid bench_serve/v1
-    document.  CI's bench-smoke lane runs this against the emitted
-    ``BENCH_serve.json`` so a schema regression fails the build."""
-    if _need(payload, "schema", "payload", str) != BENCH_SCHEMA:
-        raise ValueError(
-            f"BENCH schema: expected {BENCH_SCHEMA!r}, "
-            f"got {payload['schema']!r}")
-    run = _need(payload, "run", "payload", dict)
-    if not run:
-        raise ValueError("BENCH schema: run config must be non-empty")
-    results = _need(payload, "results", "payload", dict)
-    if not results:
-        raise ValueError("BENCH schema: results must be non-empty")
-    for codec, res in results.items():
-        w = f"results[{codec}]"
-        _need(res, "tokens_per_s", w, (int, float))
-        _need(res, "wire_kb_per_tok", w, (int, float))
-        for blk in ("step_us", "ttft_ms", "tpot_ms"):
-            _need_pctl(res, blk, w)
-        slo = _need(res, "slo", w, dict)
-        for k in ("ttft_target_ms", "tpot_target_ms", "attainment"):
-            v = _need(slo, k, f"{w}.slo", (int, float))
-            if k == "attainment" and not 0.0 <= v <= 1.0:
-                raise ValueError(
-                    f"BENCH schema: {w}.slo.attainment {v} not in [0,1]")
-        faults = _need(res, "faults", w, dict)
-        _need(faults, "preemptions", f"{w}.faults", int)
-        if "cosim" in res:
-            _validate_cosim(res["cosim"], f"{w}.cosim")
-
-
-def _validate_cosim(cosim: dict, where: str):
-    """Schema + invariant gate for the optional per-codec ``cosim``
-    block (``--cosim`` benches): cycle-level NoC figures must be
-    present, numeric, and bound the closed-form EMIO figure from
-    above — the simulator models strictly more (per-stream serdes
-    batching, deserialize, hop fill) than eq (8)."""
-    if not isinstance(cosim, dict):
-        raise ValueError(f"BENCH schema: {where} must be a dict")
-    for k in ("joules_per_token", "noc_cycles_per_token",
-              "noc_us_per_token", "emio_closed_form_cycles_per_token"):
-        _need(cosim, k, where, (int, float))
-    energy = _need(cosim, "energy_breakdown", where, dict)
-    for k in ("PE", "MEM", "Router", "EMIO"):
-        _need(energy, k, f"{where}.energy_breakdown", (int, float))
-    if (cosim["noc_cycles_per_token"] + 1e-9
-            < cosim["emio_closed_form_cycles_per_token"]):
-        raise ValueError(
-            f"BENCH schema: {where} cycle-level "
-            f"noc_cycles_per_token={cosim['noc_cycles_per_token']} below "
-            "closed-form emio_closed_form_cycles_per_token="
-            f"{cosim['emio_closed_form_cycles_per_token']} — the "
-            "simulator must upper-bound eq (8)")
-
-
-def write_bench(path: str, payload: dict):
-    """Validate then write ``BENCH_serve.json`` (pretty, stable keys)."""
-    validate_bench(payload)
-    with open(path, "w") as f:
-        json.dump(payload, f, indent=2, sort_keys=True)
-        f.write("\n")
-
-
-def load_bench(path: str) -> dict:
-    """Read + validate a ``BENCH_serve.json``; the CI gate."""
-    with open(path) as f:
-        payload = json.load(f)
-    validate_bench(payload)
-    return payload

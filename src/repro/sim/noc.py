@@ -28,8 +28,7 @@ Two serving-trace front-ends bridge the SLO harness into this model:
     peripheral ports: dependent collectives cannot pack partial serdes
     batches), pipelined deserialization, and hop fill, and contributes
     PE/MEM/Router/EMIO energy per §4.4.  Returns per-step and total
-    cycles + an energy breakdown; ``TraceReport.to_dict()`` is the
-    ``cosim`` block the ``--cosim`` benches embed in BENCH_serve.json.
+    cycles + an energy breakdown (``TraceReport.to_dict()``).
 
 ``emio_cost_from_trace(steps)``
     Closed-form cross-check: prices the aggregate ``wire_bytes`` scalar
@@ -448,9 +447,8 @@ class TraceReport:
         return dict(sorted(out.items()))
 
     def to_dict(self) -> dict:
-        """The per-codec ``cosim`` block of a BENCH_serve/v1 payload
-        (sans the closed-form cross-check figure, which the bench adds
-        from ``emio_cost_from_trace``).  Energy is in normalized-pJ
+        """The simulated cost as a plain dict (the closed-form cross-check
+        figure comes from ``emio_cost_from_trace``).  Energy is in normalized-pJ
         (e_mac = 1.0 pJ at 65 nm), so joules = energy * 1e-12."""
         toks = max(self.tokens, 1)
         return {

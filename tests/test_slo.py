@@ -1,22 +1,19 @@
 """Host-side SLO harness tests: trace generator determinism, monitor
-math under an injectable fake clock, the BENCH_serve.json schema gate,
-and the step-trace -> NoC bridge files.
+math under an injectable fake clock, and the step-trace -> NoC bridge
+files.
 
 Everything here is pure host code — no engine, no jit — so the whole
 file runs in milliseconds and belongs to the tier-1 fast lane.  The
 engine-in-the-loop counterparts (fault identity, drain cleanliness)
 live in tests/test_faults.py.
 """
-import json
 import warnings
 
 import numpy as np
 import pytest
 
 from repro.serving import (FaultPlan, PRESETS, RequestClass, SLOMonitor,
-                           SLOTargets, load_bench, make_bench_payload,
-                           make_trace, preset_trace, validate_bench,
-                           write_bench, zoo_mix)
+                           SLOTargets, make_trace, preset_trace, zoo_mix)
 from repro.serving.slo import load_trace, percentiles
 
 
@@ -418,94 +415,3 @@ def test_fault_plan_probability_sum_validated():
     FaultPlan(p_preempt=0.5, p_replica_loss=0.3, p_suspend=0.2)
     with pytest.raises(ValueError):
         FaultPlan(p_preempt=0.6, p_replica_loss=0.3, p_suspend=0.2)
-
-
-# ---------------------------------------------------------------------------
-# BENCH_serve.json schema
-# ---------------------------------------------------------------------------
-
-
-def _result():
-    pctl = {"p50": 1.0, "p95": 2.0, "p99": 3.0, "mean": 1.5, "n": 4}
-    return {"tokens_per_s": 100.0, "wire_kb_per_tok": 1.5,
-            "step_us": dict(pctl), "ttft_ms": dict(pctl),
-            "tpot_ms": dict(pctl),
-            "slo": {"ttft_target_ms": 500.0, "tpot_target_ms": 100.0,
-                    "ttft_attainment": 1.0, "tpot_attainment": 1.0,
-                    "attainment": 1.0},
-            "faults": {"preemptions": 0, "suspends": 0}}
-
-
-def test_bench_payload_roundtrip(tmp_path):
-    payload = make_bench_payload({"bench": "t", "mesh": "1x1"},
-                                 {"spike_fused": _result()})
-    path = tmp_path / "BENCH_serve.json"
-    write_bench(str(path), payload)
-    assert load_bench(str(path)) == payload
-    # stable output: keys sorted, trailing newline
-    text = path.read_text()
-    assert text.endswith("\n")
-    assert json.loads(text) == payload
-
-
-def test_bench_schema_rejects_bad_payloads(tmp_path):
-    good = make_bench_payload({"bench": "t"}, {"none": _result()})
-    with pytest.raises(ValueError):
-        validate_bench({**good, "schema": "bench_serve/v0"})
-    with pytest.raises(ValueError):
-        validate_bench({**good, "run": {}})
-    with pytest.raises(ValueError):
-        validate_bench({**good, "results": {}})
-    r = _result()
-    del r["ttft_ms"]["p99"]
-    with pytest.raises(ValueError):
-        validate_bench({**good, "results": {"none": r}})
-    r = _result()
-    r["slo"]["attainment"] = 1.5
-    with pytest.raises(ValueError):
-        validate_bench({**good, "results": {"none": r}})
-    r = _result()
-    del r["faults"]
-    with pytest.raises(ValueError):
-        validate_bench({**good, "results": {"none": r}})
-    # load_bench is the CI gate: a corrupt file on disk must raise too
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps({"schema": "bench_serve/v1", "run": {"x": 1},
-                               "results": {"none": {}}}))
-    with pytest.raises(ValueError):
-        load_bench(str(bad))
-
-
-def _cosim(noc_cpt=1500.0, emio_cpt=1200.0):
-    return {"joules_per_token": 1e-9, "noc_cycles_per_token": noc_cpt,
-            "noc_us_per_token": noc_cpt / 200.0,
-            "emio_closed_form_cycles_per_token": emio_cpt,
-            "energy_breakdown": {"PE": 1.0, "MEM": 2.0, "Router": 3.0,
-                                 "EMIO": 4.0}}
-
-
-def test_bench_schema_cosim_block():
-    """The optional per-codec cosim block is schema-gated: required
-    keys, an energy breakdown, and the cycle-level >= closed-form EMIO
-    invariant."""
-    res = {**_result(), "cosim": _cosim()}
-    make_bench_payload({"bench": "t", "cosim": True}, {"none": res})
-    # a result WITHOUT the block still validates (cosim is opt-in)
-    make_bench_payload({"bench": "t"}, {"none": _result()})
-    # missing required key
-    r = {**_result(), "cosim": _cosim()}
-    del r["cosim"]["noc_us_per_token"]
-    with pytest.raises(ValueError):
-        make_bench_payload({"bench": "t"}, {"none": r})
-    # missing energy component
-    r = {**_result(), "cosim": _cosim()}
-    del r["cosim"]["energy_breakdown"]["Router"]
-    with pytest.raises(ValueError):
-        make_bench_payload({"bench": "t"}, {"none": r})
-    # cycle-level simulation must bound the closed-form figure above
-    r = {**_result(), "cosim": _cosim(noc_cpt=1000.0, emio_cpt=1200.0)}
-    with pytest.raises(ValueError, match="upper-bound"):
-        make_bench_payload({"bench": "t"}, {"none": r})
-    # equality (both zero, e.g. a 1x1 mesh) is fine
-    r = {**_result(), "cosim": _cosim(noc_cpt=0.0, emio_cpt=0.0)}
-    make_bench_payload({"bench": "t"}, {"none": r})

@@ -4,12 +4,15 @@ The paged-decode kernel is compiled by the TPU compiler at real widths —
 qwen1.5-0.5b (MHA, dh 64) for decode (K1=1) and spec verify (K1=3), with
 and without the int8 wire epilogue, and gemma2-2b (GQA, dh 256, sliding
 window, softcap) — over a 4096-page pool, and so is the serving engine's
-whole decode step at qwen1.5-0.5b widths.  No chip is needed: the
+whole decode step at qwen1.5-0.5b widths: the kernel's custom call is
+named by the kernel itself, whatever jitted function encloses it, and
+the codec's ops carry their named scope.  No chip is needed: the
 topology is described, not attached.  Nothing touches the TPU library
 while this module is imported; the fixture describes the topology, and
 skips, only once a test of this file runs.
 """
 import os
+import re
 
 import pytest
 
@@ -52,9 +55,18 @@ def _compile_kernel(one_chip, K1, Hq, Hkv, dh, window=0, cap=0.0,
     return fn.lower(*args).compile(), pool
 
 
+def _kernel_calls(hlo: str) -> list:
+    """Names of the Pallas custom calls in an HLO text."""
+    return [re.match(r"\s*(?:ROOT )?%(\S+) = ", ln).group(1)
+            for ln in hlo.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in ln]
+
+
 def _check_kernel(compiled, pool):
     hlo = compiled.as_text()
-    assert 'custom_call_target="tpu_custom_call"' in hlo
+    # compiled inside an anonymous jit: the name is the kernel's own
+    calls = _kernel_calls(hlo)
+    assert calls and all(c.startswith("paged_flash_decode") for c in calls)
     # the pool is read in place: no relayout copy of it in front of the
     # kernel (a page-minor default layout would cost a full pool copy)
     pool_bytes = 2 * pool.size
@@ -73,11 +85,10 @@ def test_paged_decode_compiles_gemma_gqa_window_softcap(one_chip):
                                    window=4096, cap=50.0, encode_wire=True))
 
 
-def test_engine_decode_step_compiles_in_place(topo, monkeypatch):
-    """The engine's full decode step at qwen1.5-0.5b widths on one chip:
-    the kernel is in it, and the 6.4 GB KV pool is donated and updated
-    in place — no step-sized temp buffer (a scanned-in/scanned-out pool
-    or a relayout would each cost a whole pool copy)."""
+@pytest.fixture(scope="module")
+def decode_step(topo):
+    """(compiled decode step, its input shapes): the engine's full decode
+    step at qwen1.5-0.5b widths, spike_fused, on one described chip."""
     import jax
     import numpy as np
     from jax.sharding import Mesh, NamedSharding
@@ -88,9 +99,6 @@ def test_engine_decode_step_compiles_in_place(topo, monkeypatch):
     from repro.serving.engine import make_engine_decode_step
     from repro.serving.sampling import SamplingConfig
 
-    # this process's backend is the CPU; the step is compiled for the
-    # described chip, so its kernel dispatch must take the TPU branch
-    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1),
                 ("data", "model"))
     cfg = get_config("qwen1.5-0.5b", hnn_mode="hnn", codec="spike_fused")
@@ -107,9 +115,22 @@ def test_engine_decode_step_compiles_in_place(topo, monkeypatch):
         jax.tree.map(place, ins[k], isp[k])
         for k in ("cache", "token", "pos", "bt", "clp", "clo", "temp",
                   "key")]
-    step = make_engine_decode_step(cfg, plan, mesh, SamplingConfig(), PAGE,
-                                   PAGES)
-    compiled = step.lower(*args).compile()
+    # this process's backend is the CPU; the step is compiled for the
+    # described chip, so its kernel dispatch must take the TPU branch
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_on_tpu", lambda: True)
+        step = make_engine_decode_step(cfg, plan, mesh, SamplingConfig(),
+                                       PAGE, PAGES)
+        return step.lower(*args).compile(), ins
+
+
+def test_engine_decode_step_compiles_in_place(decode_step):
+    """The kernel is in the decode step, and the 6.4 GB KV pool is donated
+    and updated in place — no step-sized temp buffer (a
+    scanned-in/scanned-out pool or a relayout would each cost a whole
+    pool copy)."""
+    import jax
+    compiled, ins = decode_step
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
     mem = compiled.memory_analysis()
     pool_bytes = sum(x.size * x.dtype.itemsize
@@ -117,3 +138,36 @@ def test_engine_decode_step_compiles_in_place(topo, monkeypatch):
     assert pool_bytes > 6e9
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes // 100
+
+
+def _computations(hlo: str) -> dict:
+    """{computation name -> its body lines} of an HLO text."""
+    out, cur = {}, None
+    for ln in hlo.splitlines():
+        m = re.match(r"(?:ENTRY )?%(\S+) .*\{$", ln)
+        if m:
+            cur = out.setdefault(m.group(1), [])
+        elif ln.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(ln)
+    return out
+
+
+def test_engine_decode_step_names_its_kernel_and_codec(decode_step):
+    """The layer scan's body holds exactly one paged-decode custom call,
+    named ``paged_flash_decode`` by the kernel (the trace reduction finds
+    the kernel and the decode step by that name), and the spike codec's
+    fusions carry the ``spike_codec`` scope in their op_name."""
+    hlo = decode_step[0].as_text()
+    comps = _computations(hlo)
+    bodies = set(re.findall(r"\bwhile\(.*\bbody=%([\w.-]+)", hlo))
+    with_kernel = {name: _kernel_calls("\n".join(lines))
+                   for name, lines in comps.items()
+                   if _kernel_calls("\n".join(lines))}
+    assert with_kernel and set(with_kernel) <= bodies
+    for calls in with_kernel.values():
+        assert len(calls) == 1 and calls[0].startswith("paged_flash_decode")
+    codec = [ln for ln in hlo.splitlines()
+             if " fusion(" in ln and "/spike_codec/" in ln]
+    assert codec
