@@ -138,8 +138,11 @@ active), named ``engine.*``: ``engine.step`` (``tick``) holds
 host blocked on the device) and ``engine.commit.apply`` (bookkeeping;
 ``engine.retire`` with the ``rid`` of each request that finishes).
 Counters beside ``tokens_generated``: ``staged_bytes`` (host bytes
-handed to the device: every staged feed and each prefill's host inputs)
-and ``queue_wait_s`` (summed submit -> admit host time).
+handed to the device: every staged feed and each prefill's host inputs),
+``kv_blocks_walked`` (page blocks one paged-decode kernel call of each
+launched step computes, summed over slots and pool shards; each launch's
+share is the ``kv_blocks`` stat of its ``engine.launch`` span) and
+``queue_wait_s`` (summed submit -> admit host time).
 """
 from __future__ import annotations
 
@@ -703,6 +706,7 @@ class ServingEngine:
         self.tokens_generated = 0
         self.decode_steps = 0
         self.staged_bytes = 0      # host bytes handed to the device
+        self.kv_blocks_walked = 0  # paged-decode kernel blocks computed
         self.queue_wait_s = 0.0    # summed submit -> admit host time
         #: id(request) -> host time of its submit, until its first admit
         self._submitted: dict = {}
@@ -956,8 +960,11 @@ class ServingEngine:
 
     @property
     def idle(self) -> bool:
+        """Nothing queued, live or in flight, and no finished request left
+        for ``step()`` to hand out (a flush outside ``step()``, such as a
+        fault's preempt or suspend, can retire one after the last tick)."""
         return (not self._queue and self.num_active == 0
-                and not self._inflight)
+                and not self._inflight and not self._retired)
 
     def _live_slots(self) -> list:
         return [i for i, s in enumerate(self._slots)
@@ -1289,6 +1296,16 @@ class ServingEngine:
         self.staged_bytes += arr.nbytes
         return jax.device_put(arr, NamedSharding(self.mesh, spec))
 
+    def _launch_span(self):
+        """The ``engine.launch`` span of a step, its ``kv_blocks`` stat the
+        page blocks one of the step's paged-decode kernel calls computes
+        at the pages mapped now (0 on the reference path), counted into
+        ``kv_blocks_walked``."""
+        n = (self.cache.kv_blocks_walked()
+             if self.ecfg.attn_kernel == "fused" else 0)
+        self.kv_blocks_walked += n
+        return TraceAnnotation("engine.launch", kv_blocks=n)
+
     def _stage_step_feeds(self):
         """Staged (block table, page-list rows, page-list positions,
         temperatures): the feeds every step kind takes from the host."""
@@ -1394,7 +1411,7 @@ class ServingEngine:
             pos = self._stage(self._pos, self._feed_specs["pos"])
             bt, clp, clo, temp = self._stage_step_feeds()
             span.set_metadata(bytes=self.staged_bytes - b0)
-        with TraceAnnotation("engine.launch"):
+        with self._launch_span():
             out, self.cache.buffers = self._decode(
                 self._trunk, self.cache.buffers, tok, pos, bt, clp, clo,
                 temp, self._next_key())
@@ -1449,7 +1466,7 @@ class ServingEngine:
             pos = self._stage(self._pos, self._feed_specs["pos"])
             bt, clp, clo, temp = self._stage_step_feeds()
             span.set_metadata(bytes=self.staged_bytes - b0)
-        with TraceAnnotation("engine.launch"):
+        with self._launch_span():
             out, self.cache.buffers = self._verify(
                 self._trunk, self.cache.buffers, tok_in, pos, bt, clp, clo,
                 temp, self._next_key())
@@ -1526,7 +1543,7 @@ class ServingEngine:
             feed, pos = self._verify_feed()
             bt, clp, clo, temp = self._stage_step_feeds()
             span.set_metadata(bytes=self.staged_bytes - b0)
-        with TraceAnnotation("engine.launch"):
+        with self._launch_span():
             out, feed_next, pos_next, self.cache.buffers = self._verify(
                 self.params, self.cache.buffers, feed, pos, bt, clp, clo,
                 temp, self._next_key())
@@ -1698,6 +1715,7 @@ class ServingEngine:
         self.tokens_generated = 0
         self.decode_steps = 0
         self.staged_bytes = 0
+        self.kv_blocks_walked = 0
         self.queue_wait_s = 0.0
         self.spec_commits = 0
         self.spec_verifies = 0

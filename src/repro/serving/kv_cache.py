@@ -67,6 +67,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.boundary import (BoundaryCodec, coded_kv_migrate,
                              kv_wire_bytes, kv_wire_roundtrip)
+from ..kernels.paged_decode import blocks_walked, pages_per_block
 from ..launch.specs import (CellPlan, cache_specs, default_num_pages,
                             migrate_stage_shape, paged_cache_specs,
                             pages_per_slot)
@@ -787,6 +788,14 @@ class PagedKVCache:
             num_pages=self.num_pages, num_groups=groups,
             shards_per_group=shards)
         self.buffers = make_init_fn(plan, mesh, page_size, self.num_pages)()
+        kv = [leaf for path, leaf in
+              jax.tree_util.tree_leaves_with_path(self.buffers)
+              if _is_kv_path(path)]
+        #: list entries one grid step of the fused paged-decode kernel
+        #: walks at this pool's shapes (None: no attention KV)
+        self.kv_block_pages = (pages_per_block(
+            self.allocator.pages_per_shard, page_size, kv[0].shape[-1],
+            kv[0].dtype.itemsize) if kv else None)
         self._insert = make_insert_fn(plan, plan_pre, mesh, page_size,
                                       self.num_pages, kv_wire)
         #: exact-length prefill buckets: one compiled insert per prefill
@@ -936,6 +945,14 @@ class PagedKVCache:
     @property
     def pages_in_limbo(self) -> int:
         return self.allocator.pages_in_limbo
+
+    def kv_blocks_walked(self) -> int:
+        """Page blocks one fused paged-decode kernel call computes at the
+        allocator's current fill, summed over slots and pool shards."""
+        if self.kv_block_pages is None:
+            return 0
+        return blocks_walked(self.allocator._shard_count,
+                             self.kv_block_pages)
 
     # -- memory accounting -------------------------------------------------
 

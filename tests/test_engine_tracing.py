@@ -65,6 +65,38 @@ def test_staged_bytes_match_the_feed_shapes(engine):
     assert eng.staged_bytes == 0 and eng.queue_wait_s == 0.0
 
 
+def test_kv_blocks_walked_counts_each_launch(engine, tmp_path):
+    """One pool shard, page 8, 32-token slots: 4-entry lists, one block
+    each, so every launch computes one block per slot, live or free; the
+    launch span carries the same count as its ``kv_blocks`` stat."""
+    import glob
+    import jax
+    from repro.serving import Request
+    eng = engine
+    assert eng.cache.kv_block_pages == MAX_SEQ // PAGE
+    eng.reset_stats()
+    eng.submit(Request(rid="b", prompt=[1, 2, 3], max_new_tokens=4))
+    jax.profiler.start_trace(str(tmp_path))
+    eng.step()
+    eng.step()
+    jax.profiler.stop_trace()
+    assert eng.decode_steps == 2
+    assert eng.kv_blocks_walked == 2 * SLOTS
+    pb = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                       "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(pb[0])
+    stats = [dict(e.stats).get("kv_blocks")
+             for plane in data.planes if plane.name.startswith("/host:")
+             for ln in plane.lines for e in ln.events
+             if e.name == "engine.launch"]
+    assert stats == [SLOTS, SLOTS]
+    eng.flush()
+    while not eng.idle:
+        eng.step()
+    eng.reset_stats()
+    assert eng.kv_blocks_walked == 0
+
+
 def test_codec_ops_carry_the_named_scope(engine):
     """The compiled decode step's codec ops say ``spike_codec/encode`` and
     ``spike_codec/decode`` in their op_name, and no other op does."""

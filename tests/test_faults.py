@@ -335,6 +335,25 @@ def test_preempt_disabled_pool_exhaustion_propagates():
             eng.step()
 
 
+def test_results_retired_by_a_fault_are_handed_out():
+    """A fault's flush between ticks can retire the last request after
+    its final ``step()``: the engine is not idle until a later ``step()``
+    has handed that result out, so a drive loop that stops at ``idle``
+    loses nothing (and the next run does not receive it).  The fault
+    fuzz found this schedule and seed."""
+    from repro.serving import FaultPlan
+    schedule = [(1, 4)]
+    ref = _reference(schedule)
+    eng = _engine(spec_k=0, async_depth=1)
+    res, _ = _run_with_injector(
+        eng, _reqs(schedule),
+        FaultPlan(seed=62883, p_preempt=0.1, p_replica_loss=0.08,
+                  p_suspend=0.05, max_faults=8))
+    assert res == ref
+    _assert_drained(eng)
+    assert eng.step() == []
+
+
 # ---------------------------------------------------------------------------
 # hypothesis fuzz (skips cleanly when hypothesis is not installed)
 # ---------------------------------------------------------------------------

@@ -31,9 +31,10 @@ from _hyp import HAVE_HYPOTHESIS, given, settings, st
 
 
 def _rand_case(seed, B, K1, Hq, Hkv, dh, P_loc, psz, ppc, n_live=None,
-               partial_last=False):
+               partial_last=False, dense=False):
     """Random pool + well-formed compacted lists (distinct local rows,
-    ascending positions) + per-slot qpos at the write frontier."""
+    ascending positions; consecutive pages with ``dense``) + per-slot
+    qpos at the write frontier."""
     import jax
     import jax.numpy as jnp
     rng = np.random.RandomState(seed)
@@ -51,7 +52,9 @@ def _rand_case(seed, B, K1, Hq, Hkv, dh, P_loc, psz, ppc, n_live=None,
         n = rng.randint(1, ppc + 1) if n_live is None else n_live
         if n:
             clp[b, :n] = rng.choice(P_loc, n, replace=False)
-            clo[b, :n] = np.sort(rng.choice(ppc * 4, n, replace=False)) * psz
+            pages = (np.arange(n) if dense else
+                     np.sort(rng.choice(ppc * 4, n, replace=False)))
+            clo[b, :n] = pages * psz
             last = int(clo[b, n - 1])
             off = rng.randint(0, psz) if partial_last else psz - 1
             qpos[b] = last + max(off, K1 - 1) - np.arange(K1)[::-1]
@@ -76,31 +79,53 @@ def _assert_matches_oracle(case, window=0, cap=0.0):
                                rtol=2e-5)
 
 
-@pytest.mark.parametrize("Hq,Hkv", [(4, 4), (8, 2)])
-@pytest.mark.parametrize("K1", [1, 3])
-def test_kernel_matches_oracle(Hq, Hkv, K1):
+# (page size, list width, id suffix): at page 8 a block holds the whole
+# 4-entry list; at page 32 a block holds 8 entries, so 11 entries take
+# two blocks, the second padded with 5 unmapped ones
+WALKS = [(8, 4, ""), (32, 11, "-blocks")]
+
+
+@pytest.mark.parametrize("K1,Hq,Hkv,psz,ppc", [
+    pytest.param(K1, Hq, Hkv, psz, ppc, id=f"{K1}-{Hq}-{Hkv}{sfx}")
+    for K1 in (1, 3) for Hq, Hkv in ((4, 4), (8, 2))
+    for psz, ppc, sfx in WALKS])
+def test_kernel_matches_oracle(Hq, Hkv, K1, psz, ppc):
     _assert_matches_oracle(_rand_case(0, B=5, K1=K1, Hq=Hq, Hkv=Hkv,
-                                      dh=16, P_loc=12, psz=8, ppc=4))
+                                      dh=16, P_loc=3 * ppc, psz=psz,
+                                      ppc=ppc))
 
 
-@pytest.mark.parametrize("window,cap", [(24, 0.0), (0, 12.0), (16, 8.0)])
-def test_kernel_window_softcap(window, cap):
+@pytest.mark.parametrize("window,cap,psz,ppc", [
+    pytest.param(window, cap, psz, ppc, id=f"{window}-{cap}{sfx}")
+    for window, cap in ((24, 0.0), (0, 12.0), (16, 8.0))
+    for psz, ppc, sfx in WALKS])
+def test_kernel_window_softcap(window, cap, psz, ppc):
+    """At page 32 the pages are consecutive: a window of 24 or 16 ends
+    inside the last entry, in the walk's last block, and masks the block
+    before it whole."""
     _assert_matches_oracle(_rand_case(1, B=4, K1=2, Hq=4, Hkv=4, dh=16,
-                                      P_loc=10, psz=8, ppc=4),
+                                      P_loc=3 * ppc, psz=psz, ppc=ppc,
+                                      n_live=ppc if psz == 32 else None,
+                                      dense=psz == 32),
                            window=window, cap=cap)
 
 
-def test_evicted_slot_all_invalid():
-    """An all ``-1`` list (evicted slot riding in the batch, or a shard
-    holding none of a slot's pages) must stay finite with lse = -1e30:
-    the row's o is a degenerate uniform mean (all scores masked to the
-    same -1e30), but its weight in the cross-shard LSE combine is
-    exp(-1e30 - m) = 0 exactly, so it can never contaminate a real
-    partial — and it must agree with the oracle bit-for-bit in kind."""
-    import jax.numpy as jnp
+@pytest.mark.parametrize("n_live", [11, 16])
+def test_walk_ends_mid_block_and_at_block_boundary(n_live):
+    """20 entries at page 32 are three blocks of 8; every slot's list
+    ends inside its second block (11 mapped) or exactly at its end (16),
+    so no slot walks the third."""
+    _assert_matches_oracle(_rand_case(6, B=3, K1=2, Hq=4, Hkv=4, dh=16,
+                                      P_loc=40, psz=32, ppc=20,
+                                      n_live=n_live))
+
+
+def _assert_evicted_slot_matches(psz, ppc, n_live):
+    """Slot 1 of three holds an all ``-1`` list."""
     from repro.kernels import ops, ref
     q, kp, vp, clp, clo, qpos = _rand_case(2, B=3, K1=2, Hq=4, Hkv=4,
-                                           dh=16, P_loc=8, psz=8, ppc=3)
+                                           dh=16, P_loc=3 * ppc, psz=psz,
+                                           ppc=ppc, n_live=n_live)
     clp = clp.at[1].set(-1)
     clo = clo.at[1].set(-1)
     o, lse = ops.paged_flash_decode(q, kp, vp, clp, clo, qpos, 0,
@@ -112,14 +137,50 @@ def test_evicted_slot_all_invalid():
     np.testing.assert_allclose(np.array(o[1]), np.array(oe[1]), atol=2e-5)
     # combine weight of the dead partial is identically zero
     assert (np.exp(np.array(lse[1], np.float64) - 0.0) == 0.0).all()
+    return o, lse, oe, le
+
+
+def test_evicted_slot_all_invalid():
+    """An all ``-1`` list (evicted slot riding in the batch, or a shard
+    holding none of a slot's pages) must stay finite with lse = -1e30:
+    the row's o is a degenerate uniform mean (all scores masked to the
+    same -1e30), but its weight in the cross-shard LSE combine is
+    exp(-1e30 - m) = 0 exactly, so it can never contaminate a real
+    partial — and it must agree with the oracle bit-for-bit in kind."""
+    _assert_evicted_slot_matches(psz=8, ppc=3, n_live=None)
+
+
+def test_evicted_slot_between_full_walks():
+    """The dead slot between two slots whose 11 entries fill two blocks
+    of 8 at page 32: it walks one block, and its neighbours' partials
+    match the oracle as well."""
+    o, lse, oe, le = _assert_evicted_slot_matches(psz=32, ppc=11,
+                                                  n_live=11)
+    for b in (0, 2):
+        np.testing.assert_allclose(np.array(o[b]), np.array(oe[b]),
+                                   atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(np.array(lse[b]), np.array(le[b]),
+                                   atol=2e-4, rtol=2e-5)
+
+
+def _partial_last_page(psz, ppc):
+    _assert_matches_oracle(_rand_case(3, B=6, K1=1, Hq=4, Hkv=4, dh=16,
+                                      P_loc=3 * ppc, psz=psz, ppc=ppc,
+                                      partial_last=True))
 
 
 def test_partial_last_page():
     """qpos strictly inside the last mapped page: positions past the
     write frontier must not score."""
-    _assert_matches_oracle(_rand_case(3, B=6, K1=1, Hq=4, Hkv=4, dh=16,
-                                      P_loc=9, psz=8, ppc=3,
-                                      partial_last=True))
+    _partial_last_page(psz=8, ppc=3)
+
+
+@pytest.mark.parametrize("ppc", [3, 11])
+def test_partial_last_page_in_block_walk(ppc):
+    """The same at page 32, where a block holds 8 entries: a 3-entry list
+    is shorter than a block (one block of 3), an 11-entry one ends
+    inside its second block."""
+    _partial_last_page(psz=32, ppc=ppc)
 
 
 def test_pool_much_larger_than_live():
@@ -294,6 +355,57 @@ def test_degenerate_single_shard_matches_block_table():
     np.testing.assert_array_equal(a.page_list_pos[s, 0, :4],
                                   np.arange(4) * a.page_size)
     _check_lists(a)
+
+
+def test_kv_blocks_walked_matches_hand_count():
+    """Page 16, 1024-token slots over two shards: 32-entry lists, walked
+    16 entries a block (256 tokens) at 1024 lanes of bf16.  Each (slot,
+    shard) list walks ceil(fill / 16) blocks, and one block when empty:
+    a free or released slot still computes block 0."""
+    from repro.kernels.paged_decode import blocks_walked, pages_per_block
+    a = _mk_alloc(max_seq=1024, page_size=16, num_pages=512)
+    n = pages_per_block(a.pages_per_shard, a.page_size, 1024, 2)
+    assert (a.pages_per_shard, n) == (32, 16)
+    assert blocks_walked(a._shard_count, n) == 4 * 2      # all free
+    s0 = a.alloc(600)              # 38 pages: 19 + 19 -> 2 + 2 blocks
+    s1 = a.alloc(512)              # 32 pages: 16 + 16 -> 1 + 1 (boundary)
+    s2 = a.alloc(1024)             # 64 pages: 32 + 32 -> 2 + 2 (full)
+    assert list(a._shard_count[s0]) == [19, 19]
+    assert list(a._shard_count[s1]) == [16, 16]
+    # the fourth slot is free: 1 + 1
+    assert blocks_walked(a._shard_count, n) == 4 + 2 + 4 + 2
+    a.extend(s1, 1)                # 17 on one shard -> 2 + 1
+    assert blocks_walked(a._shard_count, n) == 4 + 3 + 4 + 2
+    a.free(s0)                     # released: 1 + 1
+    assert blocks_walked(a._shard_count, n) == 2 + 3 + 4 + 2
+    a.free(s2)
+    assert blocks_walked(a._shard_count, n) == 2 + 3 + 2 + 2
+
+
+def test_block_width_at_the_decode_cell_shape():
+    """qwen1.5-0.5b served at page 16 and max_seq 2048 on one chip:
+    128-entry lists of [16, 1024] bf16 pages walk 16 pages a block, and
+    the kernel's grid is (slots, 128 / 16) with 16 pages each of K and V
+    per step."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels.paged_decode import (paged_decode_pallas,
+                                            pages_per_block)
+    assert pages_per_block(128, 16, 1024, 2) == 16
+    sds = jax.ShapeDtypeStruct
+    pool = sds((24, 8192, 16, 1024), jnp.bfloat16)
+    lists = sds((64, 128), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda *a: paged_decode_pallas(
+        *a, encode_wire=True))(sds((64, 1, 16, 64), jnp.bfloat16), pool,
+                               pool, lists, lists, sds((64, 1), jnp.int32),
+                               sds((), jnp.int32))
+    calls = [e for e in jaxpr.jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    gm = calls[0].params["grid_mapping"]
+    assert gm.grid == (64, 8)
+    # q rows, head mask, then 16 page inputs each of K and V
+    assert gm.num_inputs == 2 + 2 * 16
 
 
 @pytest.mark.slow

@@ -2,8 +2,9 @@
 
 The paged-decode kernel is compiled by the TPU compiler at real widths —
 qwen1.5-0.5b (MHA, dh 64) for decode (K1=1) and spec verify (K1=3), with
-and without the int8 wire epilogue, and gemma2-2b (GQA, dh 256, sliding
-window, softcap) — over a 4096-page pool, and so is the serving engine's
+and without the int8 wire epilogue, qwen1.5-4b at its tp=4 per-chip
+widths (5 heads, dh 128), and gemma2-2b (GQA, dh 256, sliding window,
+softcap) — over a 4096-page pool, and so is the serving engine's
 whole decode step at qwen1.5-0.5b widths: the kernel's custom call is
 named by the kernel itself, whatever jitted function encloses it, and
 the codec's ops carry their named scope.  No chip is needed: the
@@ -78,6 +79,11 @@ def _check_kernel(compiled, pool):
 def test_paged_decode_compiles_qwen_widths(one_chip, K1, encode_wire):
     _check_kernel(*_compile_kernel(one_chip, K1, Hq=16, Hkv=16, dh=64,
                                    encode_wire=encode_wire))
+
+
+def test_paged_decode_compiles_qwen4b_tp4_chip_widths(one_chip):
+    _check_kernel(*_compile_kernel(one_chip, 1, Hq=5, Hkv=5, dh=128,
+                                   encode_wire=True))
 
 
 def test_paged_decode_compiles_gemma_gqa_window_softcap(one_chip):
