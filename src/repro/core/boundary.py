@@ -27,15 +27,19 @@ All functions must be called inside ``shard_map`` with the named axes
 bound.  The channel axis is the last axis; ``axis`` selects the token
 axis being gathered/scattered.
 
-The codec's local ops run under the named scope ``spike_codec`` (with
-``encode`` / ``decode`` sub-scopes where the two stay apart), so their
-HLO ``op_name`` metadata — and the device trace — can tell the codec's
-time from the rest of a step.  The collectives stay outside it.
+Every cross-chip exchange — encode, the collective, decode and the
+local sum — runs under the named scope ``spike_exchange``, and the
+codec's local ops inside it under ``spike_codec`` (with ``encode`` /
+``decode`` sub-scopes where the two stay apart), so their HLO
+``op_name`` metadata — and the device trace — can tell the exchange's
+time, and the codec's share of it, from the rest of a step.  The
+collectives stay outside ``spike_codec``.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 from typing import Any, Sequence
 
 import jax
@@ -79,6 +83,20 @@ HNN_PACK4 = BoundaryCodec(mode="spike_pack4", cfg=SpikeConfig(T=7))
 #: named scope of the codec's local ops; it holds none of the stream
 #: hints of ``launch.roofline``, so no collective's stream changes
 CODEC_SCOPE = "spike_codec"
+
+
+#: named scope of each whole cross-chip exchange (``_exchange``); like
+#: the codec's, it holds no stream hint of ``launch.roofline``
+EXCHANGE_SCOPE = "spike_exchange"
+
+
+def _exchange(fn):
+    """Run ``fn``, a cross-chip exchange, under ``EXCHANGE_SCOPE``."""
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope(EXCHANGE_SCOPE):
+            return fn(*args, **kwargs)
+    return scoped
 
 
 @contextlib.contextmanager
@@ -187,6 +205,7 @@ def _roundtrip_bwd(x, theta, log_scale, g, codec: BoundaryCodec):
 # ---------------------------------------------------------------------------
 
 
+@_exchange
 def coded_all_gather(x, params, codec: BoundaryCodec, axis_name: Axis,
                      axis: int = 0):
     """Gather token-sharded activations across ``axis_name``; spike wire."""
@@ -242,6 +261,7 @@ def coded_all_gather(x, params, codec: BoundaryCodec, axis_name: Axis,
 # ---------------------------------------------------------------------------
 
 
+@_exchange
 def coded_psum_scatter(x, params, codec: BoundaryCodec, axis_name: Axis,
                        axis: int = 0):
     """Reduce-scatter partial sums across ``axis_name``.
@@ -360,6 +380,7 @@ def wire_roundtrip(x, params, codec: BoundaryCodec):
     return _local_roundtrip(x, params, codec)
 
 
+@_exchange
 def coded_psum(x, params, codec: BoundaryCodec, axis_name: Axis):
     """All-reduce partial sums across ``axis_name``; coded wire.
 
@@ -429,6 +450,7 @@ def coded_psum(x, params, codec: BoundaryCodec, axis_name: Axis):
 # the channel axis only, never across slots.
 
 
+@_exchange
 def coded_head_all_gather(x, codec: BoundaryCodec, axis_name: Axis,
                           axis: int):
     """Gather head-sharded q/k/v across ``axis_name``; int8 wire when
@@ -463,6 +485,7 @@ def quantize_partial(o):
     return jnp.round(o / s).astype(jnp.int8), s
 
 
+@_exchange
 def coded_combine_partials(wire, scale, lse, axis_names: Axis, out_dtype):
     """LSE-weighted combine of int8-coded decode partials.
 
@@ -491,6 +514,7 @@ def coded_combine_partials(wire, scale, lse, axis_names: Axis, out_dtype):
 # ---------------------------------------------------------------------------
 
 
+@_exchange
 def coded_ppermute(x, params, codec: BoundaryCodec, axis_name: str,
                    perm: Sequence[tuple[int, int]]):
     if codec.mode == "none":
@@ -591,6 +615,7 @@ def kv_wire_bytes(shape, dtype_bytes: int, coded: bool) -> int:
     return n + (n // int(shape[-1])) * 4
 
 
+@_exchange
 def coded_kv_migrate(x, codec: BoundaryCodec, axis_name: str,
                      perm: Sequence[tuple[int, int]]):
     """Send a paged-KV staging buffer ``x [..., dh]`` across the die
@@ -637,6 +662,7 @@ def coded_kv_migrate(x, codec: BoundaryCodec, axis_name: str,
 # ---------------------------------------------------------------------------
 
 
+@_exchange
 def coded_all_to_all(x, params, codec: BoundaryCodec, axis_name: str,
                      split_axis: int, concat_axis: int):
     if codec.mode == "none":

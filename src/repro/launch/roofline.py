@@ -51,6 +51,14 @@ _SHAPE_RE = re.compile(r"(f64|f32|bf16|f16|f8e4m3fn|f8e5m2|s64|u64|s32|u32|"
                        r"s16|u16|s8|u8|pred|s4|u4)\[([0-9,]*)\]")
 _OPNAME_RE = re.compile(r'op_name="([^"]*)"')
 _NPART_RE = re.compile(r"num_partitions=(\d+)")
+#: a computation's header line: ``[ENTRY] %name (params) -> result {``
+_COMP_RE = re.compile(r"^(ENTRY\s+)?%?([^\s(]+)\s*\(.*\)\s*->.*\{\s*$")
+_CALLEE_RE = re.compile(r"\b(calls|to_apply|body|condition|true_computation|"
+                        r"false_computation)=%?([\w.\-]+)")
+_CALLEES_RE = re.compile(r"\b(?:branch_computations|called_computations)="
+                         r"\{([^}]*)\}")
+_TRIP_RE = re.compile(r'"known_trip_count":\{"n":"(\d+)"\}')
+_INT_CONST_RE = re.compile(r"[su]\d+\[\][^=]*\bconstant\((\d+)\)")
 
 #: ``jax.named_scope`` labels (repro.core.boundary) -> semantic stream;
 #: first substring match on the op's ``metadata.op_name`` wins
@@ -115,6 +123,8 @@ class CollectiveOp:
     bytes: float               # ring-model wire bytes (per device)
     coded: bool                # int8/int4 payload: the coded boundary
     op_name: str = ""          # HLO metadata op_name (scope trail)
+    count: int = 1             # executions per run of the module (the
+    #                            trip counts of the loops around it)
 
 
 @dataclasses.dataclass
@@ -124,6 +134,66 @@ class CollectiveStats:
     by_kind: dict
     ops: List[CollectiveOp] = dataclasses.field(default_factory=list)
     by_stream: dict = dataclasses.field(default_factory=dict)
+
+
+def _trip_count(while_line: str, bodies: dict, cond: str) -> int:
+    """Iterations of one ``while``: XLA's ``known_trip_count`` where the
+    module prints it, else the bound of a loop condition that compares
+    the counter with one integer constant (``i < N``, as ``lax.scan`` and
+    ``fori_loop`` lower), else 1."""
+    m = _TRIP_RE.search(while_line)
+    if m:
+        return int(m.group(1))
+    lines = bodies.get(cond, ())
+    consts = [int(c) for ln in lines for c in _INT_CONST_RE.findall(ln)]
+    if len(consts) == 1 and any("direction=LT" in ln for ln in lines):
+        return consts[0]
+    return 1
+
+
+def _executions(lines: list) -> list:
+    """How many times each line of a module's text runs per run of the
+    module: the product of the trip counts of the ``while`` loops on the
+    way from ``ENTRY`` to the line's computation, summed over the ways
+    there.  Lines outside any computation, or in one no call reaches,
+    count once."""
+    comp_of, bodies, order, entry = [None] * len(lines), {}, [], None
+    cur = None
+    for i, line in enumerate(lines):
+        m = _COMP_RE.match(line)
+        if m:
+            cur = m.group(2)
+            bodies[cur] = []
+            order.append(cur)
+            entry = cur if m.group(1) else entry
+        elif cur is not None:
+            if line.startswith("}"):
+                cur = None
+            else:
+                comp_of[i] = cur
+                bodies[cur].append(line)
+    runs = dict.fromkeys(order, 0)
+    if entry is not None:
+        runs[entry] = 1
+    # XLA prints callees before their callers, so walking the module
+    # backwards reaches every caller of a computation before it
+    for comp in reversed(order):
+        if not runs[comp]:
+            continue
+        for line in bodies[comp]:
+            callees = _CALLEE_RE.findall(line)
+            for group in _CALLEES_RE.findall(line):
+                callees += [("", n.strip().lstrip("%"))
+                            for n in group.split(",") if n.strip()]
+            trips = 1
+            if " while(" in line:
+                cond = dict(callees).get("condition")
+                trips = _trip_count(line, bodies, cond)
+            for kind, name in callees:
+                if name in runs:
+                    runs[name] += runs[comp] * (
+                        trips if kind in ("body", "condition") else 1)
+    return [1 if c is None else runs[c] or 1 for c in comp_of]
 
 
 def parse_collectives(hlo_text: str,
@@ -145,6 +215,11 @@ def parse_collectives(hlo_text: str,
     because an assumed group size silently mis-scales wire bytes on any
     mesh whose HLO says otherwise (e.g. tp=4 all-gathers under the old
     hardwired ``default_group=2``).
+
+    A collective inside a loop runs once per iteration: its bytes count
+    as many times as the loops around it run (``CollectiveOp.count``,
+    from ``_executions``), so a layer scan's collectives count once per
+    layer, and the totals are those of one run of the module.
     """
     counts: dict = {}
     by_kind: dict = {}
@@ -154,7 +229,8 @@ def parse_collectives(hlo_text: str,
     unsized = 0
     m = _NPART_RE.search(hlo_text)
     num_partitions = int(m.group(1)) if m else None
-    for line in hlo_text.splitlines():
+    lines = hlo_text.splitlines()
+    for line, runs in zip(lines, _executions(lines)):
         m = _COLL_RE.search(line)
         if not m:
             continue
@@ -184,12 +260,12 @@ def parse_collectives(hlo_text: str,
         nm = _OPNAME_RE.search(line)
         op_name = nm.group(1) if nm else ""
         stream = _stream_of(op_name, kind)
-        counts[kind] = counts.get(kind, 0) + 1
-        by_kind[kind] = by_kind.get(kind, 0.0) + b
-        by_stream[stream] = by_stream.get(stream, 0.0) + b
+        counts[kind] = counts.get(kind, 0) + runs
+        by_kind[kind] = by_kind.get(kind, 0.0) + b * runs
+        by_stream[stream] = by_stream.get(stream, 0.0) + b * runs
         ops.append(CollectiveOp(kind, stream, n, t_bytes, b,
-                                _is_coded(type_str), op_name))
-        total += b
+                                _is_coded(type_str), op_name, runs))
+        total += b * runs
     if unsized:
         warnings.warn(
             f"parse_collectives: {unsized} collective(s) carry no "

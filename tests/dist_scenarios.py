@@ -296,15 +296,17 @@ def scenario_analytic_crosscheck():
     compiled = step.lower(ap, ab).compile()
     stats = RL.parse_collectives(compiled.as_text())
     # structural expectation for the PARSED module (scan bodies counted
-    # once): one unit's boundary+FSDP wire, plus the embedding/LM-head
-    # weight gathers outside the scan (fwd + remat + grad-RS passes)
+    # once per layer): each unit's boundary+FSDP wire in the fwd, remat
+    # and grad-RS passes, plus the embedding/LM-head weight gathers
+    # outside the scan
     w = AN.wire_bytes_per_elem(cfg.codec)
     tp, dp = 4, 2
     B_loc, S = 8 // dp, 512
     per_unit = AN.block_cost("attn", cfg, B_loc, S, tp, dp, w).wire
     D, Vp = cfg.d_model, cfg.vocab_padded(tp)
     emb_gather = (dp - 1) / dp * (Vp * D * 2.0 / tp)   # per fwd pass
-    expected = per_unit * 3 + 2 * emb_gather * 4       # embed+head, ~4 passes
+    expected = (per_unit * 3 * cfg.n_layers
+                + 2 * emb_gather * 4)                  # embed+head, ~4 passes
     ratio = stats.wire_bytes / max(expected, 1.0)
     assert 0.3 <= ratio <= 3.0, (stats.wire_bytes, expected, ratio)
     print(f"analytic crosscheck OK: parsed={stats.wire_bytes/1e6:.1f}MB "

@@ -164,3 +164,66 @@ def test_collective_op_is_frozen_record():
     op = CollectiveOp("all-gather", "psum", 2, 8.0, 4.0, False)
     with pytest.raises(Exception):
         op.bytes = 1.0
+
+
+# ---------------------------------------------------------------------------
+# collectives inside loops
+# ---------------------------------------------------------------------------
+
+#: a layer scan whose body gathers once directly and once inside a fusion,
+#: a second loop whose trip count only its condition states, and a gather
+#: outside both; the helper computations are printed before their callers
+LOOPS = HEADER + """
+%gather_fusion (p0: s8[2]) -> s8[8] {
+  %p0 = s8[2]{0} parameter(0)
+  ROOT %g = s8[8]{0} all-gather(s8[2]{0} %p0), replica_groups={{0,1,2,3}}, dimensions={0}, metadata={op_name="jit(step)/while/body/spike_exchange/all_gather"}
+}
+
+%body (b: (s32[], s8[2])) -> (s32[], s8[2]) {
+  %b = (s32[], s8[2]{0}) parameter(0)
+  %x = s8[2]{0} get-tuple-element(%b), index=1
+  %a = s8[8]{0} all-gather(s8[2]{0} %x), replica_groups={{0,1,2,3}}, dimensions={0}
+  %f = s8[8]{0} fusion(s8[2]{0} %x), kind=kOutput, calls=%gather_fusion
+  ROOT %t = (s32[], s8[2]{0}) tuple(%b)
+}
+
+%cond (c: (s32[], s8[2])) -> pred[] {
+  %c = (s32[], s8[2]{0}) parameter(0)
+  %i = s32[] get-tuple-element(%c), index=0
+  %n = s32[]{:T(128)} constant(40)
+  ROOT %lt = pred[] compare(s32[] %i, s32[] %n), direction=LT
+}
+
+%body2 (b2: (s32[], f32[4])) -> (s32[], f32[4]) {
+  %b2 = (s32[], f32[4]{0}) parameter(0)
+  %y = f32[4]{0} get-tuple-element(%b2), index=1
+  %r = f32[4]{0} all-reduce(f32[4]{0} %y), replica_groups={{0,1}}, to_apply=%add
+  ROOT %t2 = (s32[], f32[4]{0}) tuple(%b2)
+}
+
+%cond2 (c2: (s32[], f32[4])) -> pred[] {
+  %c2 = (s32[], f32[4]{0}) parameter(0)
+  ROOT %lt2 = pred[] compare(s32[] %c2, s32[] %c2), direction=LT
+}
+
+ENTRY %main (p: s8[2], q: f32[4]) -> s8[8] {
+  %p = s8[2]{0} parameter(0)
+  %w = (s32[], s8[2]{0}) while(%p), condition=%cond, body=%body
+  %w2 = (s32[], f32[4]{0}) while(%q), condition=%cond2, body=%body2, backend_config={"known_trip_count":{"n":"3"}}
+  ROOT %o = s8[8]{0} all-gather(s8[2]{0} %p), replica_groups={{0,1,2,3}}, dimensions={0}
+}
+"""
+
+
+def test_a_collective_in_a_loop_counts_once_per_iteration():
+    st = parse_collectives(LOOPS)
+    assert [op.count for op in st.ops] == [40, 40, 3, 1]
+    assert st.counts == {"all-gather": 81, "all-reduce": 3}
+    # gather: 8 B result, 3/4 of it received; all-reduce: 2 * 16 B * 1/2
+    assert st.by_kind == {"all-gather": pytest.approx(81 * 6.0),
+                          "all-reduce": pytest.approx(3 * 16.0)}
+    assert st.wire_bytes == pytest.approx(81 * 6.0 + 3 * 16.0)
+    assert sum(st.by_stream.values()) == pytest.approx(st.wire_bytes)
+    # without ENTRY nothing is known of the calls: each op counts once
+    st1 = parse_collectives(LOOPS.replace("ENTRY ", ""))
+    assert [op.count for op in st1.ops] == [1, 1, 1, 1]
