@@ -62,7 +62,9 @@ def paged_decode_ref(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     valid = cl_page >= 0                                     # [B, ppc]
     safe = jnp.where(valid, cl_page, 0)
     k_s = k_pool[layer, safe].astype(jnp.float32)  # [B,ppc,psz,Hkv*dh]
-    v_s = v_pool[layer, safe].astype(jnp.float32)
+    # an unmapped entry's V is zero, as in the kernel, which fetches none
+    v_s = jnp.where(valid[:, :, None, None], v_pool[layer, safe],
+                    0).astype(jnp.float32)
     k_s = k_s.reshape(B, ppc * psz, Hkv, dh)
     v_s = v_s.reshape(B, ppc * psz, Hkv, dh)
     if Hkv != Hq:

@@ -141,8 +141,10 @@ Counters beside ``tokens_generated``: ``staged_bytes`` (host bytes
 handed to the device: every staged feed and each prefill's host inputs),
 ``kv_blocks_walked`` (page blocks one paged-decode kernel call of each
 launched step computes, summed over slots and pool shards; each launch's
-share is the ``kv_blocks`` stat of its ``engine.launch`` span) and
-``queue_wait_s`` (summed submit -> admit host time).
+share is the ``kv_blocks`` stat of its ``engine.launch`` span),
+``kv_pages_fetched`` (the pages of K, and as many of V, that such a call
+copies: the mapped ones; each launch's share is its ``kv_pages`` stat)
+and ``queue_wait_s`` (summed submit -> admit host time).
 """
 from __future__ import annotations
 
@@ -707,6 +709,7 @@ class ServingEngine:
         self.decode_steps = 0
         self.staged_bytes = 0      # host bytes handed to the device
         self.kv_blocks_walked = 0  # paged-decode kernel blocks computed
+        self.kv_pages_fetched = 0  # paged-decode kernel pages copied
         self.queue_wait_s = 0.0    # summed submit -> admit host time
         #: id(request) -> host time of its submit, until its first admit
         self._submitted: dict = {}
@@ -1297,14 +1300,18 @@ class ServingEngine:
         return jax.device_put(arr, NamedSharding(self.mesh, spec))
 
     def _launch_span(self):
-        """The ``engine.launch`` span of a step, its ``kv_blocks`` stat the
-        page blocks one of the step's paged-decode kernel calls computes
-        at the pages mapped now (0 on the reference path), counted into
-        ``kv_blocks_walked``."""
-        n = (self.cache.kv_blocks_walked()
-             if self.ecfg.attn_kernel == "fused" else 0)
-        self.kv_blocks_walked += n
-        return TraceAnnotation("engine.launch", kv_blocks=n)
+        """The ``engine.launch`` span of a step, its ``kv_blocks`` and
+        ``kv_pages`` stats the page blocks one of the step's paged-decode
+        kernel calls computes and the pages it copies at the pages mapped
+        now (0 on the reference path), counted into ``kv_blocks_walked``
+        and ``kv_pages_fetched``."""
+        fused = self.ecfg.attn_kernel == "fused"
+        blocks = self.cache.kv_blocks_walked() if fused else 0
+        pages = self.cache.kv_pages_fetched() if fused else 0
+        self.kv_blocks_walked += blocks
+        self.kv_pages_fetched += pages
+        return TraceAnnotation("engine.launch", kv_blocks=blocks,
+                               kv_pages=pages)
 
     def _stage_step_feeds(self):
         """Staged (block table, page-list rows, page-list positions,
@@ -1716,6 +1723,7 @@ class ServingEngine:
         self.decode_steps = 0
         self.staged_bytes = 0
         self.kv_blocks_walked = 0
+        self.kv_pages_fetched = 0
         self.queue_wait_s = 0.0
         self.spec_commits = 0
         self.spec_verifies = 0

@@ -67,7 +67,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.boundary import (BoundaryCodec, coded_kv_migrate,
                              kv_wire_bytes, kv_wire_roundtrip)
-from ..kernels.paged_decode import blocks_walked, pages_per_block
+from ..kernels.paged_decode import (blocks_walked, pages_fetched,
+                                    pages_per_block)
 from ..launch.specs import (CellPlan, cache_specs, default_num_pages,
                             migrate_stage_shape, paged_cache_specs,
                             pages_per_slot)
@@ -953,6 +954,14 @@ class PagedKVCache:
             return 0
         return blocks_walked(self.allocator._shard_count,
                              self.kv_block_pages)
+
+    def kv_pages_fetched(self) -> int:
+        """Pages of K (and as many of V) one fused paged-decode kernel call
+        copies at the allocator's current fill: the mapped list entries,
+        summed over slots and pool shards."""
+        if self.kv_block_pages is None:
+            return 0
+        return pages_fetched(self.allocator._shard_count)
 
     # -- memory accounting -------------------------------------------------
 

@@ -65,36 +65,47 @@ def test_staged_bytes_match_the_feed_shapes(engine):
     assert eng.staged_bytes == 0 and eng.queue_wait_s == 0.0
 
 
-def test_kv_blocks_walked_counts_each_launch(engine, tmp_path):
+@pytest.mark.parametrize("prompt_len,pages", [(3, 1), (8, 2)])
+def test_kv_blocks_walked_counts_each_launch(engine, tmp_path, prompt_len,
+                                             pages):
     """One pool shard, page 8, 32-token slots: 4-entry lists, one block
     each, so every launch computes one block per slot, live or free; the
-    launch span carries the same count as its ``kv_blocks`` stat."""
+    launch span carries the same count as its ``kv_blocks`` stat.  The
+    kernel copies the mapped pages alone, the allocator's fill: a 3-token
+    prompt decodes at positions 3 and 4, inside its first page, an
+    8-token one at 8 and 9, on a second page mapped for the first step;
+    the ``kv_pages`` stat of each launch says so, and
+    ``kv_pages_fetched`` sums them."""
     import glob
     import jax
     from repro.serving import Request
     eng = engine
     assert eng.cache.kv_block_pages == MAX_SEQ // PAGE
     eng.reset_stats()
-    eng.submit(Request(rid="b", prompt=[1, 2, 3], max_new_tokens=4))
+    eng.submit(Request(rid="b", prompt=list(range(1, prompt_len + 1)),
+                       max_new_tokens=4))
     jax.profiler.start_trace(str(tmp_path))
     eng.step()
+    assert eng.cache.kv_pages_fetched() == eng.cache.allocator.pages_in_use
     eng.step()
     jax.profiler.stop_trace()
     assert eng.decode_steps == 2
     assert eng.kv_blocks_walked == 2 * SLOTS
+    assert eng.kv_pages_fetched == 2 * pages
     pb = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
                        "*.xplane.pb"))
     data = jax.profiler.ProfileData.from_file(pb[0])
-    stats = [dict(e.stats).get("kv_blocks")
+    stats = [dict(e.stats)
              for plane in data.planes if plane.name.startswith("/host:")
              for ln in plane.lines for e in ln.events
              if e.name == "engine.launch"]
-    assert stats == [SLOTS, SLOTS]
+    assert [st.get("kv_blocks") for st in stats] == [SLOTS, SLOTS]
+    assert [st.get("kv_pages") for st in stats] == [pages, pages]
     eng.flush()
     while not eng.idle:
         eng.step()
     eng.reset_stats()
-    assert eng.kv_blocks_walked == 0
+    assert eng.kv_blocks_walked == 0 and eng.kv_pages_fetched == 0
 
 
 def test_codec_ops_carry_the_named_scope(engine):

@@ -132,6 +132,7 @@ def _assert_evicted_slot_matches(psz, ppc, n_live):
                                     interpret=True)
     oe, le = ref.paged_decode_ref(q, kp, vp, clp, clo, qpos, 0)
     assert np.isfinite(np.array(o)).all()
+    assert (np.array(o[1]) == 0).all()
     np.testing.assert_allclose(np.array(lse[1]), -1e30)
     np.testing.assert_allclose(np.array(le[1]), -1e30)
     np.testing.assert_allclose(np.array(o[1]), np.array(oe[1]), atol=2e-5)
@@ -143,10 +144,11 @@ def _assert_evicted_slot_matches(psz, ppc, n_live):
 def test_evicted_slot_all_invalid():
     """An all ``-1`` list (evicted slot riding in the batch, or a shard
     holding none of a slot's pages) must stay finite with lse = -1e30:
-    the row's o is a degenerate uniform mean (all scores masked to the
-    same -1e30), but its weight in the cross-shard LSE combine is
-    exp(-1e30 - m) = 0 exactly, so it can never contaminate a real
-    partial — and it must agree with the oracle bit-for-bit in kind."""
+    no page is fetched, so the row's o is exactly 0 (every score masked
+    to the same -1e30, every V row zero), and its weight in the
+    cross-shard LSE combine is exp(-1e30 - m) = 0 exactly, so it can
+    never contaminate a real partial — and it must agree with the
+    oracle."""
     _assert_evicted_slot_matches(psz=8, ppc=3, n_live=None)
 
 
@@ -161,6 +163,48 @@ def test_evicted_slot_between_full_walks():
                                    atol=2e-5, rtol=2e-5)
         np.testing.assert_allclose(np.array(lse[b]), np.array(le[b]),
                                    atol=2e-4, rtol=2e-5)
+
+
+@pytest.mark.parametrize("encode_wire", [False, True])
+def test_kernel_on_poisoned_memory(encode_wire):
+    """The kernel copies only mapped pages, so the rest of a block's ring
+    buffer holds whatever VMEM held: here NaN, as the TPU interpreter
+    fills uninitialised memory, with its race detector on.  20 entries
+    at page 32 are three blocks of 8; slot 0 fills two blocks whole,
+    slot 1 after it ends mid-block (11), slot 2 maps nothing, slot 3
+    ends at a block boundary (16) and slot 4 mid-block (5).  Every
+    output is finite and matches the oracle, and no copy races a read:
+    the unfetched V rows are zeroed, and each slot's first block, fetched
+    while its predecessor's last is scored, has landed before use."""
+    from jax.experimental.pallas import tpu as pltpu
+    from jax._src.pallas.mosaic.interpret import (
+        interpret_pallas_call as tpu_interpret)
+    from repro.kernels import ref
+    from repro.kernels.paged_decode import paged_decode_pallas
+    q, kp, vp, clp, clo, qpos = _rand_case(7, B=5, K1=2, Hq=4, Hkv=4,
+                                           dh=16, P_loc=100, psz=32,
+                                           ppc=20, n_live=16)
+    for b, n in enumerate((16, 11, 0, 16, 5)):
+        clp = clp.at[b, n:].set(-1)
+        clo = clo.at[b, n:].set(-1)
+    outs = paged_decode_pallas(
+        q, kp, vp, clp, clo, qpos, 1, encode_wire=encode_wire,
+        interpret=pltpu.InterpretParams(uninitialized_memory="nan",
+                                        detect_races=True))
+    assert not tpu_interpret.races.races_found
+    oe, le = ref.paged_decode_ref(q, kp, vp, clp, clo, qpos, 1)
+    for x in outs:
+        assert np.isfinite(np.array(x, np.float32)).all()
+    lse = np.array(outs[-1])
+    np.testing.assert_allclose(lse, np.array(le), atol=2e-4, rtol=2e-5)
+    if encode_wire:
+        wire, scale = np.array(outs[0], np.float32), np.array(outs[1])
+        # the decoded wire lies within one quantization step of the oracle
+        assert (np.abs(wire * scale - np.array(oe)) <= scale + 1e-6).all()
+    else:
+        np.testing.assert_allclose(np.array(outs[0]), np.array(oe),
+                                   atol=2e-5, rtol=2e-5)
+    assert (np.array(outs[0])[2] == 0).all()      # slot 2: nothing mapped
 
 
 def _partial_last_page(psz, ppc):
@@ -382,14 +426,38 @@ def test_kv_blocks_walked_matches_hand_count():
     assert blocks_walked(a._shard_count, n) == 2 + 3 + 2 + 2
 
 
+def test_kv_pages_fetched_matches_hand_count():
+    """The same slots: the kernel copies each list's mapped entries and
+    nothing else, where the walk's blocks span ``blocks_walked x 16``
+    entries; the gap is the stand-in fetches a block walk would make."""
+    from repro.kernels.paged_decode import (blocks_walked, pages_fetched,
+                                            pages_per_block)
+    a = _mk_alloc(max_seq=1024, page_size=16, num_pages=512)
+    n = pages_per_block(a.pages_per_shard, a.page_size, 1024, 2)
+    assert pages_fetched(a._shard_count) == 0               # all free
+    s0 = a.alloc(600)              # 38 pages: 19 + 19
+    s1 = a.alloc(512)              # 32 pages: 16 + 16 (boundary)
+    s2 = a.alloc(1024)             # 64 pages: 32 + 32 (full)
+    assert pages_fetched(a._shard_count) == 38 + 32 + 64 == a.pages_in_use
+    # 12 blocks of 16 entries span 192: 58 of them are not fetched
+    assert blocks_walked(a._shard_count, n) * n == 192
+    a.extend(s1, 1)                # one more page on one shard
+    assert pages_fetched(a._shard_count) == 38 + 33 + 64
+    a.free(s0)
+    a.free(s2)
+    assert pages_fetched(a._shard_count) == 33 == a.pages_in_use
+
+
 def test_block_width_at_the_decode_cell_shape():
     """qwen1.5-0.5b served at page 16 and max_seq 2048 on one chip:
-    128-entry lists of [16, 1024] bf16 pages walk 16 pages a block, and
-    the kernel's grid is (slots, 128 / 16) with 16 pages each of K and V
-    per step."""
+    128-entry lists of [16, 1024] bf16 pages walk 16 pages a block; the
+    kernel's grid is one step per slot, the pools stay in HBM (the kernel
+    copies its own pages), and its K and V rings hold ``RING_DEPTH``
+    blocks of 16 pages each, one DMA semaphore per buffer."""
     import jax
     import jax.numpy as jnp
-    from repro.kernels.paged_decode import (paged_decode_pallas,
+    from jax.experimental import pallas as pl
+    from repro.kernels.paged_decode import (RING_DEPTH, paged_decode_pallas,
                                             pages_per_block)
     assert pages_per_block(128, 16, 1024, 2) == 16
     sds = jax.ShapeDtypeStruct
@@ -403,9 +471,18 @@ def test_block_width_at_the_decode_cell_shape():
              if e.primitive.name == "pallas_call"]
     assert len(calls) == 1
     gm = calls[0].params["grid_mapping"]
-    assert gm.grid == (64, 8)
-    # q rows, head mask, then 16 page inputs each of K and V
-    assert gm.num_inputs == 2 + 2 * 16
+    assert gm.grid == (64,)
+    # q rows, head mask, then the two pools, whole and in HBM
+    assert gm.num_inputs == 4
+    pools = gm.block_mappings[2:4]
+    assert all(m.block_aval.memory_space == pl.ANY for m in pools)
+    assert all(m.block_aval.shape == pool.shape for m in pools)
+    scratch = [v.aval for v in calls[0].params["jaxpr"].invars[
+        -gm.num_scratch_operands:]]
+    assert [a.shape for a in scratch[:3]] == [
+        (RING_DEPTH, 16 * 16, 1024), (RING_DEPTH, 16 * 16, 1024),
+        (2, RING_DEPTH)]
+    assert scratch[0].dtype == scratch[1].dtype == jnp.bfloat16
 
 
 @pytest.mark.slow

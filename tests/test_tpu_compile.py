@@ -3,9 +3,10 @@
 The paged-decode kernel is compiled by the TPU compiler at real widths —
 qwen1.5-0.5b (MHA, dh 64) for decode (K1=1) and spec verify (K1=3), with
 and without the int8 wire epilogue, qwen1.5-4b at its tp=4 per-chip
-widths (5 heads, dh 128), and gemma2-2b (GQA, dh 256, sliding window,
-softcap) — over a 4096-page pool, and so is the serving engine's
-whole decode step at qwen1.5-0.5b widths: the kernel's custom call is
+widths (dh 128: 5 heads, or 20 over a quarter of the pages), and
+gemma2-2b (GQA, dh 256, sliding window, softcap) — over a page pool that
+it reads in place from HBM, and so is the serving engine's whole decode
+step at qwen1.5-0.5b widths: the kernel's custom call is
 named by the kernel itself, whatever jitted function encloses it, and
 the codec's ops carry their named scope.  No chip is needed: the
 topology is described, not attached.  Nothing touches the TPU library
@@ -38,7 +39,7 @@ def one_chip(topo):
 
 
 def _compile_kernel(one_chip, K1, Hq, Hkv, dh, window=0, cap=0.0,
-                    encode_wire=False):
+                    encode_wire=False, pages=PAGES):
     import jax
     import jax.numpy as jnp
     from repro.kernels.paged_decode import paged_decode_pallas
@@ -47,13 +48,15 @@ def _compile_kernel(one_chip, K1, Hq, Hkv, dh, window=0, cap=0.0,
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     ppc = 512 // PAGE
-    pool = sds((UNITS, PAGES, PAGE, Hkv * dh), jnp.bfloat16)
+    pool = sds((UNITS, pages, PAGE, Hkv * dh), jnp.bfloat16)
     args = (sds((SLOTS, K1, Hq, dh), jnp.bfloat16), pool, pool,
             sds((SLOTS, ppc), jnp.int32), sds((SLOTS, ppc), jnp.int32),
             sds((SLOTS, K1), jnp.int32), sds((), jnp.int32))
-    fn = jax.jit(lambda *a: paged_decode_pallas(
-        *a, window=window, cap=cap, encode_wire=encode_wire))
-    return fn.lower(*args).compile(), pool
+    def kernel(*a):
+        return paged_decode_pallas(*a, window=window, cap=cap,
+                                   encode_wire=encode_wire)
+    return (jax.jit(kernel).lower(*args).compile(), pool,
+            jax.make_jaxpr(kernel)(*args))
 
 
 def _kernel_calls(hlo: str) -> list:
@@ -63,11 +66,27 @@ def _kernel_calls(hlo: str) -> list:
             if 'custom_call_target="tpu_custom_call"' in ln]
 
 
-def _check_kernel(compiled, pool):
+def _check_kernel(compiled, pool, jaxpr):
+    from jax.experimental import pallas as pl
     hlo = compiled.as_text()
     # compiled inside an anonymous jit: the name is the kernel's own
     calls = _kernel_calls(hlo)
     assert calls and all(c.startswith("paged_flash_decode") for c in calls)
+    # the kernel takes both pools whole in HBM (memory space ANY) and
+    # copies its pages itself: its last two operands are the jit's pool
+    # parameters as they came in, with nothing in between
+    (call,) = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    pools = call.params["grid_mapping"].block_mappings[2:4]
+    assert all(m.block_aval.memory_space == pl.ANY
+               and m.block_aval.shape == pool.shape for m in pools)
+    entry = hlo[hlo.index("\nENTRY "):]
+    params = dict(re.findall(r"%(\S+) = \S+ parameter\(([12])\)", entry))
+    (operands,) = re.findall(
+        r"custom-call\((.*)\), custom_call_target=\"tpu_custom_call\"",
+        entry)
+    operands = [o.split("*/")[-1].lstrip("%") for o in operands.split(", ")]
+    assert [params.get(o) for o in operands[-2:]] == ["1", "2"]
     # the pool is read in place: no relayout copy of it in front of the
     # kernel (a page-minor default layout would cost a full pool copy)
     pool_bytes = 2 * pool.size
@@ -81,9 +100,16 @@ def test_paged_decode_compiles_qwen_widths(one_chip, K1, encode_wire):
                                    encode_wire=encode_wire))
 
 
-def test_paged_decode_compiles_qwen4b_tp4_chip_widths(one_chip):
-    _check_kernel(*_compile_kernel(one_chip, 1, Hq=5, Hkv=5, dh=128,
-                                   encode_wire=True))
+@pytest.mark.parametrize("heads,pages", [
+    pytest.param(5, PAGES, id="head-sharded"),
+    pytest.param(20, 6144 // 4, id="page-sharded")])
+def test_paged_decode_compiles_qwen4b_tp4_chip_widths(one_chip, heads,
+                                                      pages):
+    """A chip's share of qwen1.5-4b at tp=4: 5 of the 20 heads, or, as
+    the engine shards its pool, all 20 heads over a quarter of the cell's
+    6144 pages (2560 lanes, so 12 pages a block within the ring's VMEM)."""
+    _check_kernel(*_compile_kernel(one_chip, 1, Hq=heads, Hkv=heads,
+                                   dh=128, encode_wire=True, pages=pages))
 
 
 def test_paged_decode_compiles_gemma_gqa_window_softcap(one_chip):
