@@ -476,8 +476,8 @@ def quantize_partial(o):
     attention partial ``[..., dh]`` -> ``(wire int8, scale f32)``.
 
     Bit-identical to the fused kernel's epilogue
-    (``kernels.paged_decode``), so the reference gather path and the
-    fused path put the same bytes on the wire.
+    (``kernels.paged_decode``), so the dense-cache decode path and the
+    paged kernel put the same bytes on the wire.
     """
     o = o.astype(jnp.float32)
     s = jnp.maximum(jnp.max(jnp.abs(o), axis=-1, keepdims=True),
@@ -541,120 +541,6 @@ def coded_ppermute(x, params, codec: BoundaryCodec, axis_name: str,
 
     _pp.defvjp(_fwd, _bwd)
     return _pp(x, params["theta"], params["log_scale"])
-
-
-# ---------------------------------------------------------------------------
-# coded KV migration (disaggregated prefill -> decode state handoff)
-# ---------------------------------------------------------------------------
-#
-# Disaggregated serving migrates a finished prefill's paged KV from a
-# prefill-role dp group to a decode-role group — the paper's wire
-# discipline applied to STATE transfer, not just activations.  KV lives
-# in head space ([.., pages, page_size, Hkv, dh]) where no learned
-# spike params exist, so like the decode-step head boundaries above the
-# coded wire here is params-free int8 absmax — but with POWER-OF-TWO
-# scales (``kv_pow2_scale``): scale mul/div is then exact in floating
-# point and the encode is idempotent (encode(decode(encode(x))) ==
-# decode(encode(x)) bit-exactly), which is what lets a coded migration
-# be lossless over pool values that were already coded once at insert.
-# That idempotence is the disagg == colocated token-identity story for
-# ``EngineConfig.kv_wire="coded"``: both topologies roundtrip the KV at
-# admission, and the migration's re-encode of the roundtripped pool
-# pages reproduces the wire bytes exactly.
-
-
-def kv_pow2_scale(x):
-    """Per-vector (last axis) absmax int8 scale, snapped to a power of 2.
-
-    ``s = 2^k`` with ``k`` chosen from the frexp exponent of the absmax
-    ``m`` so that ``m/s <= 127`` (and ``m/s > 63.5``, keeping at least
-    ~7 significant bits): exact in fp arithmetic, no log2 rounding
-    hazards.  A re-encode of ``round(x/s) * s`` recovers the identical
-    ``s`` — see the section comment — because the decoded absmax is an
-    integer multiple of a power of two.
-    """
-    m = jnp.maximum(jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1,
-                            keepdims=True), 1e-6)
-    frac, exp = jnp.frexp(m)
-    k = jnp.where(frac > 127.0 / 128.0, exp - 6, exp - 7)
-    return jnp.exp2(k.astype(jnp.float32))
-
-
-def kv_wire_encode(x):
-    """``x [..., dh] -> (wire int8, scale f32 [..., 1])`` — the coded KV
-    handoff's wire format (pow2-absmax int8 per (position, head))."""
-    s = kv_pow2_scale(x)
-    wire = jnp.clip(jnp.round(x.astype(jnp.float32) / s), -127, 127)
-    return wire.astype(jnp.int8), s
-
-
-def kv_wire_roundtrip(x):
-    """Encode+decode ``x`` through the coded KV wire (lossy, idempotent).
-
-    Applied at pool INSERT when ``EngineConfig.kv_wire="coded"`` — in
-    the colocated AND the disaggregated engine alike — so the pool holds
-    wire-representable values and a later coded migration is bit-exact.
-    A 7-bit-mantissa value times a power-of-two scale is exactly
-    representable in bf16 and f32, so the roundtrip is idempotent in
-    either pool dtype.
-    """
-    wire, s = kv_wire_encode(x)
-    return (wire.astype(jnp.float32) * s).astype(x.dtype)
-
-
-def kv_wire_bytes(shape, dtype_bytes: int, coded: bool) -> int:
-    """Wire bytes of ONE migrated KV staging buffer of ``shape``
-    (``[..., dh]``): int8 counts + one f32 scale per dh-vector when
-    coded, plain dtype bytes otherwise.  Host-side accounting only —
-    ``SLOMonitor``/``emio_cost_from_trace`` price migrations with it."""
-    n = 1
-    for d in shape:
-        n *= int(d)
-    if not coded:
-        return n * dtype_bytes
-    return n + (n // int(shape[-1])) * 4
-
-
-@_exchange
-def coded_kv_migrate(x, codec: BoundaryCodec, axis_name: str,
-                     perm: Sequence[tuple[int, int]]):
-    """Send a paged-KV staging buffer ``x [..., dh]`` across the die
-    boundary named ``axis_name`` along ``perm`` — the state-transfer
-    sibling of ``coded_ppermute``.
-
-    What rides CODED vs FP on the handoff:
-
-    * KV page payload (this function, every attention ``kv`` /
-      ``cross_kv`` leaf): pow2-absmax int8 — one int8 per element plus
-      one f32 scale per (page, position, kv-head) dh-vector.  This is
-      the O(prompt_len x Hkv x dh) bulk of the migration and the term
-      the spike/int8 wire shrinks ~4x (bf16) to ~8x (f32 scales
-      amortized over dh).
-    * Recurrent/SSM state leaves (mamba/xLSTM/RWKV slot rows): FP via a
-      plain ``lax.ppermute`` — they are O(1) per slot, carry
-      log-space / accumulator values whose quantization would break
-      greedy token identity, and are not worth coding.
-    * Block-table / compacted page-list metadata: never on the device
-      wire at all — the host allocator mirrors the mapping
-      (``SlotAllocator.migrate_slot``), so only payload crosses.
-
-    ``codec.mode == "none"`` sends plain fp (the ``kv_wire="fp"``
-    default); every coded mode shares the one params-free int8 KV wire
-    (KV is head-space — there are no learned theta/log_scale channels
-    to spike against, exactly as at the decode-step head boundaries).
-    Like every boundary collective, the wire/scale ppermute pair is
-    what ``launch.roofline.parse_collectives`` sees, so the migration
-    is priced like any other coded collective — and the named scope tags
-    the ppermute pair as the ``kv_migrate`` packet stream.  Forward-only
-    (serving).
-    """
-    with jax.named_scope("coded_kv_migrate"):
-        if codec.mode == "none":
-            return lax.ppermute(x, axis_name, perm)
-        wire, s = kv_wire_encode(x)
-        wire = lax.ppermute(wire, axis_name, perm)
-        s = lax.ppermute(s, axis_name, perm)
-        return (wire.astype(jnp.float32) * s).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

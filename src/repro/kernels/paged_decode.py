@@ -2,7 +2,8 @@
 
 The kernel walks each batch slot's compacted per-shard page list
 (host-built by ``serving.kv_cache.SlotAllocator`` next to the block
-table) and fuses the three stages the reference path runs separately:
+table) and fuses three stages that a gather-then-attend path would run
+separately:
 
     page gather -> online-softmax flash decode (K1 >= 1 query tokens,
     covering both the decode K1=1 case and spec verify) -> locally
@@ -14,20 +15,19 @@ leaves the kernel already quantized for the coded die-to-die combine,
 so no ``[B, pages_per_slot*psz, Hkv, dh]`` gathered KV block ever
 materializes in HBM.  Work per slot is ``pages_per_shard =
 ceil(pages_per_slot / pool_shards)`` pages — the 1/cp page-count
-reduction the dense layout had — instead of the full block table the
-reference gather scores and masks.
+reduction the dense layout had — instead of the full block-table width.
 
 Numerics: f32 throughout, same -1e30 masking sentinel and 1e-30
 normalizer floors as ``models.common.verify_attention_partial``.  The
 online per-page max/rescale reduction is mathematically identical to
-the reference's single-max softmax but associates differently, so
-results agree to fp epsilon, not bit-for-bit; greedy token-identity of
-the served stream is what the engine fuzz enforces.  An invalid (-1)
-list entry inside a walked block is not fetched: its scores are masked
-to -1e30 and its V rows are zero, so it adds nothing to the output.
-A fully masked shard (no resident page at <= qpos) yields lse ~= -1e30
-exactly like the reference, so its weight underflows to exactly 0 in
-the combine.
+that function's single-max softmax but associates differently, so
+results agree to fp epsilon, not bit-for-bit; the engine tests hold
+each served token to the teacher-forced full-sequence forward's
+argmax.  An invalid (-1) list entry inside a walked block is not
+fetched: its scores are masked to -1e30 and its V rows are zero, so it
+adds nothing to the output.  A fully masked shard (no resident page at
+<= qpos) yields lse ~= -1e30 exactly like ``verify_attention_partial``,
+so its weight underflows to exactly 0 in the combine.
 
 Layout.  The pool is stacked over units and lane-flat, ``[U, P_loc,
 psz, Hkv*dh]``, and the unit to read is a scalar operand: the decode

@@ -270,49 +270,6 @@ def page_list_specs(plan: CellPlan, page_size: int):
     return (struct, struct), (sp, sp)
 
 
-def migrate_input_specs(plan: CellPlan, page_size: int):
-    """(inputs, specs) for the KV migration step's host-staged feeds.
-
-    The disaggregated engine's migration program takes, besides the
-    donated pool cache, four replicated host feeds: the SOURCE slot's
-    block-table row snapshot and the freshly mirrored DESTINATION row
-    (``[pages_per_slot]`` int32 global page ids, -1 unmapped) plus the
-    two slot indices (scalar int32).  Replicated (``P()``) on purpose:
-    every device must see both rows — each tp shard resolves its own
-    resident pages through ``pool_local_pages`` exactly as the insert
-    path does, and the dp groups at either end of the ppermute need the
-    row of their side of the handoff.
-    """
-    pps = pages_per_slot(plan.cell.seq_len, page_size)
-    inputs = {"src_bt": jax.ShapeDtypeStruct((pps,), jnp.int32),
-              "dst_bt": jax.ShapeDtypeStruct((pps,), jnp.int32),
-              "src_slot": jax.ShapeDtypeStruct((), jnp.int32),
-              "dst_slot": jax.ShapeDtypeStruct((), jnp.int32)}
-    specs = {"src_bt": P(), "dst_bt": P(), "src_slot": P(),
-             "dst_slot": P()}
-    return inputs, specs
-
-
-def migrate_stage_shape(plan: CellPlan, page_size: int,
-                        kv_leaf_shape) -> tuple:
-    """Shape of ONE per-shard KV migration staging buffer.
-
-    The device migration gathers the source slot's resident pages on
-    each tp shard into a static ``[U, pages_per_slot, page_size, Hkv,
-    dh]`` slab (non-resident rows zero), ppermutes the slab to the
-    destination group's same-index shard, and scatters it through the
-    mirrored destination block row.  Static width = the full block-row
-    span: the wire cost of a migration is therefore shape-constant per
-    (src, dst) pair — which is what lets the host price it without
-    reading device state (``boundary.kv_wire_bytes``).  The slab is in
-    head space (the pool leaf's lane-flat ``Hkv*dh`` split back into
-    heads), so the coded wire keeps one scale per (position, head).
-    """
-    d_at = blocks_attn.attn_dims(plan.cfg, plan.tp_size)
-    return (kv_leaf_shape[0], pages_per_slot(plan.cell.seq_len, page_size),
-            page_size, d_at["Hkv"], d_at["dh"])
-
-
 def decode_input_specs(plan: CellPlan):
     """(inputs, specs) for one decode step: cache + token + pos."""
     cfg, cell = plan.cfg, plan.cell

@@ -307,46 +307,6 @@ def _paged_kv_write(cache, bt, qpos, k_new, v_new, ctx: Context, layer):
     return {"k": ck, "v": cv}
 
 
-def _paged_kv_gather(cache, bt, ctx: Context, layer):
-    """Gather every local slot's resident pages of unit ``layer``,
-    ordered by position.
-
-    cache {k,v} [U, P_loc, psz, Hkv*dh]; bt [B, PPS].  Returns
-    (k [B, PPS*psz, Hkv, dh], v likewise, valid [B, PPS*psz] bool) —
-    entry ``i`` of the gathered sequence IS absolute position ``i`` of
-    the slot, so the attention partial runs with ``shard_offset=0`` and
-    ``valid`` masks entries whose page is unmapped or lives on another
-    shard (those rows carry arbitrary pool data and must never score).
-    The gather spans the full block table on every shard: each shard
-    materializes [B, max_seq] gathered K/V + scores where the dense
-    seq-sharded layout touched only its [B, max_seq / cp] slice — a
-    cp-fold per-shard overhead on the decode step, deliberately traded
-    for the pooled memory layout at the small B x max_seq shapes the
-    engine serves.  The fused path
-    (``kernels/paged_decode.py`` + the host-built compacted per-shard
-    page lists) restores the 1/cp slice; this gather stays as the
-    reference oracle the fused kernel is fuzz-checked against.
-
-    Invariant: every non-resident entry gathers LOCAL PAGE 0 — one
-    fixed row, the same for all invalid entries — rather than clamping
-    ``loc`` to ``P_loc - 1`` (which aliased invalid entries onto
-    whatever page happened to sit last in the shard).  Page 0's
-    contents never score (``ok`` masks them); pinning all dead gathers
-    to a single row keeps the reference path's memory traffic honest
-    for the fused-vs-reference bench comparison (one hot row instead
-    of a scatter of arbitrary pool rows) and makes the gather's
-    out-of-range behavior independent of pool size.
-    """
-    ck, cv = cache["k"], cache["v"]
-    _, P_loc, psz, _ = ck.shape
-    B, PPS = bt.shape
-    loc, ok = pool_local_pages(bt, pool_linear_index(ctx), P_loc)
-    idx = jnp.where(ok, loc, 0)
-    kg = ck[layer, idx].reshape(B, PPS * psz, -1, ctx.cfg.d_head)
-    vg = cv[layer, idx].reshape(B, PPS * psz, -1, ctx.cfg.d_head)
-    return kg, vg, jnp.repeat(ok, psz, axis=1)
-
-
 def _combine_partials(o, lse, ctx: Context):
     """Cross-shard combine of a flash partial; coded wire when the codec
     is.  Mode "none" is the plain fp LSE combine; every coded mode
@@ -361,41 +321,31 @@ def _combine_partials(o, lse, ctx: Context):
                                            jnp.float32)
 
 
-def _paged_attn_combined(q, cache, bt, page_list, qpos, ctx: Context,
-                         window, cap, layer):
-    """Paged attention partial + cross-shard combine, both cache walks.
+def _paged_attn_combined(q, cache, page_list, qpos, ctx: Context, window,
+                         cap, layer):
+    """Paged attention partial + cross-shard combine.
 
     q [B, K1, Hq, dh] (full heads, post-gather); qpos [B, K1] absolute
-    query positions; ``layer`` the unit index into the stacked pool.
-    ``page_list`` (the engine's compacted per-shard feed, local
-    [B, 1, ppc] after sharding — None on the reference path) selects
-    the fused Pallas kernel: gather -> flash -> LSE partial in
-    one pass over this shard's resident pages, with the int8 wire
-    encode fused at the kernel epilogue when the codec is coded.  The
-    reference path gathers the full block table (``_paged_kv_gather``)
-    and scores it through ``verify_attention_partial`` — the oracle the
-    fused path is fuzz-checked against.  Returns the combined
-    [B, K1, Hq, dh] f32 attention output.
+    query positions; ``layer`` the unit index into the stacked pool;
+    ``page_list`` the engine's compacted per-shard feed (local
+    [B, 1, ppc] after sharding).  The fused Pallas kernel runs gather ->
+    flash -> LSE partial in one pass over this shard's resident pages,
+    with the int8 wire encode fused at the kernel epilogue when the
+    codec is coded.  Returns the combined [B, K1, Hq, dh] f32 attention
+    output.
     """
-    coded = ctx.codec.mode != "none"
-    if page_list is not None:
-        clp, clo = page_list
-        clp, clo = clp[:, 0], clo[:, 0]            # [B_loc, ppc]
-        if coded:
-            wire, scale, lse = kops.paged_flash_decode(
-                q, cache["k"], cache["v"], clp, clo, qpos, layer,
-                window=window, cap=cap, encode_wire=True)
-            return boundary.coded_combine_partials(wire, scale, lse,
-                                                   ctx.cp, jnp.float32)
-        o, lse = kops.paged_flash_decode(q, cache["k"], cache["v"],
-                                         clp, clo, qpos, layer,
-                                         window=window, cap=cap)
-        return common.combine_decode_partials(o, lse, ctx.cp)
-    k_s, v_s, kv_valid = _paged_kv_gather(cache, bt, ctx, layer)
-    o, lse = common.verify_attention_partial(
-        q, k_s, v_s, pos=qpos, shard_offset=0, window=window, cap=cap,
-        kv_valid=kv_valid)
-    return _combine_partials(o, lse, ctx)
+    clp, clo = page_list
+    clp, clo = clp[:, 0], clo[:, 0]                # [B_loc, ppc]
+    if ctx.codec.mode != "none":
+        wire, scale, lse = kops.paged_flash_decode(
+            q, cache["k"], cache["v"], clp, clo, qpos, layer,
+            window=window, cap=cap, encode_wire=True)
+        return boundary.coded_combine_partials(wire, scale, lse,
+                                               ctx.cp, jnp.float32)
+    o, lse = kops.paged_flash_decode(q, cache["k"], cache["v"],
+                                     clp, clo, qpos, layer,
+                                     window=window, cap=cap)
+    return common.combine_decode_partials(o, lse, ctx.cp)
 
 
 # ---------------------------------------------------------------------------
@@ -411,9 +361,12 @@ def attn_decode_fwd(p, x, cache, pos, ctx: Context, aux, kind="attn",
       dense (single-request serve path): cache {k,v} [B_loc, Ss, Hkv, dh]
         seq-sharded over ctx.cp, indexed ``cache[slot, pos]``;
       paged (serving engine): cache {k,v} [U, P_loc, psz, Hkv*dh] —
-        this shard's pages of the shared pool, every unit — indexed
-        ``cache[aux["layer"], page, offset]`` through the per-slot block
-        table rows in ``aux["block_table"]``.
+        this shard's pages of the shared pool, every unit.  The new
+        token's K/V is written at ``cache[aux["layer"], page, offset]``
+        through the per-slot block table rows in ``aux["block_table"]``;
+        attention reads the pages through the paged-decode kernel fed by
+        ``aux["page_list"]`` (the engine's compacted per-shard page list,
+        required on this layout).
 
     Returns (x', cache')."""
     cfg = ctx.cfg
@@ -491,11 +444,9 @@ def attn_decode_fwd(p, x, cache, pos, ctx: Context, aux, kind="attn",
     window = cfg.window if kind == "local" else 0
     bt = None if is_cross else aux.get("block_table")
     if bt is not None:
-        # paged: fused kernel over the compacted page lists when the
-        # engine feeds them, else the reference full-table gather
-        plist = aux.get("page_list")
-        o = _paged_attn_combined(q, cache, bt, plist, pos[:, None], ctx,
-                                 window, cfg.attn_softcap,
+        # paged: the fused kernel walks the compacted page lists
+        o = _paged_attn_combined(q, cache, aux["page_list"], pos[:, None],
+                                 ctx, window, cfg.attn_softcap,
                                  aux["layer"])[:, 0]
     else:
         k_s, v_s, kv_valid = cache["k"], cache["v"], None
@@ -528,9 +479,9 @@ def attn_verify_fwd(p, x, cache, pos, ctx: Context, aux, kind="attn"):
     committed token followed by spec_k draft tokens per slot; pos [B]
     per-slot *base* positions (query j sits at pos+j).  Cache layout is
     dense ([B, Ss, Hkv, dh] seq-sharded over ctx.cp) or the shared page
-    pool ([U, P_loc, psz, Hkv*dh] + ``aux["block_table"]`` +
-    ``aux["layer"]``), exactly as in
-    ``attn_decode_fwd``.
+    pool ([U, P_loc, psz, Hkv*dh] + ``aux["block_table"]`` for the
+    writes, ``aux["page_list"]`` for the kernel's reads, and
+    ``aux["layer"]``), exactly as in ``attn_decode_fwd``.
 
     Every per-token op is shared with ``attn_decode_fwd`` (same norms,
     same ``wire_roundtrip`` spike boundary, same projections), so under
@@ -584,13 +535,11 @@ def attn_verify_fwd(p, x, cache, pos, ctx: Context, aux, kind="attn"):
     if bt is not None:
         # paged: one duplicate-free scatter for all K1 positions (their
         # (page, offset) targets are distinct by construction), then
-        # attend over the slot's resident pages — fused kernel when the
-        # engine feeds the compacted lists, reference gather otherwise
+        # attend over the slot's resident pages through the fused kernel
         cache = _paged_kv_write(cache, bt, qpos, k_new, v_new, ctx,
                                 aux["layer"])
-        o = _paged_attn_combined(q, cache, bt, aux.get("page_list"),
-                                 qpos, ctx, window, cfg.attn_softcap,
-                                 aux["layer"])
+        o = _paged_attn_combined(q, cache, aux["page_list"], qpos, ctx,
+                                 window, cfg.attn_softcap, aux["layer"])
     else:
         # dense: scatter the K1 new KV rows one position at a time (K1
         # is static and small) — sequential writes keep the update

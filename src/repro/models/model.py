@@ -571,10 +571,11 @@ def forward_decode(params, cache, token, pos, ctx: Context, aux_extra=None):
     Two attention-cache layouts: dense slot-major (``cache[slot, pos]``,
     the single-request serve path) or — when ``aux_extra`` carries a
     ``"block_table"`` row per local slot — the serving engine's shared
-    KV page pool, indexed ``cache[page, offset]`` through that table
-    (see ``blocks_attn.attn_decode_fwd``).  Recurrent-state leaves are
-    slot-major in both.  Returns (logits_local [B_loc, V_loc],
-    new_cache)."""
+    KV page pool, written ``cache[page, offset]`` through that table and
+    read by the paged-decode kernel through the compacted
+    ``"page_list"`` feed (see ``blocks_attn.attn_decode_fwd``).
+    Recurrent-state leaves are slot-major in both.  Returns
+    (logits_local [B_loc, V_loc], new_cache)."""
     cfg = ctx.cfg
     ctx = ctx.with_(mode="decode")
     aux = dict(aux_extra or {})

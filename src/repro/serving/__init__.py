@@ -35,7 +35,7 @@ wire, and an SLO harness (trace-driven workloads, fault injection).
                  otherwise) with at least ``spec_k`` heads.  Both
                  drafters are greedy-token-identical to ``spec_k=0``
                  (fuzz-enforced across drafter x spec_k x async_depth x
-                 codec x disagg).
+                 codec).
 ``num_pages``    KV page-pool size, independent of ``num_slots *
                  max_seq``.  0: dense-equivalent default (can never
                  exhaust before the slots do); smaller is the paging
@@ -44,19 +44,12 @@ wire, and an SLO harness (trace-driven workloads, fault injection).
 ``page_size``    Positions per KV page.  Admission maps only
                  ``ceil(prompt_len / page_size)`` pages; decode maps one
                  more page per ``page_size`` generated tokens
-                 (alloc-on-extend).
-``attn_kernel``  Decode/verify attention path.  ``"fused"`` (default):
-                 the Pallas kernel walks the allocator's compacted
-                 per-shard page lists — page gather, online-softmax
-                 flash decode and the int8 wire epilogue in ONE kernel,
-                 no ``[B, pages*page_size, Hkv, dh]`` gather in HBM, per
-                 shard cost ``ceil(len / (page_size * tp))`` pages
-                 instead of the full block-table width.  ``"reference"``:
-                 the dense gather + ``verify_attention_partial`` path —
-                 the oracle the kernel is fuzz-checked against
-                 (token-identical greedy streams, enforced in
-                 tests/test_paged_decode.py).  Anything else is a typed
-                 ``EngineConfigError``.
+                 (alloc-on-extend).  Decode/verify attention reads the
+                 pool through the paged-decode Pallas kernel, which
+                 walks the allocator's compacted per-shard page lists —
+                 page gather, online-softmax flash decode and the int8
+                 wire epilogue in ONE kernel, per shard cost
+                 ``ceil(len / (page_size * tp))`` pages.
 ``preempt``      Graceful degradation under pool pressure (default on):
                  a mid-flight ``PagePoolExhausted`` drains the pipeline
                  (limbo pages rejoin the pool) and then evicts +
@@ -65,29 +58,6 @@ wire, and an SLO harness (trace-driven workloads, fault injection).
                  stay bit-identical to an uninterrupted run
                  (fuzz-enforced), so only latency pays.  False: the
                  typed error propagates to the caller's own policy.
-``disagg``       Disaggregated prefill/decode (default off; needs a
-                 dp >= 2 mesh).  The first ``prefill_groups`` dp groups
-                 own admission prefill; the rest own decode.  Each
-                 admitted request's paged KV (and any recurrent-state
-                 rows) migrates to its decode group in ONE ppermute onto
-                 pages the decode group mapped at matching per-shard
-                 positions; admission pre-checks BOTH sides (slot, pages,
-                 mirrored placement) so a started prefill can never
-                 strand.  Greedy streams are token-identical to the
-                 colocated engine (fuzz-enforced across spec_k x
-                 async_depth x codec x kv_wire).
-``prefill_groups``  How many dp groups ``disagg`` reserves for prefill
-                 (default 1; must leave >= 1 decode group).
-``kv_wire``      Migration wire format: ``"fp"`` moves KV pages at pool
-                 dtype; ``"coded"`` moves per-page pow2-absmax int8
-                 (~0.3x the bytes at dh=16) whose power-of-two scales
-                 make encode/decode exactly idempotent on the pool — so
-                 the coded wire is also token-identical, not just close
-                 (see ``repro.core.boundary.coded_kv_migrate``).
-``router``       Decode-group choice per migration: ``"load"`` (default)
-                 picks the group with the fewest pages in use + limbo
-                 (ties to the lowest id), ``"rr"`` round-robins over
-                 mirror-capable groups.
 ===============  ========================================================
 
 SLO harness knobs (``repro.serving.workload`` / ``repro.serving.slo``):
@@ -110,12 +80,11 @@ SLO harness knobs (``repro.serving.workload`` / ``repro.serving.slo``):
 ``wire_streams_     ``SLOMonitor`` pricing table: step kind -> per-
 per_step``          collective {stream -> bytes} of one compiled step,
                     from ``engine.wire_stream_profile()`` (psum / head
-                    all-gather / partial combine / kv-migrate, parsed
-                    out of the step HLO).  Every tick then records a
+                    all-gather / partial combine, parsed out of the
+                    step HLO).  Every tick then records a
                     ``wire_streams`` split summing to its scalar
                     ``wire_bytes``; unknown step kinds warn instead of
-                    silently pricing at 0, and migration bytes pending
-                    at drain flush into a terminal ``drain`` event.
+                    silently pricing at 0.
 ==================  =====================================================
 """
 from .draft import NGramDrafter

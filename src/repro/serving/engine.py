@@ -233,36 +233,6 @@ class EngineConfig:
     #                                in the starving pool group instead
     #                                of failing the step (False: the
     #                                typed error propagates)
-    attn_kernel: str = "fused"     # paged decode attention path:
-    #                                "fused" walks the compacted per-shard
-    #                                page lists in one Pallas kernel
-    #                                (kernels/paged_decode.py; its XLA
-    #                                oracle off-TPU); "reference" scores the
-    #                                full block table per shard — the
-    #                                oracle the fused path is fuzz-checked
-    #                                against
-    disagg: bool = False           # disaggregated prefill/decode roles:
-    #                                dedicate the first prefill_groups dp
-    #                                groups to admission prefills and
-    #                                migrate each finished prefill's paged
-    #                                KV (+ state rows) to a decode-role
-    #                                group through one coded ppermute
-    #                                (False: colocated, behavior-identical
-    #                                to the pre-disagg engine)
-    prefill_groups: int = 1        # dp groups dedicated to prefill when
-    #                                disagg=True (the rest decode); must
-    #                                satisfy 0 < prefill_groups < dp_size
-    kv_wire: str = "fp"            # KV payload discipline at pool insert
-    #                                and on the migration wire: "fp"
-    #                                (exact, default) or "coded" (pow2-
-    #                                absmax int8 roundtrip at insert +
-    #                                int8 wire on migration — lossy once,
-    #                                then idempotent, so disagg stays
-    #                                token-identical to colocated)
-    router: str = "load"           # disagg admission router picking the
-    #                                migration target among decode
-    #                                groups: "load" (fewest pages mapped
-    #                                + in limbo) or "rr" (round-robin)
 
 
 @dataclasses.dataclass
@@ -356,8 +326,7 @@ def make_engine_prefill_step(cfg, plan, mesh, scfg: SamplingConfig,
 
 def make_engine_decode_step(cfg, plan, mesh, scfg: SamplingConfig,
                             page_size, num_pages,
-                            replicate_weights=False,
-                            attn_kernel="fused"):
+                            replicate_weights=False):
     """decode(params, cache, token[B], pos[B], bt[B,PPS], clp[B,S,ppc],
     clo[B,S,ppc], temp[B], key) -> (next_token [B], cache) — cache
     donated.
@@ -365,9 +334,7 @@ def make_engine_decode_step(cfg, plan, mesh, scfg: SamplingConfig,
     ``cache`` is the shared KV page pool (+ slot-major state leaves);
     ``bt`` the per-slot block table the attention writes K/V through;
     ``clp``/``clo`` the compacted per-shard page lists (local page rows
-    / start positions) the fused attention kernel walks.  With
-    ``attn_kernel="reference"`` the lists are staged but unused and
-    attention gathers the full block table per shard.
+    / start positions) the paged-decode kernel walks.
     """
     _, pspecs, _ = shard_params_specs(cfg, plan)
     ctx = make_context(plan, "decode")
@@ -375,12 +342,9 @@ def make_engine_decode_step(cfg, plan, mesh, scfg: SamplingConfig,
         pspecs = strip_dp_specs(pspecs)
         ctx = ctx.with_(dp_size=1)
     _, ispecs = serve_decode_input_specs(plan, page_size, num_pages)
-    fused = attn_kernel == "fused"
 
     def step(params, cache, token, pos, bt, clp, clo, temp, key):
-        aux = {"block_table": bt}
-        if fused:
-            aux["page_list"] = (clp, clo)
+        aux = {"block_table": bt, "page_list": (clp, clo)}
         logits, cache = M.forward_decode(params, cache, token, pos, ctx,
                                          aux_extra=aux)
         tok = sampling.sample(logits, key, temp, tp=ctx.tp,
@@ -398,8 +362,7 @@ def make_engine_decode_step(cfg, plan, mesh, scfg: SamplingConfig,
 
 def make_engine_verify_step(cfg, plan, mesh, scfg: SamplingConfig, spec_k,
                             page_size, num_pages,
-                            replicate_weights=False,
-                            attn_kernel="fused"):
+                            replicate_weights=False):
     """verify(params, cache, tokens[B,K1], pos[B], bt[B,PPS], clp, clo,
     temp[B], key) -> (tokens_out [B,K1], cache) — cache donated.
 
@@ -407,8 +370,8 @@ def make_engine_verify_step(cfg, plan, mesh, scfg: SamplingConfig, spec_k,
     every slot; column j of ``tokens_out`` is the model's (greedy or
     sampled) next token after committing ``tokens[:, :j+1]``.  Reads and
     writes the same page pool + block table as the decode step, and
-    takes the same compacted page lists for the fused attention path
-    (the kernel covers K1 >= 1 with one code path).
+    takes the same compacted page lists for the paged-decode kernel
+    (which covers K1 >= 1 with one code path).
     """
     _, pspecs, _ = shard_params_specs(cfg, plan)
     ctx = make_context(plan, "decode")
@@ -416,12 +379,9 @@ def make_engine_verify_step(cfg, plan, mesh, scfg: SamplingConfig, spec_k,
         pspecs = strip_dp_specs(pspecs)
         ctx = ctx.with_(dp_size=1)
     _, ispecs = serve_verify_input_specs(plan, spec_k, page_size, num_pages)
-    fused = attn_kernel == "fused"
 
     def step(params, cache, tokens, pos, bt, clp, clo, temp, key):
-        aux = {"block_table": bt}
-        if fused:
-            aux["page_list"] = (clp, clo)
+        aux = {"block_table": bt, "page_list": (clp, clo)}
         logits, cache = M.forward_verify(params, cache, tokens, pos, ctx,
                                          aux_extra=aux)
         tok = sampling.sample_verify(logits, key, temp, tp=ctx.tp,
@@ -439,8 +399,7 @@ def make_engine_verify_step(cfg, plan, mesh, scfg: SamplingConfig, spec_k,
 
 def make_engine_heads_verify_step(cfg, plan, mesh, scfg: SamplingConfig,
                                   spec_k, page_size, num_pages, max_seq,
-                                  replicate_weights=False,
-                                  attn_kernel="fused"):
+                                  replicate_weights=False):
     """verify_heads(params, cache, tokens[B,K1], pos[B], bt, clp, clo,
     temp[B], key) -> (tokens_out [B,K1], feed_next [B,K1],
     pos_next [B], cache) — cache donated.
@@ -481,13 +440,10 @@ def make_engine_heads_verify_step(cfg, plan, mesh, scfg: SamplingConfig,
     pspecs = dict(pspecs)
     pspecs["draft_heads"] = hspecs
     _, ispecs = serve_verify_input_specs(plan, spec_k, page_size, num_pages)
-    fused = attn_kernel == "fused"
     k = spec_k
 
     def step(params, cache, tokens, pos, bt, clp, clo, temp, key):
-        aux = {"block_table": bt}
-        if fused:
-            aux["page_list"] = (clp, clo)
+        aux = {"block_table": bt, "page_list": (clp, clo)}
         logits, cache, h = M.forward_verify(params, cache, tokens, pos,
                                             ctx, aux_extra=aux,
                                             return_hidden=True)
@@ -559,34 +515,9 @@ class ServingEngine:
                 f"num_pages={self.num_pages} must divide over the "
                 f"dp x tp devices ({shards}) so the page pool shards "
                 "evenly")
-        if ecfg.attn_kernel not in ("fused", "reference"):
-            raise EngineConfigError(
-                f"attn_kernel={ecfg.attn_kernel!r}: expected 'fused' or "
-                "'reference'")
         if ecfg.drafter not in ("ngram", "heads"):
             raise EngineConfigError(
                 f"drafter={ecfg.drafter!r}: expected 'ngram' or 'heads'")
-        if ecfg.kv_wire not in ("fp", "coded"):
-            raise EngineConfigError(
-                f"kv_wire={ecfg.kv_wire!r}: expected 'fp' or 'coded'")
-        if ecfg.router not in ("load", "rr"):
-            raise EngineConfigError(
-                f"router={ecfg.router!r}: expected 'load' or 'rr'")
-        if ecfg.disagg:
-            if len(self.plan.dp) != 1:
-                raise EngineConfigError(
-                    "disagg=True needs exactly one dp mesh axis (the "
-                    f"migration ppermute axis); plan has {self.plan.dp}")
-            if self.plan.dp_size < 2:
-                raise EngineConfigError(
-                    "disagg=True needs dp_size >= 2 (at least one "
-                    "prefill-role and one decode-role group); "
-                    f"dp_size={self.plan.dp_size}")
-            if not 0 < ecfg.prefill_groups < self.plan.dp_size:
-                raise EngineConfigError(
-                    f"prefill_groups={ecfg.prefill_groups} must be in "
-                    f"(0, dp_size={self.plan.dp_size}): both roles need "
-                    "at least one dp group")
         cell_pre = ShapeCell("serve_admit", prefill_len, 1, "prefill")
         self.plan_pre = make_plan(cfg, cell_pre, mesh)
         self.prefill_len = prefill_len
@@ -636,7 +567,7 @@ class ServingEngine:
                                                self.plan_pre)}
         self._decode = make_engine_decode_step(
             cfg, self.plan, mesh, scfg, ecfg.page_size, self.num_pages,
-            ecfg.replicate_weights, ecfg.attn_kernel)
+            ecfg.replicate_weights)
         self._verify = None
         if self.spec_k > 0:
             self.plan_ver = make_plan(
@@ -646,25 +577,14 @@ class ServingEngine:
                 self._verify = make_engine_heads_verify_step(
                     cfg, self.plan_ver, mesh, scfg, self.spec_k,
                     ecfg.page_size, self.num_pages, ecfg.max_seq,
-                    ecfg.replicate_weights, ecfg.attn_kernel)
+                    ecfg.replicate_weights)
             else:
                 self._verify = make_engine_verify_step(
                     cfg, self.plan_ver, mesh, scfg, self.spec_k,
                     ecfg.page_size, self.num_pages,
-                    ecfg.replicate_weights, ecfg.attn_kernel)
+                    ecfg.replicate_weights)
         self.cache = PagedKVCache(self.plan, self.plan_pre, mesh,
-                                  ecfg.page_size, self.num_pages,
-                                  kv_wire=ecfg.kv_wire)
-        #: disaggregated roles: the first ``prefill_groups`` dp groups
-        #: take admission prefills, the rest decode; colocated engines
-        #: leave both None and admit anywhere
-        self._prefill_group_ids = None
-        self._decode_group_ids = None
-        if ecfg.disagg:
-            ng = self.cache.allocator.num_groups
-            self._prefill_group_ids = tuple(range(ecfg.prefill_groups))
-            self._decode_group_ids = tuple(range(ecfg.prefill_groups, ng))
-        self._rr_next = 0              # round-robin router cursor
+                                  ecfg.page_size, self.num_pages)
 
         n = ecfg.num_slots
         self._tokens = np.zeros(n, np.int32)
@@ -724,13 +644,9 @@ class ServingEngine:
         self.preemptions = 0       # evict + re-queue events (pool
         #                            pressure or injected faults)
         self.suspends = 0          # drain + snapshot + resume events
-        self.migrations = 0        # prefill -> decode KV handoffs (disagg)
-        self.migrated_wire_bytes = 0   # coded/fp bytes those handoffs put
-        #                                on the dp boundary (shape-static
-        #                                per migration)
         #: observability hooks: objects whose optional ``on_submit`` /
         #: ``on_admit`` / ``on_first_token`` / ``on_finish`` /
-        #: ``on_preempt`` / ``on_suspend`` / ``on_migrate`` methods are
+        #: ``on_preempt`` / ``on_suspend`` methods are
         #: called at the matching lifecycle points (see
         #: ``repro.serving.slo``); the per-tick ``on_step`` hook stays
         #: on ``run(on_step=...)``
@@ -843,21 +759,7 @@ class ServingEngine:
             # admit maps ceil(P_len/page_size) pages — O(prompt), not
             # O(max_seq); each decode step maps the next page on demand
             with TraceAnnotation("engine.insert"):
-                slot = self.cache.admit(pre_cache, P_len, plan_pre=plan_pre,
-                                        groups=self._prefill_group_ids)
-            if self.ecfg.disagg:
-                # prefill-role group done: hand the paged KV (+ state rows)
-                # to a decode-role group through the coded one-ppermute
-                # migration.  The dispatch-side pre-check (_can_admit_next)
-                # already proved a mirror-capable target exists, so routing
-                # here cannot fail.
-                dst = self._route_migration(slot)
-                src_g = self.cache.allocator.group_of(slot)
-                wire = self.cache.migrate_wire_bytes()
-                slot = self.cache.migrate(slot, dst)
-                self.migrations += 1
-                self.migrated_wire_bytes += wire
-                self._emit("on_migrate", req.rid, src_g, dst, wire)
+                slot = self.cache.admit(pre_cache, P_len, plan_pre=plan_pre)
             st = _Slot(req, list(prior), None, seq=self._admit_seq,
                        pending_first=first)
             self._admit_seq += 1
@@ -980,67 +882,6 @@ class ServingEngine:
                        if s is not None),
                       key=lambda i: self._slots[i].seq)
 
-    # -- disaggregated admission / routing ---------------------------------
-
-    def _route_migration(self, src_slot: int) -> int:
-        """Pick the decode-role group that takes ``src_slot``'s KV.
-
-        ``router="load"``: the mirror-capable candidate with the fewest
-        pages mapped-or-in-limbo (limbo pages are claims the group
-        already owes), ties to the lowest group id.  ``router="rr"``:
-        the first mirror-capable candidate at/after a round-robin
-        cursor.  ``_can_admit_next`` proved a candidate exists before
-        the admission started, so exhaustion here is a scheduler bug —
-        surfaced as a typed ``PagePoolExhausted``.
-        """
-        alloc = self.cache.allocator
-        cands = [g for g in self._decode_group_ids
-                 if alloc.can_migrate(src_slot, g)]
-        if not cands:
-            raise PagePoolExhausted(
-                f"migration of slot {src_slot}: no decode group can "
-                "mirror its page placement (admission pre-check raced "
-                "the allocator — scheduler bug)")
-        if self.ecfg.router == "rr":
-            dgs = self._decode_group_ids
-            n = len(dgs)
-            for k in range(n):
-                g = dgs[(self._rr_next + k) % n]
-                if g in cands:
-                    self._rr_next = (self._rr_next + k + 1) % n
-                    return g
-        return min(cands, key=lambda g: (alloc.pages_in_use_by_group(g)
-                                         + alloc.limbo_pages_in_group(g),
-                                         g))
-
-    def _admit_ready(self, P_len: int) -> bool:
-        """Exact can-this-admission-finish pre-check for a ``P_len``
-        prompt against the allocator's CURRENT state.
-
-        Colocated: limbo-aware ``can_admit``.  Disaggregated, three
-        legs: a prefill-role group can take the prompt, the slot
-        ``alloc`` would pick can place its pages (simulated placement),
-        and some decode-role group can MIRROR that placement per shard
-        and has a free slot.  Admission only starts when the whole
-        prefill -> migrate chain is guaranteed, so the router never has
-        to unwind a prefill — a starved target keeps the request
-        queued, which IS the re-queue path.
-        """
-        alloc = self.cache.allocator
-        if not self.ecfg.disagg:
-            return alloc.can_admit(P_len)
-        if not alloc.can_admit(P_len, groups=self._prefill_group_ids):
-            return False
-        src = alloc.peek_alloc(P_len, groups=self._prefill_group_ids)
-        if src is None:
-            return False
-        cnt = alloc.placement_counts(alloc.group_of(src),
-                                     alloc.pages_needed(P_len))
-        if cnt is None:
-            return False
-        return any(alloc.can_place_mirror(g, cnt)
-                   for g in self._decode_group_ids)
-
     def _can_admit_next(self) -> bool:
         """Admission gate for the queue head — limbo-aware.
 
@@ -1059,7 +900,7 @@ class ServingEngine:
         trade the overlap bubble for earlier admission).
         """
         _, _, prompt = self._entry_parts(self._queue[0])
-        return self._admit_ready(len(prompt))
+        return self.cache.allocator.can_admit(len(prompt))
 
     # -- faults / graceful degradation -------------------------------------
 
@@ -1303,11 +1144,9 @@ class ServingEngine:
         """The ``engine.launch`` span of a step, its ``kv_blocks`` and
         ``kv_pages`` stats the page blocks one of the step's paged-decode
         kernel calls computes and the pages it copies at the pages mapped
-        now (0 on the reference path), counted into ``kv_blocks_walked``
-        and ``kv_pages_fetched``."""
-        fused = self.ecfg.attn_kernel == "fused"
-        blocks = self.cache.kv_blocks_walked() if fused else 0
-        pages = self.cache.kv_pages_fetched() if fused else 0
+        now, counted into ``kv_blocks_walked`` and ``kv_pages_fetched``."""
+        blocks = self.cache.kv_blocks_walked()
+        pages = self.cache.kv_pages_fetched()
         self.kv_blocks_walked += blocks
         self.kv_pages_fetched += pages
         return TraceAnnotation("engine.launch", kv_blocks=blocks,
@@ -1730,8 +1569,6 @@ class ServingEngine:
         self.pipelined_dispatches = 0
         self.preemptions = 0
         self.suspends = 0
-        self.migrations = 0
-        self.migrated_wire_bytes = 0
         # the pool high-water mark is a stat too: warmup's throwaway
         # admission must not overstate the measured run's peak
         self.cache.peak_pages_in_use = self.cache.allocator.pages_in_use

@@ -65,13 +65,10 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..core.boundary import (BoundaryCodec, coded_kv_migrate,
-                             kv_wire_bytes, kv_wire_roundtrip)
 from ..kernels.paged_decode import (blocks_walked, pages_fetched,
                                     pages_per_block)
 from ..launch.specs import (CellPlan, cache_specs, default_num_pages,
-                            migrate_stage_shape, paged_cache_specs,
-                            pages_per_slot)
+                            paged_cache_specs, pages_per_slot)
 from ..models.context import axes_linear_index, pool_local_pages
 from .errors import CacheOverflowError, PagePoolExhausted, SlotsExhausted
 
@@ -192,13 +189,6 @@ class SlotAllocator:
 
     def free_pages_in_group(self, group: int) -> int:
         return sum(len(d) for d in self._free_pages[group])
-
-    def limbo_pages_in_group(self, group: int) -> int:
-        """Pages of ``group`` parked in deferred-free limbo (freed, but an
-        uncommitted device step's snapshot may still name them)."""
-        lo = group * self.pages_per_group
-        hi = lo + self.pages_per_group
-        return sum(1 for _, p in self._limbo if lo <= p < hi)
 
     def _limbo_by_shard(self, group: int) -> list:
         """Limbo page count per tp shard of ``group`` — what each shard's
@@ -356,8 +346,7 @@ class SlotAllocator:
 
     # -- slot lifecycle ----------------------------------------------------
 
-    def can_admit(self, seq_len: int, after_flush: bool = False,
-                  groups=None) -> bool:
+    def can_admit(self, seq_len: int, after_flush: bool = False) -> bool:
         """True iff some free slot's group can map ``seq_len`` tokens.
 
         Limbo-aware (see ``_admit_capacity``): pages parked in
@@ -366,26 +355,20 @@ class SlotAllocator:
         would starve mid-flight.  ``after_flush=True`` answers the
         counterfactual "would this admit pass once the pipeline drains
         and limbo pages rejoin the pool?" — the engine's
-        flush-then-retry gate.  ``groups`` (optional iterable) restricts
-        the candidate free slots to those dp groups — the disaggregated
-        engine admits prefills into prefill-role groups only.
+        flush-then-retry gate.
         """
         if not 0 < seq_len <= self.max_seq:
             return False
         need = self.pages_needed(seq_len)
-        cand = set(groups) if groups is not None else None
         return any(need <= self._admit_capacity(self.group_of(s),
                                                 after_flush=after_flush)
-                   for s in self._free
-                   if cand is None or self.group_of(s) in cand)
+                   for s in self._free)
 
-    def alloc(self, seq_len: int, groups=None) -> int:
+    def alloc(self, seq_len: int) -> int:
         """Claim a slot + map pages for ``seq_len`` already-held tokens.
 
         Picks the first free slot (FIFO) whose group has enough free
-        pages; ``groups`` (optional iterable) restricts candidates to
-        those dp groups (disaggregated admission targets prefill-role
-        groups).  Typed failures: ``SlotsExhausted`` when no slot is
+        pages.  Typed failures: ``SlotsExhausted`` when no slot is
         free, ``PagePoolExhausted`` when slots are free but no group can
         map the request — the caller queues in either case.  Deliberately
         limbo-PERMISSIVE (mechanism, not policy): free-list pages are
@@ -394,15 +377,10 @@ class SlotAllocator:
         """
         if not 0 < seq_len <= self.max_seq:
             raise ValueError(f"seq_len {seq_len} not in (0, {self.max_seq}]")
-        cand = set(groups) if groups is not None else None
-        free = [s for s in self._free
-                if cand is None or self.group_of(s) in cand]
-        if not free:
-            raise SlotsExhausted(
-                f"all {self.num_slots} slots in use"
-                + ("" if cand is None else f" (groups {sorted(cand)})"))
+        if not self._free:
+            raise SlotsExhausted(f"all {self.num_slots} slots in use")
         need = self.pages_needed(seq_len)
-        for slot in free:
+        for slot in self._free:
             if need <= self._fresh_capacity(self.group_of(slot)):
                 break
         else:
@@ -462,141 +440,6 @@ class SlotAllocator:
         self._len[slot] = 0
         self._free.append(slot)
 
-    # -- cross-group migration (disaggregated prefill/decode) --------------
-
-    def pages_in_use_by_group(self, group: int) -> int:
-        lo = group * self._slots_per_group
-        return sum(len(self._pages[s])
-                   for s in range(lo, lo + self._slots_per_group))
-
-    def free_slot_in_group(self, group: int) -> int | None:
-        """First free slot of ``group`` (FIFO), or None."""
-        for s in self._free:
-            if self.group_of(s) == group:
-                return s
-        return None
-
-    def placement_counts(self, group: int, need: int) -> list | None:
-        """Per-shard page counts balanced placement WOULD give a fresh
-        slot of ``group`` mapping ``need`` pages right now, or None if
-        the group cannot map them.  Pure simulation (no mutation) — the
-        disaggregated router uses it to predict, before a prefill runs,
-        whether a decode group could mirror the resulting placement.
-        """
-        avail = [len(d) for d in self._free_pages[group]]
-        cnt = [0] * self.shards_per_group
-        for _ in range(need):
-            cands = [s for s in range(self.shards_per_group)
-                     if avail[s] and cnt[s] < self.pages_per_shard]
-            if not cands:
-                return None
-            s = min(cands, key=lambda s: (cnt[s], -avail[s], s))
-            avail[s] -= 1
-            cnt[s] += 1
-        return cnt
-
-    def peek_alloc(self, seq_len: int, groups=None) -> int | None:
-        """The slot ``alloc(seq_len, groups)`` would claim RIGHT NOW (no
-        mutation), or None if it would raise.  The disaggregated router
-        runs its whole admission pre-check — prefill-group capacity,
-        placement simulation, decode-group mirror capacity — against
-        this prediction before popping the queue head, so an admission
-        that starts can always finish."""
-        if not 0 < seq_len <= self.max_seq:
-            return None
-        cand = set(groups) if groups is not None else None
-        need = self.pages_needed(seq_len)
-        for s in self._free:
-            if cand is not None and self.group_of(s) not in cand:
-                continue
-            if need <= self._fresh_capacity(self.group_of(s)):
-                return s
-        return None
-
-    def can_place_mirror(self, dst_group: int, counts) -> bool:
-        """True iff ``dst_group`` has a free slot and each tp shard s can
-        supply ``counts[s]`` pages from its free deque — the mirror
-        feasibility test against a SIMULATED source placement
-        (``placement_counts``), used before the source pages even
-        exist."""
-        if self.free_slot_in_group(dst_group) is None:
-            return False
-        free = self._free_pages[dst_group]
-        return all(int(c) <= len(free[s]) for s, c in enumerate(counts))
-
-    def can_migrate(self, src_slot: int, dst_group: int) -> bool:
-        """True iff ``dst_group`` has a free slot AND every tp shard can
-        mirror ``src_slot``'s per-shard page counts from its own free
-        deque.  Mirroring is stricter than balanced placement — the
-        device migration is ONE ppermute in which shard s of the source
-        group sends its pages straight to shard s of the destination —
-        so a group passing ``can_admit`` may still refuse a migration;
-        the router treats that as starvation and keeps the request
-        queued (or falls back to another decode group).
-        """
-        if self._len[src_slot] <= 0 or dst_group == self.group_of(src_slot):
-            return False
-        if self.free_slot_in_group(dst_group) is None:
-            return False
-        cnt = self._shard_count[src_slot]
-        free = self._free_pages[dst_group]
-        return all(int(cnt[s]) <= len(free[s])
-                   for s in range(self.shards_per_group))
-
-    def migrate_slot(self, src_slot: int, dst_group: int) -> int:
-        """Move ``src_slot``'s mapping to a fresh slot of ``dst_group``
-        with SHARD-MIRRORED placement; returns the new slot id.
-
-        For each source page held on tp shard s (in compacted-list
-        order), a destination page is popped from ``dst_group``'s
-        shard-s free deque and placed at the SAME list position with the
-        SAME position offset — so the device-side handoff is a single
-        ``ppermute`` over the dp axis (shard s talks only to shard s)
-        and the destination compacted lists/block table describe the
-        received pages without any re-indexing.  The source slot is then
-        freed through the ordinary ``free``/limbo machinery: with steps
-        in flight its pages park in deferred-free limbo, so a migration
-        can never hand a page to a new owner while an uncommitted
-        snapshot still names it.  Raises ``SlotsExhausted`` /
-        ``PagePoolExhausted`` (typed) when ``dst_group`` cannot take the
-        slot — callers should gate on ``can_migrate``.
-        """
-        if self._len[src_slot] <= 0:
-            raise ValueError(f"migrate_slot: slot {src_slot} is free")
-        src_group = self.group_of(src_slot)
-        if dst_group == src_group or not 0 <= dst_group < self.num_groups:
-            raise ValueError(
-                f"migrate_slot: dst_group {dst_group} invalid for slot "
-                f"{src_slot} of group {src_group}")
-        dst_slot = self.free_slot_in_group(dst_group)
-        if dst_slot is None:
-            raise SlotsExhausted(f"no free slot in group {dst_group}")
-        cnt = self._shard_count[src_slot]
-        free = self._free_pages[dst_group]
-        for s in range(self.shards_per_group):
-            if int(cnt[s]) > len(free[s]):
-                raise PagePoolExhausted(
-                    f"migrate slot {src_slot} -> group {dst_group}: shard "
-                    f"{s} must mirror {int(cnt[s])} page(s) but has "
-                    f"{len(free[s])} free")
-        self._free.remove(dst_slot)
-        pages_by_ordinal = {}
-        for s in range(self.shards_per_group):
-            for j in range(int(cnt[s])):
-                page = free[s].popleft()
-                self.page_list_loc[dst_slot, s, j] = page % self.pages_local
-                pos = int(self.page_list_pos[src_slot, s, j])
-                self.page_list_pos[dst_slot, s, j] = pos
-                ordinal = pos // self.page_size
-                self.block_table[dst_slot, ordinal] = page
-                pages_by_ordinal[ordinal] = page
-        self._pages[dst_slot] = [pages_by_ordinal[o]
-                                 for o in sorted(pages_by_ordinal)]
-        self._shard_count[dst_slot] = cnt
-        self._len[dst_slot] = self._len[src_slot]
-        self.free(src_slot)
-        return dst_slot
-
 
 def _is_kv_path(path) -> bool:
     return any(getattr(p, "key", None) in _KV_KEYS for p in path)
@@ -624,7 +467,7 @@ def make_init_fn(plan: CellPlan, mesh, page_size: int, num_pages: int):
 
 
 def make_insert_fn(plan: CellPlan, plan_pre: CellPlan, mesh,
-                   page_size: int, num_pages: int, kv_wire: str = "fp"):
+                   page_size: int, num_pages: int):
     """insert(cache, pre_cache, slot, pages) -> cache (donated, in place).
 
     ``pre_cache`` is the B=1 cache returned by the engine prefill step
@@ -635,14 +478,6 @@ def make_insert_fn(plan: CellPlan, plan_pre: CellPlan, mesh,
     KV over tp and scatter it page-block-wise into the pool — only the
     mapped pages are written (unmapped / non-resident targets drop), so
     an admit touches O(prompt_len), not O(max_seq), pool bytes.
-
-    ``kv_wire="coded"`` roundtrips the inserted KV through the pow2
-    int8 wire (``boundary.kv_wire_roundtrip``) so the pool holds
-    wire-representable values: a later coded migration then re-encodes
-    them bit-exactly (idempotence), which is what keeps disaggregated
-    and colocated greedy streams identical under a lossy KV wire.
-    Applied in EVERY topology when selected — colocated engines pay the
-    same (one-time, per-admit) quantization as disaggregated ones.
     """
     assert plan.cp == (plan.tp,) and plan_pre.cp == (plan_pre.tp,), (
         "engine admit requires tp-only context parallelism on both the "
@@ -679,10 +514,7 @@ def make_insert_fn(plan: CellPlan, plan_pre: CellPlan, mesh,
                 pps = pages.shape[0]
                 gpos = jnp.arange(pps * psz)
                 src = jnp.take(full, jnp.minimum(gpos, S_pre - 1), axis=1)
-                src = src.astype(c.dtype)
-                if kv_wire == "coded":
-                    src = kv_wire_roundtrip(src)     # per (position, head)
-                src = src.reshape(c.shape[0], pps, psz, -1)
+                src = src.astype(c.dtype).reshape(c.shape[0], pps, psz, -1)
                 loc, _ = pool_local_pages(pages, pidx, P_loc)
                 return c.at[:, loc].set(src, mode="drop")
             cur = lax.dynamic_index_in_dim(c, ls, axis=1, keepdims=False)
@@ -696,87 +528,14 @@ def make_insert_fn(plan: CellPlan, plan_pre: CellPlan, mesh,
     return jax.jit(fn, donate_argnums=(0,))
 
 
-def make_migrate_fn(plan: CellPlan, mesh, page_size: int, num_pages: int,
-                    src_group: int, dst_group: int, coded: bool):
-    """migrate(cache, src_bt, dst_bt, src_slot, dst_slot) -> cache
-    (donated): move one slot's paged KV + state rows across dp groups.
-
-    Compiled once per (src_group, dst_group) pair — the ppermute perm is
-    static.  Per KV leaf, each tp shard of the source group gathers its
-    resident pages of the source block row into a static
-    ``[U, pages_per_slot, page_size, Hkv, dh]`` head-space staging slab
-    (non-resident rows zeroed), sends it through ONE
-    ``boundary.coded_kv_migrate`` over the dp axis (pow2-absmax int8
-    wire + f32 scales when ``coded``, plain fp otherwise), and the
-    destination group's same-index shard scatters the slab through the
-    MIRRORED destination block row (``SlotAllocator.migrate_slot``
-    guarantees ordinal j is resident on dst shard s iff it was on src
-    shard s, so no cross-shard reshuffle is ever needed).  Non-resident
-    / non-destination targets drop exactly as on the insert path.
-    Recurrent/SSM state leaves ride a plain fp ppermute of the source
-    slot row into the destination slot row — O(1) per slot, see
-    ``coded_kv_migrate``'s coded-vs-fp contract.
-    """
-    _, cspecs = paged_cache_specs(plan, page_size, num_pages)
-    num_slots = plan.cell.global_batch
-    dp_size = plan.dp_size
-    slots_loc = num_slots // dp_size
-    pool_axes = tuple(plan.dp) + (plan.tp,)
-    assert len(plan.dp) == 1, "disaggregated migration needs one dp axis"
-    dp_axis = plan.dp[0]
-    perm = [(src_group, dst_group)]
-    codec = BoundaryCodec(mode="int8" if coded else "none")
-
-    def mig(cache, src_bt, dst_bt, src_slot, dst_slot):
-        pidx = axes_linear_index(pool_axes)
-        r_dp = lax.axis_index(dp_axis)
-        ls_src = jnp.clip(src_slot - src_group * slots_loc, 0,
-                          slots_loc - 1)
-        ls_dst = jnp.clip(dst_slot - dst_group * slots_loc, 0,
-                          slots_loc - 1)
-
-        def move(path, c):
-            if _is_kv_path(path):
-                P_loc = c.shape[1]
-                loc_s, ok_s = pool_local_pages(src_bt, pidx, P_loc)
-                stage = jnp.take(c, jnp.minimum(loc_s, P_loc - 1), axis=1)
-                stage = jnp.where(
-                    ok_s.reshape(1, -1, 1, 1), stage,
-                    jnp.zeros((), c.dtype))
-                stage = coded_kv_migrate(
-                    stage.reshape(migrate_stage_shape(plan, page_size,
-                                                      c.shape)),
-                    codec, dp_axis, perm)
-                loc_d, _ = pool_local_pages(dst_bt, pidx, P_loc)
-                return c.at[:, loc_d].set(
-                    stage.reshape(c.shape[0], -1, *c.shape[2:]).astype(
-                        c.dtype), mode="drop")
-            row = lax.dynamic_index_in_dim(c, ls_src, axis=1,
-                                           keepdims=False)
-            row = lax.ppermute(row, dp_axis, perm)
-            cur = lax.dynamic_index_in_dim(c, ls_dst, axis=1,
-                                           keepdims=False)
-            new = jnp.where(r_dp == dst_group, row.astype(c.dtype), cur)
-            return c.at[:, ls_dst].set(new)
-
-        return jax.tree_util.tree_map_with_path(move, cache)
-
-    fn = jax.shard_map(mig, mesh=mesh,
-                       in_specs=(cspecs, P(), P(), P(), P()),
-                       out_specs=cspecs, check_vma=False)
-    return jax.jit(fn, donate_argnums=(0,))
-
-
 class PagedKVCache:
     """Shared device KV page pool + slot-major state + host allocator."""
 
     def __init__(self, plan: CellPlan, plan_pre: CellPlan, mesh,
-                 page_size: int = 64, num_pages: int | None = None,
-                 kv_wire: str = "fp"):
+                 page_size: int = 64, num_pages: int | None = None):
         self.plan = plan
         self.mesh = mesh
         self.page_size = page_size
-        self.kv_wire = kv_wire
         self.num_pages = (default_num_pages(plan, page_size)
                           if num_pages is None else num_pages)
         groups = plan.dp_size if plan.batch_sharded else 1
@@ -798,14 +557,10 @@ class PagedKVCache:
             self.allocator.pages_per_shard, page_size, kv[0].shape[-1],
             kv[0].dtype.itemsize) if kv else None)
         self._insert = make_insert_fn(plan, plan_pre, mesh, page_size,
-                                      self.num_pages, kv_wire)
+                                      self.num_pages)
         #: exact-length prefill buckets: one compiled insert per prefill
         #: seq length (the gather/re-slice inside depends on S_pre)
         self._insert_fns = {plan_pre.cell.seq_len: self._insert}
-        #: compiled cross-group migration programs, one per static
-        #: (src_group, dst_group) ppermute pair
-        self._migrate_fns: dict = {}
-        self._mig_bytes: int | None = None
         self.peak_pages_in_use = 0
 
     def _note_peak(self):
@@ -837,17 +592,15 @@ class PagedKVCache:
         if S not in self._insert_fns:
             self._insert_fns[S] = make_insert_fn(
                 self.plan, plan_pre, self.mesh, self.page_size,
-                self.num_pages, self.kv_wire)
+                self.num_pages)
         return self._insert_fns[S]
 
-    def admit(self, pre_cache, seq_len: int, plan_pre: CellPlan = None,
-              groups=None) -> int:
+    def admit(self, pre_cache, seq_len: int,
+              plan_pre: CellPlan = None) -> int:
         """Allocate a slot, map ``ceil(seq_len/page_size)`` pages, and
         splice the prefilled cache into them.  ``plan_pre`` selects a
-        non-default exact-length prefill bucket's insert program;
-        ``groups`` restricts the slot to those dp groups (disaggregated
-        admission lands prefills in prefill-role groups)."""
-        slot = self.allocator.alloc(seq_len, groups=groups)
+        non-default exact-length prefill bucket's insert program."""
+        slot = self.allocator.alloc(seq_len)
         self._note_peak()
         ins = (self._insert if plan_pre is None
                else self.insert_fn_for(plan_pre))
@@ -858,53 +611,6 @@ class PagedKVCache:
             self.buffers, pre_cache, jnp.asarray(slot, jnp.int32),
             np.array(self.allocator.block_table[slot], np.int32))
         return slot
-
-    def migrate(self, src_slot: int, dst_group: int) -> int:
-        """Move ``src_slot`` to a fresh slot of ``dst_group``: mirror the
-        page mapping on the host (``SlotAllocator.migrate_slot``), then
-        launch the compiled one-ppermute device handoff.  The source
-        block row is snapshotted BEFORE the host free so the device
-        gather still sees it; the freed source pages go through the
-        ordinary limbo machinery, so with steps in flight no new owner
-        can touch them until every dispatched snapshot commits.  Returns
-        the destination slot id."""
-        alloc = self.allocator
-        src_group = alloc.group_of(src_slot)
-        src_bt = np.array(alloc.block_table[src_slot], np.int32)
-        dst_slot = alloc.migrate_slot(src_slot, dst_group)
-        key = (src_group, dst_group)
-        if key not in self._migrate_fns:
-            self._migrate_fns[key] = make_migrate_fn(
-                self.plan, self.mesh, self.page_size, self.num_pages,
-                src_group, dst_group, coded=self.kv_wire == "coded")
-        self.buffers = self._migrate_fns[key](
-            self.buffers, jnp.asarray(src_bt),
-            np.array(alloc.block_table[dst_slot], np.int32),
-            jnp.asarray(src_slot, jnp.int32),
-            jnp.asarray(dst_slot, jnp.int32))
-        return dst_slot
-
-    def migrate_wire_bytes(self) -> int:
-        """Wire bytes of ONE slot migration (shape-static per engine):
-        the per-shard KV staging slabs across all tp shards — int8 +
-        f32 scales when ``kv_wire="coded"``, dtype bytes otherwise —
-        plus the fp state rows.  What ``SLOMonitor`` adds to the step
-        trace and ``emio_cost_from_trace`` prices per handoff."""
-        if self._mig_bytes is None:
-            coded = self.kv_wire == "coded"
-            shards = self.allocator.shards_per_group
-            total = 0
-            for path, leaf in jax.tree_util.tree_leaves_with_path(
-                    self.buffers):
-                if _is_kv_path(path):
-                    shape = migrate_stage_shape(self.plan, self.page_size,
-                                                leaf.shape)
-                    total += shards * kv_wire_bytes(
-                        shape, leaf.dtype.itemsize, coded)
-                else:
-                    total += leaf.nbytes // leaf.shape[1]
-            self._mig_bytes = int(total)
-        return self._mig_bytes
 
     def ensure(self, slot: int, new_len: int):
         """Map pages (alloc-on-extend) so positions < ``new_len`` are

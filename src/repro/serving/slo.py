@@ -85,15 +85,12 @@ class StepEvent:
     pages_in_use: int
     pages_in_limbo: int
     wire_bytes: float                # total die-to-die bytes the tick's
-    #                                  device step moved (0 if unknown),
-    #                                  INCLUDING any KV migration below
-    mig_bytes: float = 0.0           # disagg KV-migration bytes folded
-    #                                  into this tick's wire_bytes
+    #                                  device step moved (0 if unknown)
     accepted_len: float = 0.0        # mean tokens committed per (slot,
     #                                  verify-step) this tick — 0.0 on
     #                                  non-speculative ticks
     #: per-collective split of ``wire_bytes`` (stream kind -> bytes:
-    #: psum / head_all_gather / partial_combine / kv_migrate / ...);
+    #: psum / head_all_gather / partial_combine / ...);
     #: always sums to ``wire_bytes``, empty when only the scalar was
     #: registered
     wire_streams: Dict[str, float] = dataclasses.field(
@@ -147,12 +144,9 @@ class SLOMonitor:
         self.steps: List[StepEvent] = []
         self.preemptions = 0
         self.suspends = 0
-        self.migrations = 0
-        self.migrated_bytes = 0.0
         self._t_last: Optional[float] = None
         self._tokens_last = 0
         self._steps_last = 0
-        self._pending_mig_bytes = 0.0
         self._spec_commits_last = 0
         self._spec_verifies_last = 0
         self._spec_k = 0
@@ -209,18 +203,6 @@ class SLOMonitor:
                 rec.t_first = rec.t_finish = None
                 rec.n_tokens = 0
 
-    def on_migrate(self, rid, src_group: int, dst_group: int,
-                   wire_bytes: int):
-        """Disaggregated KV handoff: ``wire_bytes`` moved from the
-        prefill group to the decode group for ``rid``.  Migrations fire
-        during admission, between ticks — the bytes are held pending and
-        folded into the NEXT ``StepEvent``'s ``wire_bytes`` (and
-        surfaced separately as ``mig_bytes``) so the EMIO co-simulation
-        prices them with the step that paid for them."""
-        self.migrations += 1
-        self.migrated_bytes += wire_bytes
-        self._pending_mig_bytes += wire_bytes
-
     # -- per-tick recorder -------------------------------------------------
 
     def on_step(self, engine):
@@ -233,7 +215,6 @@ class SLOMonitor:
         d_steps = engine.decode_steps - self._steps_last
         self._steps_last = engine.decode_steps
         alloc = engine.cache.allocator
-        mig, self._pending_mig_bytes = self._pending_mig_bytes, 0.0
         # per-step accepted-draft length: how many of this tick's verify
         # participations' tokens the drafter paid for (the acceptance
         # signal the drafter benches compare ngram vs heads on).
@@ -270,35 +251,12 @@ class SLOMonitor:
             streams = {"total": base}
         else:
             streams = {}
-        if mig > 0:
-            streams["kv_migrate"] = streams.get("kv_migrate", 0.0) + mig
         self.steps.append(StepEvent(
             t=now, dt=dt, kind=kind, tokens=max(d_tokens, 0),
             queue_depth=engine.queue_depth, active=engine.num_active,
             pages_in_use=alloc.pages_in_use,
             pages_in_limbo=alloc.pages_in_limbo,
-            wire_bytes=base + mig,
-            mig_bytes=mig, accepted_len=acc_len, wire_streams=streams))
-
-    def _flush_pending_mig(self):
-        """Fold migration bytes still pending after the LAST tick into a
-        terminal ``kind="drain"`` event so they are never dropped from
-        wire accounting (a migration admitted on the final tick has no
-        following ``on_step`` to absorb it).  ``dt=0.0`` keeps the event
-        out of the step-latency percentiles."""
-        mig, self._pending_mig_bytes = self._pending_mig_bytes, 0.0
-        if mig <= 0:
-            return
-        last = self.steps[-1] if self.steps else None
-        self.steps.append(StepEvent(
-            t=self._t_last if self._t_last is not None else self.clock(),
-            dt=0.0, kind="drain", tokens=0,
-            queue_depth=last.queue_depth if last else 0,
-            active=last.active if last else 0,
-            pages_in_use=last.pages_in_use if last else 0,
-            pages_in_limbo=last.pages_in_limbo if last else 0,
-            wire_bytes=mig, mig_bytes=mig,
-            wire_streams={"kv_migrate": mig}))
+            wire_bytes=base, accepted_len=acc_len, wire_streams=streams))
 
     # -- reductions --------------------------------------------------------
 
@@ -308,7 +266,6 @@ class SLOMonitor:
 
     def report(self) -> dict:
         """Structured SLO report."""
-        self._flush_pending_mig()
         fin = self._finished()
         t = self.targets
         ttft = [(r.t_first - r.t_submit) * 1e3 for r in fin]
@@ -367,12 +324,6 @@ class SLOMonitor:
                          / self._spec_k
                          if self.accepted_lens and self._spec_k else 0.0),
             },
-            "migration": {
-                "count": self.migrations,
-                "kb_total": self.migrated_bytes / 1e3,
-                "kb_per_request": (self.migrated_bytes / 1e3
-                                   / max(len(fin), 1)),
-            },
         }
 
     def per_class_report(self) -> dict:
@@ -399,12 +350,11 @@ class SLOMonitor:
         (``NocSim.simulate_trace``: ``wire_streams``, ``tokens``) and
         the closed-form bridge (``emio_cost_from_trace``:
         ``wire_bytes``, ``tokens``) consume, plus scheduling context."""
-        self._flush_pending_mig()
         return [{"t": s.t, "dt_us": s.dt * 1e6, "kind": s.kind,
                  "tokens": s.tokens, "queue_depth": s.queue_depth,
                  "active": s.active, "pages_in_use": s.pages_in_use,
                  "pages_in_limbo": s.pages_in_limbo,
-                 "wire_bytes": s.wire_bytes, "mig_bytes": s.mig_bytes,
+                 "wire_bytes": s.wire_bytes,
                  "accepted_len": s.accepted_len,
                  "wire_streams": dict(s.wire_streams)}
                 for s in self.steps]

@@ -271,7 +271,7 @@ class NocSim:
         ``steps`` is an ``SLOMonitor.step_trace()`` record list (or the
         ``slo.load_trace`` of its JSONL): each record's
         ``wire_streams`` maps collective stream kind (psum /
-        head_all_gather / partial_combine / kv_migrate / ...) to the
+        head_all_gather / partial_combine / ...) to the
         die-to-die bytes that collective moved during the tick; records
         without a stream split fall back to pricing the aggregate
         ``wire_bytes`` as one ``"total"`` stream.
@@ -397,7 +397,7 @@ PAPER_MODELS = {
 class TraceStepReport:
     """Cycle-level cost of one serving tick's boundary traffic."""
 
-    kind: str                       # step kind ("decode"/"verify"/"drain")
+    kind: str                       # step kind ("decode"/"verify")
     tokens: int
     cycles: float                   # serdes + deserialize + hop fill
     e_pe: float                     # boundary encode/decode accumulates
@@ -484,7 +484,7 @@ def emio_cost_from_trace(steps: Sequence[dict],
     """
     cfg = cfg or NocConfig()
     nc = max(1, cfg.boundary_cores)
-    cycles = energy = mig_bytes = 0.0
+    cycles = energy = 0.0
     tokens = 0
     for s in steps:
         pb = float(s.get("wire_bytes", 0.0))
@@ -492,9 +492,6 @@ def emio_cost_from_trace(steps: Sequence[dict],
             cycles += math.floor(pb / nc) * cfg.cycles_ser + pb
             energy += pb * cfg.e_d2d
         tokens += int(s.get("tokens", 0))
-        # disagg KV migrations are already folded into wire_bytes (and
-        # thus priced above); surface their share for the report
-        mig_bytes += float(s.get("mig_bytes", 0.0))
     return {
         "steps": len(steps),
         "tokens": tokens,
@@ -503,5 +500,4 @@ def emio_cost_from_trace(steps: Sequence[dict],
         "e_emio": energy,
         "emio_cycles_per_token": cycles / max(tokens, 1),
         "e_emio_per_token": energy / max(tokens, 1),
-        "mig_bytes": mig_bytes,
     }

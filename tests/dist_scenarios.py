@@ -562,11 +562,13 @@ def scenario_serving_paged_mixed():
 def scenario_serving_fused_parity():
     """Fused paged-decode kernel on the (2, 4) mesh: the compacted
     per-shard page lists really partition each slot's pages across the
-    4 pool shards of its dp group, and the fused gather->flash->combine
-    path is token-identical to the reference dense-gather path — for
-    the plain and spike codecs, with the pool sized below the dense
-    reservation so slots contend for pages, and (spike) through the
-    speculative verify path (K1 > 1) as well."""
+    4 pool shards of its dp group, and every served token is the
+    teacher-forced argmax (within a 1e-4 logit gap) — for the plain and
+    spike codecs, with the pool sized below the dense reservation so
+    slots contend for pages, and (spike) through the speculative verify
+    path (K1 > 1) as well."""
+    from _teacher_forced import (assert_on_teacher_forced,
+                                 teacher_forced_logits)
     from repro.configs import get_config
     from repro.configs.base import ShapeCell
     from repro.configs.reduced import reduced
@@ -589,14 +591,12 @@ def scenario_serving_fused_parity():
         plan = SP.make_plan(cfg, cell, mesh)
         params = TR.init_sharded_params(cfg, plan, mesh,
                                         jax.random.PRNGKey(0))
-        ref = ServingEngine(cfg, mesh, params, EngineConfig(
-            **kw, attn_kernel="reference"))
-        res_r = ref.run(reqs())
-        fus = ServingEngine(cfg, mesh, params, EngineConfig(
-            **kw, attn_kernel="fused"))
+        oracle = teacher_forced_logits(cfg, mesh, 48, 8)
+        fus = ServingEngine(cfg, mesh, params, EngineConfig(**kw))
         res_f = fus.run(reqs())
-        for i in range(len(prompts)):
-            assert res_f[i] == res_r[i], (codec, i, res_r[i], res_f[i])
+        assert_on_teacher_forced(oracle, params, prompts,
+                                 [res_f[i] for i in range(len(prompts))],
+                                 what=(codec,))
         alloc = fus.cache.allocator
         # the engine really built 4-way compacted lists for this mesh
         assert alloc.shards_per_group == 4
@@ -605,150 +605,15 @@ def scenario_serving_fused_parity():
         assert (alloc.page_list_loc == -1).all()
         if codec == "spike_fused":
             spec = ServingEngine(cfg, mesh, params, EngineConfig(
-                **kw, attn_kernel="fused", spec_k=3))
+                **kw, spec_k=3))
             res_s = spec.run(reqs())
             assert spec.spec_verifies > 0 and spec.mean_accepted_len > 1.0
-            for i in range(len(prompts)):
-                assert res_s[i] == res_r[i], (i, res_r[i], res_s[i])
+            assert_on_teacher_forced(
+                oracle, params, prompts,
+                [res_s[i] for i in range(len(prompts))],
+                what=(codec, "spec"))
+            assert spec.cache.allocator.pages_in_use == 0
         print(f"fused parity OK {codec}")
-
-
-def scenario_serving_disagg_parity():
-    """Disaggregated prefill/decode on the (2, 4) mesh: dp group 0 owns
-    prefill, dp group 1 owns decode, and every admission hands the
-    finished prefill's paged KV across in ONE coded ppermute onto pages
-    the decode group mapped for it.  Token streams must be bit-identical
-    to the colocated engine for BOTH wire formats — fp and the
-    pow2-absmax int8 coded wire (whose scales are exact powers of two,
-    so encode/decode is idempotent on the pool) — with migrations
-    landing mid-trace under queue pressure, the coded wire moving fewer
-    bytes, and both groups draining page/limbo-clean.  A hybrid
-    (attention + mamba) leg checks recurrent state rows ride the same
-    migration."""
-    from repro.configs import get_config
-    from repro.configs.base import ShapeCell
-    from repro.configs.reduced import reduced
-    from repro.launch import specs as SP, train as TR
-    from repro.serving import EngineConfig, Request, ServingEngine
-    mesh = mesh24()
-    P_len, N = 16, 8
-    kw = dict(num_slots=4, max_seq=32, prefill_len=16, page_size=8)
-    for codec in ("none", "spike_fused"):
-        hnn = "ann" if codec == "none" else "hnn"
-        cfg = reduced(get_config("qwen1.5-0.5b", hnn_mode=hnn)).replace(
-            dtype=jnp.float32, codec=codec)
-        cell = ShapeCell("serve_decode", kw["max_seq"], kw["num_slots"],
-                         "decode")
-        plan = SP.make_plan(cfg, cell, mesh)
-        params = TR.init_sharded_params(cfg, plan, mesh,
-                                        jax.random.PRNGKey(0))
-        rng = np.random.RandomState(0)
-        prompts = [list(rng.randint(0, cfg.vocab, P_len)) for _ in range(6)]
-        reqs = lambda: [Request(rid=i, prompt=p, max_new_tokens=N)
-                        for i, p in enumerate(prompts)]
-        ref = ServingEngine(cfg, mesh, params, EngineConfig(**kw)).run(
-            reqs())
-        wire = {}
-        for kv_wire in ("fp", "coded"):
-            eng = ServingEngine(cfg, mesh, params, EngineConfig(
-                **kw, disagg=True, kv_wire=kv_wire))
-            res = eng.run(reqs())
-            for i in range(6):
-                assert res[i] == ref[i], (codec, kv_wire, i, ref[i], res[i])
-            # 6 admits through a 2-slot decode group: every one migrated,
-            # the later ones mid-trace while earlier slots still decode
-            assert eng.migrations == 6, (kv_wire, eng.migrations)
-            assert eng.migrated_wire_bytes \
-                == 6 * eng.cache.migrate_wire_bytes()
-            wire[kv_wire] = eng.cache.migrate_wire_bytes()
-            alloc = eng.cache.allocator
-            assert alloc.pages_in_use == 0 and alloc.pages_in_limbo == 0
-            assert (alloc.block_table == -1).all()
-        assert wire["coded"] < wire["fp"], wire
-        # the pipelined + speculative disagg engine rides the same coded
-        # migration path and stays token-identical
-        spec = ServingEngine(cfg, mesh, params, EngineConfig(
-            **kw, disagg=True, kv_wire="coded", spec_k=2, async_depth=1))
-        res_s = spec.run(reqs())
-        for i in range(6):
-            assert res_s[i] == ref[i], (codec, "spec", i, ref[i], res_s[i])
-        assert spec.migrations == 6
-        assert spec.cache.allocator.pages_in_limbo == 0
-        print(f"serving disagg parity OK {codec} "
-              f"wire={wire['coded']}/{wire['fp']}B")
-    # hybrid family: slot-major mamba state rows migrate alongside the
-    # paged attention KV (plain ppermute for state, coded for KV)
-    cfg = reduced(get_config("jamba-1.5-large-398b", hnn_mode="ann")
-                  ).replace(dtype=jnp.float32, codec="none")
-    cell = ShapeCell("serve_decode", kw["max_seq"], kw["num_slots"],
-                     "decode")
-    plan = SP.make_plan(cfg, cell, mesh)
-    params = TR.init_sharded_params(cfg, plan, mesh, jax.random.PRNGKey(0))
-    rng = np.random.RandomState(1)
-    prompts = [list(rng.randint(0, cfg.vocab, P_len)) for _ in range(4)]
-    reqs = lambda: [Request(rid=i, prompt=p, max_new_tokens=6)
-                    for i, p in enumerate(prompts)]
-    ref = ServingEngine(cfg, mesh, params, EngineConfig(**kw)).run(reqs())
-    eng = ServingEngine(cfg, mesh, params, EngineConfig(
-        **kw, disagg=True, kv_wire="coded"))
-    assert eng.cache.state_bytes_per_slot() > 0      # really hybrid
-    res = eng.run(reqs())
-    for i in range(4):
-        assert res[i] == ref[i], ("jamba", i, ref[i], res[i])
-    assert eng.migrations == 4
-    print("serving disagg parity OK jamba")
-
-
-def scenario_serving_disagg_fuzz():
-    """One fuzz draw of disagg-vs-colocated identity, parameterized by
-    argv: <spec_k> <async_depth> <codec> <kv_wire> <seed>.  The seed
-    derives a random schedule (mixed prompt lengths, max_new, eos
-    pressure); the disaggregated engine must be token-identical to the
-    colocated one and drain clean.  Driven by the hypothesis property in
-    tests/test_serving.py (and by fixed combos in the CI dist lane)."""
-    from repro.configs import get_config
-    from repro.configs.base import ShapeCell
-    from repro.configs.reduced import reduced
-    from repro.launch import specs as SP, train as TR
-    from repro.serving import EngineConfig, Request, ServingEngine
-    spec_k, async_depth = int(sys.argv[2]), int(sys.argv[3])
-    codec, kv_wire, seed = sys.argv[4], sys.argv[5], int(sys.argv[6])
-    mesh = mesh24()
-    hnn = "ann" if codec == "none" else "hnn"
-    cfg = reduced(get_config("qwen1.5-0.5b", hnn_mode=hnn)).replace(
-        dtype=jnp.float32, codec=codec)
-    kw = dict(num_slots=4, max_seq=32, prefill_len=16, page_size=8,
-              eos_id=7)
-    cell = ShapeCell("serve_decode", kw["max_seq"], kw["num_slots"],
-                     "decode")
-    plan = SP.make_plan(cfg, cell, mesh)
-    params = TR.init_sharded_params(cfg, plan, mesh, jax.random.PRNGKey(0))
-    rng = np.random.RandomState(seed)
-    reqs = lambda: [Request(rid=i,
-                            prompt=list(rng.randint(0, 256, plen)),
-                            max_new_tokens=int(mnt))
-                    for i, (plen, mnt) in enumerate(
-                        (int(rng.randint(1, 17)), rng.randint(1, 9))
-                        for _ in range(int(rng.randint(1, 8))))]
-    sched = reqs()
-    clone = lambda: [Request(rid=r.rid, prompt=r.prompt,
-                             max_new_tokens=r.max_new_tokens)
-                     for r in sched]
-    ref = ServingEngine(cfg, mesh, params, EngineConfig(**kw)).run(clone())
-    eng = ServingEngine(cfg, mesh, params, EngineConfig(
-        **kw, disagg=True, kv_wire=kv_wire, spec_k=spec_k,
-        async_depth=async_depth))
-    res = eng.run(clone())
-    assert set(res) == set(ref)
-    for i in ref:
-        assert res[i] == ref[i], (i, ref[i], res[i])
-    assert eng.migrations == len(sched)
-    alloc = eng.cache.allocator
-    assert alloc.pages_in_use == 0 and alloc.pages_in_limbo == 0
-    assert (alloc.block_table == -1).all()
-    print(f"disagg fuzz OK spec_k={spec_k} depth={async_depth} "
-          f"{codec}/{kv_wire} seed={seed} n={len(sched)} "
-          f"migrated={eng.migrated_wire_bytes}B")
 
 
 def scenario_serving_spec_recurrent_fallback():

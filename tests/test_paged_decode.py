@@ -1,7 +1,8 @@
-"""Fused paged-decode attention: kernel-vs-oracle sweeps, compacted
-per-shard page-list invariants, and engine fused-vs-reference identity.
+"""Fused paged-decode attention: kernel-vs-oracle sweeps and compacted
+per-shard page-list invariants.
 
-Three layers, matching the data path:
+Two layers, matching the data path (the engine end to end is held to
+the teacher-forced forward in tests/test_engine_teacher_forced.py):
 
 1. ``kernels.paged_decode`` (interpret mode) against the dense
    single-softmax oracle ``kernels.ref.paged_decode_ref`` — GQA, K1 > 1
@@ -14,11 +15,6 @@ Three layers, matching the data path:
    alloc/extend/rollback/free interleavings: disjointness, per-shard
    residency, position ordering, agreement with the block table, and
    the enforced (never best-effort) per-shard width invariant.
-
-3. The serving engine end-to-end: greedy token streams of the fused
-   kernel path vs the reference gather path must be identical across
-   spec_k x async_depth x codec (the acceptance bar for making
-   ``attn_kernel="fused"`` the default).
 """
 import numpy as np
 import pytest
@@ -237,7 +233,7 @@ def test_pool_much_larger_than_live():
 def test_wire_epilogue_matches_quantize_partial():
     """The kernel's fused int8 epilogue implements the SAME per-token
     absmax contract as the host-side ``boundary.quantize_partial`` (the
-    reference path's encoder), so ``coded_combine_partials`` decodes
+    dense-cache decode path's encoder), so ``coded_combine_partials`` decodes
     either identically: scales agree to fp epsilon (the two are
     separately compiled programs, so bit-identity is not guaranteed)
     and the decoded wires agree to within one quantization step."""
@@ -523,110 +519,3 @@ def test_fuzz_allocator_invariants(seed, shards, steps):
         a.free(s)
     _check_lists(a)
     assert a.pages_in_use == 0
-
-
-# ---------------------------------------------------------------------------
-# 3. engine: fused vs reference token identity
-# ---------------------------------------------------------------------------
-
-PREFILL_LEN = 16
-MAX_SEQ = 32
-NUM_SLOTS = 3
-VOCAB = 256
-EOS = 7
-
-_MODELS = {}
-_ENGINES = {}
-
-
-def _model(codec):
-    if codec not in _MODELS:
-        import jax
-        import jax.numpy as jnp
-        from repro.configs import get_config
-        from repro.configs.base import ShapeCell
-        from repro.configs.reduced import reduced
-        from repro.launch import specs as SP, train as TR
-        from repro.launch.mesh import make_mesh
-
-        mesh = make_mesh((1, 1), ("data", "model"))
-        hnn = "ann" if codec == "none" else "hnn"
-        cfg = reduced(get_config("qwen1.5-0.5b", hnn_mode=hnn)).replace(
-            dtype=jnp.float32, codec=codec)
-        cell = ShapeCell("serve_decode", MAX_SEQ, NUM_SLOTS, "decode")
-        plan = SP.make_plan(cfg, cell, mesh)
-        params = TR.init_sharded_params(cfg, plan, mesh,
-                                        jax.random.PRNGKey(0))
-        _MODELS[codec] = (cfg, mesh, params)
-    return _MODELS[codec]
-
-
-def _engine(codec, kernel, spec_k, async_depth):
-    key = (codec, kernel, spec_k, async_depth)
-    if key not in _ENGINES:
-        from repro.serving import EngineConfig, ServingEngine
-        cfg, mesh, params = _model(codec)
-        _ENGINES[key] = ServingEngine(
-            cfg, mesh, params,
-            EngineConfig(num_slots=NUM_SLOTS, max_seq=MAX_SEQ,
-                         prefill_len=PREFILL_LEN, page_size=8, eos_id=EOS,
-                         spec_k=spec_k, async_depth=async_depth,
-                         attn_kernel=kernel))
-    return _ENGINES[key]
-
-
-def _run_schedule(eng, schedule, seed=77):
-    from repro.serving import Request
-    rng = np.random.RandomState(seed)
-    reqs = [Request(rid=i, prompt=list(rng.randint(0, VOCAB, plen)),
-                    max_new_tokens=mnt)
-            for i, (plen, mnt) in enumerate(schedule)]
-    return eng.run(reqs)
-
-
-_SCHEDULE = [(16, 6), (3, 4), (9, 5), (1, 3), (12, 6)]
-
-
-@pytest.mark.parametrize("codec", ["none", "spike_fused"])
-@pytest.mark.parametrize("spec_k", [0, 2])
-@pytest.mark.parametrize("async_depth", [0, 1])
-def test_engine_fused_matches_reference(codec, spec_k, async_depth):
-    """The acceptance bar: byte-identical greedy streams from the fused
-    Pallas path and the reference dense-gather path, across speculative
-    and pipelined variants and both codecs."""
-    ref = _run_schedule(_engine(codec, "reference", spec_k, async_depth),
-                        _SCHEDULE)
-    fus = _run_schedule(_engine(codec, "fused", spec_k, async_depth),
-                        _SCHEDULE)
-    assert set(ref) == set(fus)
-    for rid in ref:
-        assert fus[rid] == ref[rid], (codec, spec_k, async_depth, rid)
-    for eng in (_engine(codec, "reference", spec_k, async_depth),
-                _engine(codec, "fused", spec_k, async_depth)):
-        alloc = eng.cache.allocator
-        assert alloc.pages_in_use == 0 and alloc.pages_in_limbo == 0
-
-
-@pytest.mark.slow
-@settings(max_examples=5, deadline=None)
-@given(schedule=st.lists(
-    st.tuples(st.integers(1, PREFILL_LEN), st.integers(1, 8)),
-    min_size=1, max_size=6))
-def test_fuzz_engine_fused_matches_reference(schedule):
-    """Random schedules (queue pressure, mixed lengths, eos) through the
-    sync vanilla pair — the cheapest combo, fuzzed hardest."""
-    ref = _run_schedule(_engine("none", "reference", 0, 0), schedule)
-    fus = _run_schedule(_engine("none", "fused", 0, 0), schedule)
-    assert ref == fus
-
-
-def test_engine_rejects_unknown_kernel():
-    from repro.serving import EngineConfig
-    from repro.serving.errors import EngineConfigError
-    cfg, mesh, params = _model("none")
-    from repro.serving import ServingEngine
-    with pytest.raises(EngineConfigError):
-        ServingEngine(cfg, mesh, params,
-                      EngineConfig(num_slots=NUM_SLOTS, max_seq=MAX_SEQ,
-                                   prefill_len=PREFILL_LEN, page_size=8,
-                                   attn_kernel="dense"))

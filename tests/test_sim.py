@@ -101,20 +101,18 @@ def test_emio_cost_from_trace_eq8():
 
 def test_emio_cost_from_trace_edge_cases():
     """Closed-form bridge corners: zero-token steps still price their
-    bytes, mig_bytes-only steps count (migration bytes live inside
-    wire_bytes), and the mig share is surfaced separately."""
+    bytes, and the per-token figures guard the zero-token
+    denominator."""
     from repro.sim.noc import emio_cost_from_trace
 
     cfg = NocConfig()
     nc = cfg.boundary_cores
     steps = [
         {"wire_bytes": 500.0, "tokens": 0},               # drained tick
-        {"wire_bytes": 300.0, "mig_bytes": 300.0,         # mig-only
-         "tokens": 0},
+        {"wire_bytes": 300.0, "tokens": 0},
     ]
     out = emio_cost_from_trace(steps, cfg)
     assert out["tokens"] == 0
-    assert out["mig_bytes"] == pytest.approx(300.0)
     want = (math.floor(500.0 / nc) * cfg.cycles_ser + 500.0
             + math.floor(300.0 / nc) * cfg.cycles_ser + 300.0)
     assert out["emio_cycles"] == pytest.approx(want)
@@ -134,8 +132,8 @@ def _trace():
                           "partial_combine": 120.0},
          "wire_bytes": 1620.0},
         {"kind": "decode", "tokens": 4, "wire_bytes": 900.0},  # no split
-        {"kind": "drain", "tokens": 0, "wire_bytes": 300.0,
-         "mig_bytes": 300.0, "wire_streams": {"kv_migrate": 300.0}},
+        {"kind": "verify", "tokens": 0, "wire_bytes": 300.0,
+         "wire_streams": {"permute": 300.0}},
         {"kind": "decode", "tokens": 2, "wire_bytes": 0.0},    # idle wire
     ]
 
@@ -174,7 +172,7 @@ def test_simulate_trace_exact_per_stream_pricing():
     assert d["joules_per_token"] == pytest.approx(
         rep.total_energy / 10 * 1e-12)
     assert set(d["energy_breakdown"]) == {"PE", "MEM", "Router", "EMIO"}
-    assert d["wire_kb_by_stream"]["kv_migrate"] == pytest.approx(0.3)
+    assert d["wire_kb_by_stream"]["permute"] == pytest.approx(0.3)
 
 
 def test_simulate_trace_bounds_closed_form():

@@ -236,7 +236,7 @@ def test_monitor_step_trace_and_wire_bytes():
 def test_monitor_wire_streams_split_and_scaling():
     """A registered stream profile lands a per-collective breakdown in
     every StepEvent, scaled per DEVICE step, and always summing to the
-    scalar wire_bytes; migration bytes appear as a kv_migrate stream."""
+    scalar wire_bytes."""
     clk = _Clock()
     eng = _StubEngine()
     mon = SLOMonitor(clock=clk, wire_streams_per_step={
@@ -247,14 +247,12 @@ def test_monitor_wire_streams_split_and_scaling():
     mon.on_step(eng)
     clk.t = 0.001
     eng.decode_steps, eng.tokens_generated = 3, 6   # 2 steps this tick
-    mon.on_migrate("r0", 0, 1, 25)
     mon.on_step(eng)
     trace = mon.step_trace()
     assert trace[0]["wire_streams"] == {"psum": 60.0,
                                         "head_all_gather": 40.0}
     assert trace[1]["wire_streams"] == {"psum": 120.0,
-                                        "head_all_gather": 80.0,
-                                        "kv_migrate": 25.0}
+                                        "head_all_gather": 80.0}
     for s in trace:
         assert sum(s["wire_streams"].values()) == pytest.approx(
             s["wire_bytes"])
@@ -309,37 +307,7 @@ def test_monitor_warns_on_unknown_step_kind():
         mon3.on_step(eng3)
 
 
-def test_monitor_flushes_migration_on_last_tick():
-    """Bug regression: migration bytes arriving after the LAST tick
-    (admission at drain) used to be dropped from wire accounting.  They
-    now flush into a terminal dt=0 'drain' event, exactly once."""
-    clk = _Clock()
-    eng = _StubEngine()
-    mon = SLOMonitor(wire_bytes_per_step={"decode": 10.0}, clock=clk)
-    eng.decode_steps, eng.queue_depth = 1, 2
-    mon.on_step(eng)
-    mon.on_migrate("r9", 0, 1, 500)      # no further on_step
-    trace = mon.step_trace()
-    assert len(trace) == 2
-    drain = trace[-1]
-    assert drain["kind"] == "drain"
-    assert drain["dt_us"] == 0.0
-    assert drain["tokens"] == 0
-    assert drain["wire_bytes"] == 500.0
-    assert drain["mig_bytes"] == 500.0
-    assert drain["wire_streams"] == {"kv_migrate": 500.0}
-    assert drain["queue_depth"] == 2     # context copied from last tick
-    # total wire bytes conserved: 10 (step) + 500 (migration)
-    assert sum(s["wire_bytes"] for s in trace) == pytest.approx(510.0)
-    # flush is idempotent: report() + another step_trace() add nothing
-    rep = mon.report()
-    assert rep["migration"]["kb_total"] == pytest.approx(0.5)
-    assert len(mon.step_trace()) == 2
-    # dt=0 keeps the drain event out of the step-latency percentiles
-    assert rep["step_us"]["n"] == 0
-
-
-def test_monitor_flush_without_pending_is_noop():
+def test_monitor_report_appends_no_step():
     mon = SLOMonitor(clock=_Clock())
     eng = _StubEngine()
     mon.on_step(eng)
