@@ -231,6 +231,27 @@ def paged_cache_specs(plan: CellPlan, page_size: int, num_pages: int):
     return structs, specs
 
 
+def prefill_handoff_specs(plan: CellPlan):
+    """(ShapeDtypeStruct tree, PartitionSpec tree) of the cache the engine
+    prefill program hands to the pool insert.
+
+    ``cache_specs`` of the prefill plan, with each attention KV leaf
+    lane-flat ``[U, B, S, Hkv*dh]``: the page pool's row layout, so the
+    insert copies whole pages without relaying out a row.  State leaves
+    are as in ``cache_specs``.
+    """
+    structs, specs = cache_specs(plan)
+    for i, kind in enumerate(plan.cfg.pattern):
+        if kind in ("attn", "global", "local", "attn_moe"):
+            st, sp = structs[f"pos{i}"]["kv"], specs[f"pos{i}"]["kv"]
+            for kk in ("k", "v"):
+                U, B, S, Hkv, dh = st[kk].shape
+                st[kk] = jax.ShapeDtypeStruct((U, B, S, Hkv * dh),
+                                              st[kk].dtype)
+                sp[kk] = P(*sp[kk][:3], None)
+    return structs, specs
+
+
 def block_table_specs(plan: CellPlan, page_size: int):
     """(ShapeDtypeStruct, PartitionSpec) of the per-slot block table.
 

@@ -132,7 +132,8 @@ Tracing: the scheduler's layers are host spans on the profiler's clock
 (``jax.profiler.TraceAnnotation``, a no-op unless a profiler session is
 active), named ``engine.*``: ``engine.step`` (``tick``) holds
 ``engine.dispatch`` — ``engine.admit`` (``rid``, ``prompt_len``; under it
-``engine.prefill`` with its host input ``bytes`` and ``engine.insert``),
+``engine.prefill`` with its host input ``bytes`` and ``engine.insert``
+with its ``pages``),
 ``engine.ensure_pages``, ``engine.stage`` (``bytes``) and
 ``engine.launch`` — and ``engine.commit`` — ``engine.commit.wait`` (the
 host blocked on the device) and ``engine.commit.apply`` (bookkeeping;
@@ -143,7 +144,10 @@ handed to the device: every staged feed and each prefill's host inputs),
 launched step computes, summed over slots and pool shards; each launch's
 share is the ``kv_blocks`` stat of its ``engine.launch`` span),
 ``kv_pages_fetched`` (the pages of K, and as many of V, that such a call
-copies: the mapped ones; each launch's share is its ``kv_pages`` stat)
+copies: the mapped ones; each launch's share is its ``kv_pages`` stat),
+``kv_pages_inserted`` (the pool pages the admission inserts wrote,
+summed over shards: ``ceil(prompt_len / page_size)`` an admission; each
+admission's share is the ``pages`` stat of its ``engine.insert`` span)
 and ``queue_wait_s`` (summed submit -> admit host time).
 """
 from __future__ import annotations
@@ -162,7 +166,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..configs.base import ShapeCell
 from ..launch.serve import strip_dp_specs
 from ..launch.specs import (cache_specs, default_num_pages, make_context,
-                            make_plan, serve_decode_input_specs,
+                            make_plan, prefill_handoff_specs,
+                            serve_decode_input_specs,
                             serve_feed_specs, serve_heads_feed_specs,
                             serve_verify_input_specs, verify_shape_cell)
 from ..launch.train import shard_params_specs
@@ -174,7 +179,7 @@ from . import sampling
 from .draft import NGramDrafter
 from .errors import (CacheOverflowError, EngineConfigError,
                      PagePoolExhausted, SchedulerStall, SlotsExhausted)
-from .kv_cache import PagedKVCache
+from .kv_cache import PagedKVCache, pool_rows
 from .sampling import SamplingConfig
 
 __all__ = ["CacheOverflowError", "EngineConfig", "EngineConfigError",
@@ -302,20 +307,24 @@ class _InFlight:
 def make_engine_prefill_step(cfg, plan, mesh, scfg: SamplingConfig,
                              replicate_weights=False):
     """prefill(params, tokens[1,S], last_pos[1], temp[1], key) ->
-    (first_token [1], cache)."""
+    (first_token [1], cache).
+
+    The cache is handed over in the pool's row layout
+    (``prefill_handoff_specs``): each attention KV leaf lane-flat
+    ``[U, 1, S, Hkv*dh]``, so the insert copies pages as they are."""
     _, pspecs, _ = shard_params_specs(cfg, plan)
     ctx = make_context(plan, "prefill")
     if replicate_weights:
         pspecs = strip_dp_specs(pspecs)
         ctx = ctx.with_(dp_size=1)
-    _, cspecs = cache_specs(plan)
+    _, cspecs = prefill_handoff_specs(plan)
 
     def step(params, tokens, last_pos, temp, key):
         logits, caches = M.forward_prefill(params, {"tokens": tokens}, ctx,
                                            last_pos=last_pos)
         tok = sampling.sample(logits, key, temp, tp=ctx.tp,
                               tp_size=ctx.tp_size, cfg=scfg)
-        return tok, caches
+        return tok, pool_rows(caches)
 
     fn = jax.shard_map(
         step, mesh=mesh,
@@ -630,6 +639,7 @@ class ServingEngine:
         self.staged_bytes = 0      # host bytes handed to the device
         self.kv_blocks_walked = 0  # paged-decode kernel blocks computed
         self.kv_pages_fetched = 0  # paged-decode kernel pages copied
+        self.kv_pages_inserted = 0  # pool pages the admit inserts wrote
         self.queue_wait_s = 0.0    # summed submit -> admit host time
         #: id(request) -> host time of its submit, until its first admit
         self._submitted: dict = {}
@@ -758,8 +768,11 @@ class ServingEngine:
                                               self._next_key())
             # admit maps ceil(P_len/page_size) pages — O(prompt), not
             # O(max_seq); each decode step maps the next page on demand
-            with TraceAnnotation("engine.insert"):
-                slot = self.cache.admit(pre_cache, P_len, plan_pre=plan_pre)
+            with TraceAnnotation("engine.insert") as span:
+                slot, pages = self.cache.admit(pre_cache, P_len,
+                                               plan_pre=plan_pre)
+                span.set_metadata(pages=pages)
+            self.kv_pages_inserted += pages
             st = _Slot(req, list(prior), None, seq=self._admit_seq,
                        pending_first=first)
             self._admit_seq += 1
@@ -1563,6 +1576,7 @@ class ServingEngine:
         self.staged_bytes = 0
         self.kv_blocks_walked = 0
         self.kv_pages_fetched = 0
+        self.kv_pages_inserted = 0
         self.queue_wait_s = 0.0
         self.spec_commits = 0
         self.spec_verifies = 0

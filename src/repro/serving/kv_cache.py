@@ -41,11 +41,13 @@ to.  With no step in flight (the synchronous engine), frees are
 immediate and behavior is byte-identical to the pre-async allocator.
 
 ``insert`` splices a freshly prefilled single-request cache into the
-pool: state leaves are a slot-row write; KV leaves all_gather the one
-request's seq-sharded prefill KV over tp (the natural admit cost) and
-scatter it page-block-wise into the slot's freshly mapped pages —
-out-of-shard / unmapped targets drop, so only ``ceil(prompt_len /
-page_size)`` pages are ever touched.
+pool: state leaves are a slot-row write; the prefill hands its KV over
+in the pool's lane-flat row layout (``pool_rows``), the insert
+all_gathers the one request's seq-sharded rows over tp (the natural
+admit cost) and copies them one page block at a time into the slot's
+freshly mapped pages — out-of-shard / unmapped targets drop, so each
+chip writes only its own of the ``ceil(prompt_len / page_size)`` pages,
+each once, and nothing the size of ``max_seq`` is staged.
 
 Safety invariant (why stale pool rows can never leak between slots): a
 slot's visible positions ``[0, len)`` are always positions the slot
@@ -67,8 +69,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..kernels.paged_decode import (blocks_walked, pages_fetched,
                                     pages_per_block)
-from ..launch.specs import (CellPlan, cache_specs, default_num_pages,
-                            paged_cache_specs, pages_per_slot)
+from ..launch.specs import (CellPlan, default_num_pages, paged_cache_specs,
+                            pages_per_slot, prefill_handoff_specs)
 from ..models.context import axes_linear_index, pool_local_pages
 from .errors import CacheOverflowError, PagePoolExhausted, SlotsExhausted
 
@@ -466,24 +468,43 @@ def make_init_fn(plan: CellPlan, mesh, page_size: int, num_pages: int):
     return jax.jit(init, out_shardings=shardings)
 
 
+def pool_rows(cache):
+    """The prefill's cache in the pool's row layout: each attention KV
+    leaf ``[U, B, S, Hkv, dh]`` lane-flat as ``[U, B, S, Hkv*dh]`` (see
+    ``prefill_handoff_specs``); state leaves as they are."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, x: (x.reshape(*x.shape[:3], -1) if _is_kv_path(path)
+                         else x), cache)
+
+
+def insert_blocks(seq_len: int, page_size: int) -> int:
+    """Block-table entries an insert of a ``seq_len``-row prefill
+    covers: the page blocks its rows fill, the last one maybe short."""
+    return -(-seq_len // page_size)
+
+
 def make_insert_fn(plan: CellPlan, plan_pre: CellPlan, mesh,
                    page_size: int, num_pages: int):
     """insert(cache, pre_cache, slot, pages) -> cache (donated, in place).
 
     ``pre_cache`` is the B=1 cache returned by the engine prefill step
-    (seq length ``plan_pre.cell.seq_len``); ``slot`` a replicated int32;
-    ``pages`` the slot's freshly mapped block-table row (replicated
-    int32 [pages_per_slot], -1 for entries beyond the prompt).  State
-    leaves are a slot-row write; KV leaves gather the request's prefill
-    KV over tp and scatter it page-block-wise into the pool — only the
-    mapped pages are written (unmapped / non-resident targets drop), so
-    an admit touches O(prompt_len), not O(max_seq), pool bytes.
+    (seq length ``S_pre = plan_pre.cell.seq_len``, KV leaves in the
+    pool's row layout, ``prefill_handoff_specs``); ``slot`` a replicated
+    int32; ``pages`` the slot's freshly mapped block-table row
+    (replicated int32 [pages_per_slot], -1 for entries beyond the
+    prompt).  State leaves are a slot-row write.  KV leaves gather the
+    request's prefill rows over tp, view them as ``ceil(S_pre / psz)``
+    page blocks (only a short last block is padded) and write block
+    ``j`` to page ``pages[j]`` where that page is mapped and lives on
+    this shard: each such page once, no other pool row, and nothing
+    ``max_seq``-sized on the way.  The body runs under the named scope
+    ``kv_insert``.
     """
     assert plan.cp == (plan.tp,) and plan_pre.cp == (plan_pre.tp,), (
         "engine admit requires tp-only context parallelism on both the "
         "prefill and decode plans")
     _, cspecs = paged_cache_specs(plan, page_size, num_pages)
-    _, pspecs = cache_specs(plan_pre)
+    _, pspecs = prefill_handoff_specs(plan_pre)
     num_slots = plan.cell.global_batch
     dp_size = plan.dp_size if plan.batch_sharded else 1
     slots_loc = num_slots // dp_size
@@ -491,8 +512,39 @@ def make_insert_fn(plan: CellPlan, plan_pre: CellPlan, mesh,
     tp = plan.tp
     pool_axes = tuple(plan.dp) + (plan.tp,)
     psz = page_size
+    nb = insert_blocks(S_pre, psz)        # table entries the rows cover
 
-    def ins(cache, pre, slot, pages):
+    def page_rows(p0, c):
+        """The request's rows [U, nb * psz, L] of one KV leaf, from this
+        shard's [U, S_pre / tp, L] (a leaf still in the model's
+        [U, S_pre / tp, Hkv, dh] is viewed lane-flat first): gathered
+        over tp, a short last block padded."""
+        rows = p0.reshape(*p0.shape[:2], -1)
+        rows = lax.all_gather(rows, tp, axis=1, tiled=True).astype(c.dtype)
+        if S_pre % psz:
+            rows = jnp.pad(rows, ((0, 0), (0, nb * psz - S_pre), (0, 0)))
+        return rows
+
+    def copy_pages(pools, rows, loc):
+        """Copy block ``j`` of each of ``rows`` into its pool's row
+        ``loc[j]``, for every ``j`` whose page lives on this shard: a loop
+        over those blocks only, each one copy of ``[U, psz, L]`` per leaf
+        (``loc`` one past the end of the shard: not here)."""
+        ok = loc < pools[0].shape[1]
+        order = jnp.argsort(~ok, stable=True)     # this shard's blocks first
+
+        def body(i, pools):
+            j = order[i]
+            return tuple(
+                lax.dynamic_update_slice_in_dim(
+                    c, lax.dynamic_slice_in_dim(r, j * psz, psz,
+                                                axis=1)[:, None],
+                    loc[j], axis=1)
+                for c, r in zip(pools, rows))
+
+        return lax.fori_loop(0, ok.sum(), body, tuple(pools))
+
+    def splice(cache, pre, slot, pages):
         pidx = axes_linear_index(pool_axes)        # pool shard index
         if dp_size > 1:
             r_dp = jnp.zeros((), jnp.int32)
@@ -503,25 +555,29 @@ def make_insert_fn(plan: CellPlan, plan_pre: CellPlan, mesh,
         own = (slot >= r_dp * slots_loc) & (slot < (r_dp + 1) * slots_loc)
         ls = jnp.clip(slot - r_dp * slots_loc, 0, slots_loc - 1)
 
-        def merge(path, c, p):
+        flat, tree = jax.tree_util.tree_flatten_with_path(cache)
+        out, kv = [], []
+        for (path, c), p in zip(flat, jax.tree_util.tree_leaves(pre)):
             p0 = p[:, 0]                              # drop the B=1 dim
             if _is_kv_path(path):
-                # c: pool shard [U, P_loc, psz, Hkv*dh]; gather the one
-                # request's full prefill KV, re-slice it into lane-flat
-                # page blocks, scatter through the slot's fresh table row
-                P_loc = c.shape[1]
-                full = lax.all_gather(p0, tp, axis=1, tiled=True)
-                pps = pages.shape[0]
-                gpos = jnp.arange(pps * psz)
-                src = jnp.take(full, jnp.minimum(gpos, S_pre - 1), axis=1)
-                src = src.astype(c.dtype).reshape(c.shape[0], pps, psz, -1)
-                loc, _ = pool_local_pages(pages, pidx, P_loc)
-                return c.at[:, loc].set(src, mode="drop")
+                # c: pool shard [U, P_loc, psz, Hkv*dh]; p0: this shard's
+                # rows [U, S_pre / tp, Hkv*dh] of the one request
+                kv.append((len(out), c, page_rows(p0, c)))
+                out.append(c)
+                continue
             cur = lax.dynamic_index_in_dim(c, ls, axis=1, keepdims=False)
             row = jnp.where(own, p0.astype(c.dtype), cur)
-            return c.at[:, ls].set(row)
+            out.append(c.at[:, ls].set(row))
+        if kv:
+            idx, pools, rows = zip(*kv)
+            loc, _ = pool_local_pages(pages[:nb], pidx, pools[0].shape[1])
+            for k, c in zip(idx, copy_pages(pools, rows, loc)):
+                out[k] = c
+        return jax.tree_util.tree_unflatten(tree, out)
 
-        return jax.tree_util.tree_map_with_path(merge, cache, pre)
+    def ins(cache, pre, slot, pages):
+        with jax.named_scope("kv_insert"):
+            return splice(cache, pre, slot, pages)
 
     fn = jax.shard_map(ins, mesh=mesh, in_specs=(cspecs, pspecs, P(), P()),
                        out_specs=cspecs, check_vma=False)
@@ -556,11 +612,11 @@ class PagedKVCache:
         self.kv_block_pages = (pages_per_block(
             self.allocator.pages_per_shard, page_size, kv[0].shape[-1],
             kv[0].dtype.itemsize) if kv else None)
-        self._insert = make_insert_fn(plan, plan_pre, mesh, page_size,
-                                      self.num_pages)
+        self._plan_pre = plan_pre
         #: exact-length prefill buckets: one compiled insert per prefill
-        #: seq length (the gather/re-slice inside depends on S_pre)
-        self._insert_fns = {plan_pre.cell.seq_len: self._insert}
+        #: seq length (the gather and page blocks depend on S_pre)
+        self._insert_fns = {plan_pre.cell.seq_len: make_insert_fn(
+            plan, plan_pre, mesh, page_size, self.num_pages)}
         self.peak_pages_in_use = 0
 
     def _note_peak(self):
@@ -596,21 +652,29 @@ class PagedKVCache:
         return self._insert_fns[S]
 
     def admit(self, pre_cache, seq_len: int,
-              plan_pre: CellPlan = None) -> int:
+              plan_pre: CellPlan = None) -> tuple:
         """Allocate a slot, map ``ceil(seq_len/page_size)`` pages, and
         splice the prefilled cache into them.  ``plan_pre`` selects a
-        non-default exact-length prefill bucket's insert program."""
+        non-default exact-length prefill bucket's insert program.
+
+        Returns ``(slot, pages)``: ``pages`` is the number of pool pages
+        the insert writes, summed over shards — the mapped entries of the
+        slot's row among those the insert covers (0 without attention
+        KV)."""
         slot = self.allocator.alloc(seq_len)
         self._note_peak()
-        ins = (self._insert if plan_pre is None
-               else self.insert_fn_for(plan_pre))
+        plan_pre = self._plan_pre if plan_pre is None else plan_pre
+        ins = self.insert_fn_for(plan_pre)
         # a snapshot of the row, not a view: the array handed to the
         # asynchronously dispatched insert may alias host memory, and the
         # allocator rewrites this row as the slot grows or is recycled
-        self.buffers = ins(
-            self.buffers, pre_cache, jnp.asarray(slot, jnp.int32),
-            np.array(self.allocator.block_table[slot], np.int32))
-        return slot
+        row = np.array(self.allocator.block_table[slot], np.int32)
+        self.buffers = ins(self.buffers, pre_cache,
+                           jnp.asarray(slot, jnp.int32), row)
+        nb = insert_blocks(plan_pre.cell.seq_len, self.page_size)
+        pages = (int((row[:nb] >= 0).sum())
+                 if self.kv_block_pages is not None else 0)
+        return slot, pages
 
     def ensure(self, slot: int, new_len: int):
         """Map pages (alloc-on-extend) so positions < ``new_len`` are

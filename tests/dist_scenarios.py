@@ -677,6 +677,26 @@ def scenario_sampling_stats():
         print(f"sampling stats OK {name} tv={tv:.4f}")
 
 
+def scenario_kv_insert_contract():
+    """The admission insert's contract (``_kv_insert``) for every case on
+    a 1x4 mesh (the pool's pages over four tp shards) and a 2x2 mesh
+    (two dp page groups of two shards each).  A case that fails prints
+    why and the rest still run, so each case's test reports its own."""
+    from jax.sharding import Mesh
+    from _kv_insert import CASES, check
+    for shape in ((1, 4), (2, 2)):
+        mesh = Mesh(np.array(jax.devices()[:4]).reshape(shape),
+                    ("data", "model"))
+        for case in sorted(CASES):
+            name = f"{case} {shape[0]}x{shape[1]}"
+            try:
+                check(mesh, case)
+            except AssertionError as e:
+                print(f"insert contract FAILED {name}: {e!r}")
+            else:
+                print(f"insert contract OK {name}")
+
+
 SCENARIOS = {k[len("scenario_"):]: v for k, v in list(globals().items())
              if k.startswith("scenario_")}
 

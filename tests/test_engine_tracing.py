@@ -108,6 +108,64 @@ def test_kv_blocks_walked_counts_each_launch(engine, tmp_path, prompt_len,
     assert eng.kv_blocks_walked == 0 and eng.kv_pages_fetched == 0
 
 
+@pytest.mark.parametrize("prompt_lens", [(3,), (8, 9), (16, 1, 12)])
+def test_kv_pages_inserted_counts_each_admission(engine, tmp_path,
+                                                 prompt_lens):
+    """Page 8: each admission's insert writes ``ceil(prompt_len / 8)``
+    pool pages, the ``pages`` stat of its ``engine.insert`` span, and
+    ``kv_pages_inserted`` sums them; reset_stats zeroes it."""
+    import glob
+    import jax
+    from repro.serving import Request
+    eng = engine
+    eng.reset_stats()
+    assert eng.kv_pages_inserted == 0
+    for i, n in enumerate(prompt_lens):
+        eng.submit(Request(rid=f"p{i}", prompt=list(range(1, n + 1)),
+                           max_new_tokens=2))
+    jax.profiler.start_trace(str(tmp_path))
+    eng.step()
+    jax.profiler.stop_trace()
+    want = [-(-n // PAGE) for n in prompt_lens]
+    assert eng.kv_pages_inserted == sum(want)
+    pb = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                       "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(pb[0])
+    stats = [dict(e.stats)
+             for plane in data.planes if plane.name.startswith("/host:")
+             for ln in plane.lines for e in ln.events
+             if e.name == "engine.insert"]
+    assert [st.get("pages") for st in stats] == want
+    eng.flush()
+    while not eng.idle:
+        eng.step()
+    eng.reset_stats()
+    assert eng.kv_pages_inserted == 0
+
+
+def test_insert_ops_carry_the_named_scope(engine):
+    """The compiled insert's page copies say ``kv_insert`` in their
+    op_name (``insert_ms_per_admit`` reads them by it), and no op of the
+    decode step does."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.launch.specs import prefill_handoff_specs
+    eng = engine
+    ins = eng.cache.insert_fn_for(eng.plan_pre)
+    pre = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype),
+                       prefill_handoff_specs(eng.plan_pre)[0])
+    hlo = ins.lower(eng.cache.buffers, pre, jnp.int32(0),
+                    np.zeros(MAX_SEQ // PAGE, np.int32)).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', hlo)
+    assert any("/kv_insert/while/body/dynamic_update_slice" in n
+               for n in names)
+    dec = re.findall(r'op_name="([^"]*)"',
+                     eng.compiled_decode_step().as_text())
+    assert not any("kv_insert" in n.split("/") for n in dec)
+
+
 def test_codec_ops_carry_the_named_scope(engine):
     """The compiled decode step's codec ops say ``spike_codec/encode`` and
     ``spike_codec/decode`` in their op_name, and no other op does."""

@@ -8,17 +8,30 @@ gemma2-2b (GQA, dh 256, sliding window, softcap) — over a page pool that
 it reads in place from HBM, and so is the serving engine's whole decode
 step at qwen1.5-0.5b widths: the kernel's custom call is
 named by the kernel itself, whatever jitted function encloses it, and
-the codec's ops carry their named scope.  No chip is needed: the
+the codec's ops carry their named scope.  The engine's admission insert
+is compiled at each benchmark cell's sizes: it copies pages in place
+and stages nothing the size of ``max_seq``.  No chip is needed: the
 topology is described, not attached.  Nothing touches the TPU library
 while this module is imported; the fixture describes the topology, and
 skips, only once a test of this file runs.
 """
+import json
 import os
 import re
+import sys
+from pathlib import Path
 
 import pytest
 
 PAGES, PAGE, SLOTS, UNITS = 4096, 16, 8, 24
+ROOT = Path(__file__).resolve().parents[1]
+#: benchmark cell -> (its configuration, its traffic) under bench/
+BENCH_CELLS = {
+    "qwen05b-spike-decode": ("qwen1.5-0.5b-hnn-spike",
+                             "qwen05b-spike-decode"),
+    "qwen4b-spike-tp4-decode": ("qwen1.5-4b-hnn-spike-tp4",
+                                "qwen4b-spike-tp4-decode"),
+}
 
 
 @pytest.fixture(scope="module")
@@ -203,3 +216,68 @@ def test_engine_decode_step_names_its_kernel_and_codec(decode_step):
     codec = [ln for ln in hlo.splitlines()
              if " fusion(" in ln and "/spike_codec/" in ln]
     assert codec
+
+
+def _compile_insert(topo, cell):
+    """(compiled insert, pages_per_slot, bytes of the gathered prefill
+    rows) of the engine at ``cell``'s sizes, fed the prefill's hand-off
+    as the engine's prefill program returns it."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.configs.base import ShapeCell
+    from repro.launch import specs as SP
+    from repro.serving.kv_cache import make_insert_fn
+    sys.path.insert(0, str(ROOT))
+    from bench import serve
+
+    config_name, traffic_name = BENCH_CELLS[cell]
+    config = json.loads((ROOT / "bench" / "configs"
+                         / f"{config_name}.json").read_text())
+    eng = json.loads((ROOT / "bench" / "traffic"
+                      / f"{traffic_name}.json").read_text())["engine"]
+    tp = int(config["tp"])
+    mesh = Mesh(np.array(topo.devices[:tp]).reshape(1, tp),
+                ("data", "model"))
+    cfg = serve.model_config(config)
+    psz, pages = eng["page_size"], eng["num_pages"]
+    plan = SP.make_plan(cfg, ShapeCell("serve_decode", eng["max_seq"],
+                                       eng["num_slots"], "decode"), mesh)
+    plan_pre = SP.make_plan(cfg, ShapeCell(
+        "serve_admit", eng["prefill_len"], 1, "prefill"), mesh)
+
+    def placed(structs, specs):
+        return jax.tree.map(
+            lambda s, sp: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=NamedSharding(mesh, sp)),
+            structs, specs, is_leaf=lambda x: isinstance(x, P))
+
+    pps = SP.pages_per_slot(eng["max_seq"], psz)
+    pre = SP.prefill_handoff_specs(plan_pre)
+    args = (placed(*SP.paged_cache_specs(plan, psz, pages)), placed(*pre),
+            placed(jax.ShapeDtypeStruct((), jnp.int32), P()),
+            placed(jax.ShapeDtypeStruct((pps,), jnp.int32), P()))
+    gathered = 0 if tp == 1 else sum(
+        s.size * s.dtype.itemsize for s in jax.tree.leaves(pre[0]))
+    compiled = make_insert_fn(plan, plan_pre, mesh, psz,
+                              pages).lower(*args).compile()
+    return compiled, pps, psz, gathered
+
+
+@pytest.mark.parametrize("cell", sorted(BENCH_CELLS))
+def test_engine_insert_copies_pages_in_place(topo, cell):
+    """The insert updates the donated pool in place and its temp holds at
+    most the prefill rows gathered over tp (none on one chip) and 16 MB
+    besides; no buffer of it is ``max_seq`` rows long, as a source of
+    ``pages_per_slot x page_size`` rows staged for a scatter would be."""
+    compiled, pps, psz, gathered = _compile_insert(topo, cell)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes > 1e9
+    assert mem.temp_size_in_bytes <= gathered + 16e6, mem
+    hlo = compiled.as_text()
+    for dims in re.findall(r"\b[a-z]+\d*\[([\d,]+)\]", hlo):
+        d = [int(x) for x in dims.split(",")]
+        assert pps * psz not in d, dims
+        assert (pps, psz) not in zip(d, d[1:]), dims
+    assert "/kv_insert/" in hlo
